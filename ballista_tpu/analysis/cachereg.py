@@ -313,7 +313,6 @@ CACHES: tuple[CacheEntry, ...] = (
             "ballista_tpu/ops/join.py::_build_prep_program",
             "ballista_tpu/ops/join.py::_exact2_range_program",
             "ballista_tpu/ops/join.py::_lut_program",
-            "ballista_tpu/ops/pallas_agg.py::available",
             "ballista_tpu/ops/pallas_agg.py::_program",
             "ballista_tpu/ops/perm.py::_argsort_program",
             "ballista_tpu/ops/perm.py::_take_program",

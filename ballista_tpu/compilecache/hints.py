@@ -23,8 +23,9 @@ tally, which meters in-process build tables that die with the process.
 
 Layout: one JSON file, ``plan_hints.json``, in the resolved hint dir —
 ``BALLISTA_TPU_HINT_CACHE`` when set (``off`` disables), else the XLA
-cache dir (``BALLISTA_TPU_JAX_CACHE``), so ``off`` there keeps the whole
-persistence surface inert (satellite 1). Writes are atomic
+cache dir (``ballista_tpu.resolve_jax_cache_dir``), so
+``BALLISTA_TPU_JAX_CACHE=off`` keeps the whole persistence surface
+inert. Writes are atomic
 (tmp + ``os.replace``) and debounced by content fingerprint; concurrent
 executors sharing a dir are last-writer-wins, which is safe for the same
 reason staleness is.
@@ -56,13 +57,10 @@ def store_path() -> str | None:
     """Resolved hint-file path, or None when persistence is off."""
     spec = os.environ.get("BALLISTA_TPU_HINT_CACHE", "")
     if not spec:
-        spec = os.environ.get(
-            "BALLISTA_TPU_JAX_CACHE",
-            os.path.join(
-                os.path.expanduser("~"), ".cache", "ballista_tpu_jax"
-            ),
-        )
-    if spec == "off":
+        import ballista_tpu
+
+        spec = ballista_tpu.resolve_jax_cache_dir()
+    if spec is None or spec == "off":
         return None
     return os.path.join(spec, HINT_FILE)
 

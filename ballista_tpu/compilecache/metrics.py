@@ -13,7 +13,7 @@ telemetry uses) and keeps process-global counters:
   REQUESTS and the wall time spent inside them (persistent-cache hits
   still pass through here, cheaply).
 - ``persistent_cache_hits`` / ``persistent_cache_misses`` — the on-disk
-  XLA cache (BALLISTA_TPU_JAX_CACHE): a miss is a real XLA compile.
+  XLA cache (docs/compile_cache.md): a miss is a real XLA compile.
 - ``cache_retrieval_seconds`` — time spent deserializing cached
   executables (the cost floor of a cache-hit cold start).
 - ``jit_cache_hits`` / ``jit_cache_misses`` — the shared jitted-callable

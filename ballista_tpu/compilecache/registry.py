@@ -247,7 +247,8 @@ def enumerate_prewarm(
     sigs: list[PrewarmSignature] = []
     for cap in buckets:
         for dt in dtypes:
-            for desc in (False, True):
+            # flags sort through the int32 program (ops/perm.stable_argsort)
+            for desc in (False, True) if dt != "bool" else ():
                 sigs.append(PrewarmSignature(
                     "ops.perm.f", cap, (dt,),
                     variant=f"argsort,desc={int(desc)}",

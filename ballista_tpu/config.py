@@ -275,9 +275,11 @@ ENV_REGISTRY: tuple[EnvEntry, ...] = (
         "docs/aqe.md",
     ),
     EnvEntry(
-        "BALLISTA_TPU_JAX_CACHE", "path|off", "~/.cache/ballista_tpu_jax",
-        "Persistent XLA compilation cache directory; 'off' disables the "
-        "cache machinery entirely",
+        "BALLISTA_TPU_JAX_CACHE", "off", "",
+        "'off' disables the persistent XLA compilation cache machinery "
+        "entirely. The directory is not set here: JAX's own "
+        "JAX_COMPILATION_CACHE_DIR places it, and unset it is "
+        "<checkout>/.jax_cache",
         "docs/compile_cache.md",
     ),
     EnvEntry(

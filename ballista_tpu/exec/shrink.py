@@ -56,10 +56,10 @@ def _shrink_program(
     program. The gather runs over the SLICED order (new_cap indices), so
     its cost scales with the small output, not the old capacity; only the
     bool argsort pass touches the full batch."""
-    from ballista_tpu.ops.perm import take_many_split
+    from ballista_tpu.ops.perm import argsort_i32, take_many_split
 
     def f(cols, nulls, valid):
-        order = jnp.argsort(~valid, stable=True)[:new_cap]
+        order = argsort_i32((~valid).astype(jnp.int32))[:new_cap]
         out_cols, out_nulls = take_many_split(
             list(cols), list(nulls), order
         )

@@ -114,6 +114,15 @@ def main(argv: list[str] | None = None) -> int:
         "jax persistent compilation cache: %s",
         ballista_tpu.jax_cache_dir or "disabled",
     )
+    # the executor is the process that owns the chip: say which one, before
+    # anything is served (a missing or busy device fails here, at start)
+    import jax
+
+    devices = jax.devices()
+    log.info(
+        "devices: platform=%s count=%d kind=%s",
+        devices[0].platform, len(devices), devices[0].device_kind,
+    )
     work_dir = args.work_dir or tempfile.mkdtemp(prefix="ballista-executor-")
     os.makedirs(work_dir, exist_ok=True)
     policy = TaskSchedulingPolicy.parse(args.task_scheduling_policy)
@@ -181,6 +190,10 @@ def main(argv: list[str] | None = None) -> int:
 
         stop_metrics_server(metrics_httpd)
     worker.stop()
+    log.info(
+        "device peak_bytes_in_use=%s",
+        (devices[0].memory_stats() or {}).get("peak_bytes_in_use"),
+    )
     return 0
 
 

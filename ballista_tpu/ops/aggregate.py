@@ -155,8 +155,9 @@ _MATMUL_MAX_SLOTS = 2048
 _MATMUL_MAX_ONEHOT_BYTES = 512 << 20
 
 # The pallas kernel (ops/pallas_agg.py) replaces the XLA one-hot matmul on
-# big batches only: its f32 in-block accumulation carries ~1e-8 relative
-# error, acceptable for SQL sums at scale (no defined summation order) but
+# big batches only: its f32 in-block accumulation carries ~2e-8 relative
+# error (ops/pallas_agg.py), acceptable for SQL sums at scale (no defined
+# summation order) but
 # above what small-data unit tests assert (rtol=1e-9). Below the bar the
 # XLA f64 path is cheap anyway.
 _PALLAS_MIN_ROWS = 1 << 20
@@ -930,11 +931,6 @@ def dense_group_aggregate(
     ops: list[AggOp],
 ) -> GroupAggResult:
     """Sort-free aggregation over dictionary codes (see ``_dense_agg``)."""
-    # resolve the pallas-availability probe OUTSIDE the jit trace (it runs
-    # a tiny trial kernel; the answer is cached for the process)
-    from ballista_tpu.ops import pallas_agg
-
-    pallas_agg.available()
     return _dense_agg_jit(
         list(key_codes), list(key_nulls), tuple(vocab_sizes), valid,
         list(val_cols), list(val_nulls), tuple(ops),

@@ -10,17 +10,21 @@ columns — branch-free and vectorizable on the VPU.
 from __future__ import annotations
 
 import jax.numpy as jnp
+import numpy as np
 
-_C1 = jnp.uint64(0x9E3779B97F4A7C15)
-_C2 = jnp.uint64(0xBF58476D1CE4E5B9)
-_C3 = jnp.uint64(0x94D049BB133111EB)
+# numpy scalars, not jnp: a jnp constant at import initialises a backend, so
+# every process importing the engine (scheduler, client, CLI) would claim
+# the chip that belongs to the executor.
+_C1 = np.uint64(0x9E3779B97F4A7C15)
+_C2 = np.uint64(0xBF58476D1CE4E5B9)
+_C3 = np.uint64(0x94D049BB133111EB)
 
 
 def _splitmix64(x: jnp.ndarray) -> jnp.ndarray:
     x = x + _C1
-    x = (x ^ (x >> jnp.uint64(30))) * _C2
-    x = (x ^ (x >> jnp.uint64(27))) * _C3
-    return x ^ (x >> jnp.uint64(31))
+    x = (x ^ (x >> np.uint64(30))) * _C2
+    x = (x ^ (x >> np.uint64(27))) * _C3
+    return x ^ (x >> np.uint64(31))
 
 
 def _to_u64(col: jnp.ndarray) -> jnp.ndarray:

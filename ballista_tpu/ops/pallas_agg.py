@@ -13,10 +13,15 @@ HBM traffic collapses to the operands themselves.
 Numerics: f64 value columns are split into exact f32 (hi, lo) pairs
 host-side (48-bit significand coverage); products against the 0/1 one-hot
 are exact on the MXU at HIGHEST precision, so the only error source is
-f32 accumulation inside a block — bounded by accumulating at most
-``_SUPER`` blocks per f32 partial and summing partials in f64. Measured
-end-to-end relative error ~1e-8 at 8.4M rows, which is why callers gate
-this path to large batches (unit tests assert rtol=1e-9 on small data).
+f32 accumulation. What decides it is the LENGTH OF ONE DOT, the block's
+row count: on a v5e, 2M rows into 12 slots came out 1.8e-6 off an f64
+reference with 32768-row blocks, 1.3e-7 with 8192, 2.4e-8 with 2048 and
+4e-9 with 512, while the number of blocks accumulated into one f32
+partial (1, 8, 16 or 64) moved nothing (chip run of PR 21, PERF.md). So
+blocks are capped at ``_MAX_BLOCK_ROWS`` = 2048 rows (1.16 ms against
+0.93 ms for those 2M rows), partials cover ``_PARTIAL_ROWS`` rows and are
+summed in f64. ~2e-8 is still above what small-data unit tests assert
+(rtol=1e-9), which is why callers gate this path to large batches.
 
 Counts (0/1 contributions) are exact: per-block partials stay below 2^24
 (f32's exact-integer range) and the cross-block sum runs in f64.
@@ -33,9 +38,15 @@ import functools
 import jax
 import jax.numpy as jnp
 
-# Accumulate this many grid steps into one f32 partial before handing off
-# to the f64 cross-partial sum (bounds f32 accumulation error).
-_SUPER = 64
+from ballista_tpu.compilecache import metrics
+
+# Rows accumulated into one f32 partial before the f64 cross-partial sum.
+# It bounds the partial outputs ((n / _PARTIAL_ROWS, P, R) f32), not the
+# error: see the module note.
+_PARTIAL_ROWS = 32768
+
+# Rows of one grid step, i.e. the length of one f32 dot: the error bound.
+_MAX_BLOCK_ROWS = 2048
 
 # VMEM budget for the (P, B) one-hot: B*P*4 bytes <= ~6MB.
 _ONEHOT_VMEM_BYTES = 6 << 20
@@ -43,23 +54,14 @@ _ONEHOT_VMEM_BYTES = 6 << 20
 
 def _block_rows(P: int) -> int:
     b = _ONEHOT_VMEM_BYTES // (4 * max(P, 1))
-    return max(512, min(32768, (b // 512) * 512))
+    return max(512, min(_MAX_BLOCK_ROWS, (b // 512) * 512))
 
 
-@functools.lru_cache(maxsize=1)
 def available() -> bool:
-    """Pallas path is TPU-only; probed once with a tiny trial compile."""
-    if jax.default_backend() != "tpu":
-        return False
-    try:
-        import numpy as np
-
-        rid = jnp.zeros((1, 512), jnp.int32)
-        mat = jnp.ones((1, 512), jnp.float32)
-        out = _program(512, 1, 8)(rid, mat)
-        return bool(np.asarray(out)[0, 0] == 512.0)
-    except Exception:  # pragma: no cover - platform-specific
-        return False
+    """The kernel is the TPU's path and only the TPU's. No trial compile
+    guards it: a shape Mosaic refuses raises where the query lowers it,
+    it does not become a silent switch to the XLA one-hot path."""
+    return jax.default_backend() == "tpu"
 
 
 @functools.lru_cache(maxsize=None)
@@ -73,12 +75,13 @@ def _program(n: int, R: int, P: int):
 
     B = min(_block_rows(P), n)
     nb = -(-n // B)
-    nb2 = -(-nb // _SUPER)
+    sup = max(1, _PARTIAL_ROWS // B)  # grid steps per f32 partial
+    nb2 = -(-nb // sup)
 
     def kernel(rid_ref, mat_ref, out_ref):
         g = pl.program_id(0)
 
-        @pl.when(g % _SUPER == 0)
+        @pl.when(g % sup == 0)
         def _():
             out_ref[...] = jnp.zeros_like(out_ref)
 
@@ -108,7 +111,7 @@ def _program(n: int, R: int, P: int):
                     pl.BlockSpec((R, B), lambda g: (0, g)),
                 ],
                 out_specs=pl.BlockSpec(
-                    (1, P, R), lambda g: (g // _SUPER, 0, 0)
+                    (1, P, R), lambda g: (g // sup, 0, 0)
                 ),
             )
             pad = nb * B - n
@@ -125,6 +128,9 @@ def onehot_sums(rid: jnp.ndarray, rows: list[jnp.ndarray], P: int):
     """Sum each f32 row-vector of ``rows`` into ``P`` slots keyed by
     ``rid`` (i32[n]; values outside [0, P) are dropped). Returns
     (P, len(rows)) f64. Traceable under jit."""
+    # runs at trace time: one count per program the kernel was staged into
+    # (chip_smoke.py reads it to show q1 took this path, not the XLA one)
+    metrics.add("pallas_onehot_traces")
     matT = jnp.stack([r.astype(jnp.float32) for r in rows], axis=0)
     rid2 = rid.astype(jnp.int32).reshape(1, -1)
     return _program(rid2.shape[1], len(rows), P)(rid2, matT)

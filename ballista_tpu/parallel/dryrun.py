@@ -4,8 +4,8 @@ n-device mesh, asserted against a numpy oracle.
 Run as ``python -m ballista_tpu.parallel.dryrun N`` in an environment where
 jax sees N devices (the driver entry ``__graft_entry__.dryrun_multichip``
 launches this module in a subprocess with ``JAX_PLATFORMS=cpu`` and
-``--xla_force_host_platform_device_count=N`` so a broken/mismatched TPU
-runtime on the host can never take the dryrun down with it).
+``--xla_force_host_platform_device_count=N``: a rehearsal on virtual
+devices, never a chip result).
 
 The pipeline mirrors the reference's PARTITIONED join + repartitioned
 aggregate flow (planner.rs:133-157; shuffle_writer.rs:142-292 <->

@@ -31,7 +31,7 @@ import threading
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 # Collective programs from CONCURRENT host threads (a multi-slot executor
@@ -253,7 +253,7 @@ class MeshStageRunner:
         )
         sm = shard_map(
             f, mesh=self.mesh, in_specs=in_specs, out_specs=out_specs,
-            check_rep=False,
+            check_vma=False,
         )
         return jax.jit(sm)
 
@@ -342,7 +342,7 @@ class MeshStageRunner:
         )
         sm = shard_map(
             f, mesh=self.mesh, in_specs=in_specs, out_specs=out_specs,
-            check_rep=False,
+            check_vma=False,
         )
         return jax.jit(sm)
 
@@ -496,7 +496,7 @@ class MeshStageRunner:
         )
         sm = shard_map(
             f, mesh=self.mesh, in_specs=in_specs, out_specs=out_specs,
-            check_rep=False,
+            check_vma=False,
         )
         return jax.jit(sm)
 
@@ -584,7 +584,7 @@ class MeshStageRunner:
         )
         sm = shard_map(
             f, mesh=self.mesh, in_specs=in_specs, out_specs=out_specs,
-            check_rep=False,
+            check_vma=False,
         )
         return jax.jit(sm)
 
@@ -831,6 +831,6 @@ class MeshStageRunner:
         )
         sm = shard_map(
             f, mesh=self.mesh, in_specs=in_specs, out_specs=out_specs,
-            check_rep=False,
+            check_vma=False,
         )
         return jax.jit(sm)

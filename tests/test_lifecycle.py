@@ -57,6 +57,10 @@ def cluster_procs(tmp_path):
     flight_port, grpc_port = _free_port(), _free_port()
     env = dict(CPU_MESH_ENV)
     procs = []
+    # logs go to files, never to a pipe nobody drains: every persistent-cache
+    # hit makes XLA:CPU print a ~3 KB "AOT result ... machine type" error, a
+    # few dozen of them fill a 64 KB pipe and the daemon blocks in write()
+    logs = [open(tmp_path / f"{n}.log", "w+") for n in ("scheduler", "executor")]
     try:
         procs.append(
             subprocess.Popen(
@@ -69,9 +73,8 @@ def cluster_procs(tmp_path):
                     "--state-path", str(tmp_path / "state.db"),
                 ],
                 env=env,
-                stdout=subprocess.PIPE,
+                stdout=logs[0],
                 stderr=subprocess.STDOUT,
-                text=True,
             )
         )
         time.sleep(2.0)
@@ -90,12 +93,11 @@ def cluster_procs(tmp_path):
                     "--job-data-clean-up-interval-seconds", "1",
                 ],
                 env=env,
-                stdout=subprocess.PIPE,
+                stdout=logs[1],
                 stderr=subprocess.STDOUT,
-                text=True,
             )
         )
-        yield sched_port, rest_port, procs
+        yield sched_port, rest_port, logs
     finally:
         for p in procs:
             p.terminate()
@@ -104,12 +106,14 @@ def cluster_procs(tmp_path):
                 p.wait(timeout=10)
             except subprocess.TimeoutExpired:
                 p.kill()
+        for f in logs:
+            f.close()
 
 
 def test_process_entrypoints_end_to_end(tmp_path, cluster_procs):
     """A client runs SQL against scheduler+executor child processes over an
     external CSV table (self-contained plan serde — no shared memory)."""
-    sched_port, rest_port, procs = cluster_procs
+    sched_port, rest_port, daemon_logs = cluster_procs
 
     csv = tmp_path / "points.csv"
     csv.write_text(
@@ -160,13 +164,13 @@ print("ENTRYPOINT-OK")
         timeout=300,
     )
     if proc.returncode != 0:
-        for p in procs:
-            p.terminate()
-        logs = "\n---\n".join(
-            p.communicate()[0] or "" for p in procs
-        )
+        logs = []
+        for f in daemon_logs:
+            f.seek(0)
+            logs.append(f.read()[-6000:])
         raise AssertionError(
-            f"stdout:\n{proc.stdout}\nstderr:\n{proc.stderr}\nprocs:\n{logs}"
+            f"stdout:\n{proc.stdout}\nstderr:\n{proc.stderr[-6000:]}\n"
+            "daemons:\n" + "\n---\n".join(logs)
         )
     assert "ENTRYPOINT-OK" in proc.stdout
 
@@ -178,7 +182,7 @@ print("ENTRYPOINT-OK")
     )
     assert state["version"]
     assert len(state["executors"]) == 1
-    # the executor sees the 8-device virtual mesh and clamps to one task
+    # the executor sees the 4-device virtual mesh and clamps to one task
     # slot (executor.effective_task_slots: a mesh is one resource)
     assert state["executors"][0]["total_task_slots"] == 1
     assert any(j["status"] == "completed" for j in state["jobs"]), state
@@ -186,7 +190,7 @@ print("ENTRYPOINT-OK")
     # have their stage bookkeeping torn down, so it may be empty)
     assert all("stages" in j for j in state["jobs"]), state
 
-    assert state["executors"][0]["n_devices"] == 8  # virtual mesh advertised
+    assert state["executors"][0]["n_devices"] == 4  # virtual mesh advertised
 
     # /api/job/<id>: stage DAG detail (deps + plan display) for the UI's
     # expandable job rows
@@ -231,3 +235,70 @@ print("ENTRYPOINT-OK")
     )
     assert metrics.metricValues[0].metricValue == 0
     ch.close()
+
+
+# -- one chip owner per process ---------------------------------------------
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+
+def _run_py(code: str, **env_overrides) -> subprocess.CompletedProcess:
+    env = {**os.environ, "PYTHONPATH": REPO}
+    for k, v in env_overrides.items():
+        if v is None:
+            env.pop(k, None)
+        else:
+            env[k] = v
+    return subprocess.run(
+        [sys.executable, "-c", code],
+        env=env, capture_output=True, text=True, timeout=120, cwd=REPO,
+    )
+
+
+@pytest.mark.parametrize(
+    "module",
+    [
+        "ballista_tpu.scheduler.server",
+        "ballista_tpu.scheduler.__main__",
+        "ballista_tpu.client.context",
+        "ballista_tpu.cli",
+    ],
+)
+def test_import_initialises_no_backend(module):
+    """The control plane and the client must be importable without ever
+    touching a device: a chip belongs to ONE process, the executor. With a
+    platform name that is no backend, any initialisation at import dies."""
+    proc = _run_py(
+        f"import {module}", JAX_PLATFORMS="no_such_platform"
+    )
+    assert proc.returncode == 0, proc.stderr[-3000:]
+
+
+@pytest.mark.parametrize("placed", [True, False])
+def test_compile_cache_dir_rule(placed, tmp_path):
+    """docs/compile_cache.md: JAX_COMPILATION_CACHE_DIR set -> that
+    directory, and the package itself set nothing (the child also stubs
+    jax.config.update to see every write); unset -> <checkout>/.jax_cache."""
+    code = (
+        "import jax\n"
+        "seen = []\n"
+        "orig = jax.config.update\n"
+        "def spy(k, v):\n"
+        "    seen.append(k)\n"
+        "    return orig(k, v)\n"
+        "jax.config.update = spy\n"
+        "import ballista_tpu\n"
+        "print(jax.config.jax_compilation_cache_dir)\n"
+        "print('jax_compilation_cache_dir' in seen)\n"
+        "print(ballista_tpu.resolve_jax_cache_dir())\n"
+    )
+    want = str(tmp_path) if placed else os.path.join(REPO, ".jax_cache")
+    proc = _run_py(
+        code,
+        JAX_COMPILATION_CACHE_DIR=str(tmp_path) if placed else None,
+        BALLISTA_TPU_JAX_CACHE=None,
+    )
+    assert proc.returncode == 0, proc.stderr[-3000:]
+    cache_dir, set_in_code, resolved = proc.stdout.split()
+    assert cache_dir == want and resolved == want
+    assert set_in_code == str(not placed)

@@ -425,8 +425,12 @@ from ballista_tpu.client.context import BallistaContext
 from ballista_tpu.config import BallistaConfig
 from ballista_tpu.scheduler.rest import start_rest_server, stop_rest_server
 
+# file-shuffle tier pinned: on the virtual mesh the query fuses into one
+# stage, which the single-stage bypass runs with no stage bookkeeping, and
+# this test is about the per-stage stats of a multi-stage run
 cfg = (BallistaConfig()
        .with_setting("ballista.shuffle.partitions", "2")
+       .with_setting("ballista.tpu.collective_shuffle", "false")
        .with_setting("ballista.tpu.trace", "on"))
 ctx = BallistaContext.standalone(cfg, n_executors=2)
 n = 4000

@@ -380,3 +380,58 @@ def test_bool_min_max_sum():
     assert bool(res.values[0][0]) is False and bool(res.values[0][1]) is True
     assert bool(res.values[1][0]) is True and bool(res.values[1][1]) is True
     assert int(res.values[2][0]) == 1 and int(res.values[2][1]) == 2
+
+
+@pytest.mark.parametrize("n,slots", [(1000, 12), (70_000, 12), (5000, 300)])
+def test_pallas_onehot_sums_interpreted(monkeypatch, n, slots):
+    """ops/pallas_agg.py, run by the Pallas interpreter (the kernel itself
+    needs a TPU; tests/test_tpu_compile.py compiles it for one): a ragged
+    tail, several grid steps per f32 partial and several partials, rows
+    keyed outside [0, slots) dropped. Small integers sum exactly in f32;
+    f64 columns go through the (hi, lo) split callers use."""
+    from jax.experimental import pallas as pl
+
+    from ballista_tpu.ops import pallas_agg
+
+    real = pl.pallas_call
+    monkeypatch.setattr(
+        pl, "pallas_call", lambda *a, **kw: real(*a, interpret=True, **kw)
+    )
+    pallas_agg._program.cache_clear()
+    r = np.random.default_rng(n)
+    rid = r.integers(-1, slots + 1, n).astype(np.int32)
+    ints = r.integers(0, 100, n).astype(np.float64)
+    money = np.round(r.uniform(900, 105_000, n), 2)
+    hi, lo = pallas_agg.split_hi_lo(jnp.asarray(money))
+    got = np.asarray(
+        pallas_agg.onehot_sums(
+            jnp.asarray(rid), [jnp.asarray(ints), hi, lo], slots
+        )
+    )
+    pallas_agg._program.cache_clear()  # no interpreted program outlives this
+    keep = (rid >= 0) & (rid < slots)
+    want_i = np.bincount(rid[keep], weights=ints[keep], minlength=slots)
+    want_m = np.bincount(rid[keep], weights=money[keep], minlength=slots)
+    np.testing.assert_array_equal(got[:, 0], want_i)
+    np.testing.assert_allclose(got[:, 1] + got[:, 2], want_m, rtol=1e-6)
+
+
+def test_dense_aggregate_does_not_fall_back_from_the_kernel(monkeypatch):
+    """Where the kernel is the path (a TPU backend, >= 1<<20 rows, few
+    slots), a kernel the compiler refuses fails the aggregate: there is no
+    silent switch to the XLA one-hot path (PR 21)."""
+    from ballista_tpu.ops import aggregate as A
+    from ballista_tpu.ops import pallas_agg
+
+    def refused(n, R, P):
+        raise RuntimeError("Mosaic refused the kernel")
+
+    monkeypatch.setattr(pallas_agg, "available", lambda: True)
+    monkeypatch.setattr(pallas_agg, "_program", refused)
+    n = A._PALLAS_MIN_ROWS
+    codes = jnp.zeros(n, jnp.int32)
+    with pytest.raises(RuntimeError, match="Mosaic refused"):
+        A._dense_agg(
+            [codes], [None], (3,), jnp.ones(n, bool),
+            [jnp.ones(n, jnp.float64)], [None], (AggOp.SUM,),
+        )

@@ -1,4 +1,4 @@
-"""Multi-device mesh tier tests (virtual 8-device CPU mesh, subprocess).
+"""Multi-device mesh tier tests (virtual 4-device CPU mesh, subprocess).
 
 Covers the ICI shuffle exchange (bucket + all_to_all), the repartitioned
 aggregate (partial -> exchange -> final merge), the PARTITIONED join, and
@@ -24,8 +24,9 @@ from ballista_tpu.parallel import (
     MeshStageRunner, make_mesh, shard_batch, unshard_batch,
 )
 
-assert len(jax.devices()) == 8, jax.devices()
-mesh = make_mesh(8)
+N_DEV = 4
+assert len(jax.devices()) == N_DEV, jax.devices()
+mesh = make_mesh(N_DEV)
 runner = MeshStageRunner(mesh)
 rng = np.random.default_rng(13)
 """
@@ -47,7 +48,7 @@ def run_script(body: str):
 
 def test_exchange_routes_every_row_once():
     out = run_script(r"""
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 from ballista_tpu.parallel.collective import exchange_by_key
 from ballista_tpu.parallel.mesh import SHARD_AXIS
@@ -56,11 +57,11 @@ n = 4000
 t = pa.table({"k": pa.array(rng.integers(0, 101, n)),
               "v": pa.array(np.arange(n, dtype=np.int64))})
 sb = shard_batch(mesh, batch_from_arrow(t))
-cap_local = sb.capacity // 8
+cap_local = sb.capacity // N_DEV
 
 def f(cols, valid):
     c, _, v, ovf = exchange_by_key(
-        cols, (None, None), valid, (0,), SHARD_AXIS, 8, cap_local
+        cols, (None, None), valid, (0,), SHARD_AXIS, N_DEV, cap_local
     )
     return c, v, ovf.reshape(1)
 
@@ -68,7 +69,7 @@ sm = jax.jit(shard_map(
     f, mesh=mesh,
     in_specs=((P(SHARD_AXIS), P(SHARD_AXIS)), P(SHARD_AXIS)),
     out_specs=((P(SHARD_AXIS), P(SHARD_AXIS)), P(SHARD_AXIS), P(SHARD_AXIS)),
-    check_rep=False,
+    check_vma=False,
 ))
 (k2, v2), valid2, ovf = sm(sb.columns, sb.valid)
 assert not np.any(np.asarray(ovf))
@@ -76,12 +77,12 @@ k2, v2, valid2 = map(np.asarray, (k2, v2, valid2))
 # every original row appears exactly once after the exchange
 got = sorted(v2[valid2].tolist())
 assert got == list(range(n)), (len(got), n)
-# routing invariant: rows on device d are exactly those with hash(k)%8==d
+# routing invariant: rows on device d are exactly those with hash(k)%N_DEV==d
 from ballista_tpu.ops.hashing import hash_columns
 import jax.numpy as jnp
-pid = np.asarray(hash_columns([jnp.asarray(k2)]) % jnp.uint64(8)).astype(int)
+pid = np.asarray(hash_columns([jnp.asarray(k2)]) % jnp.uint64(N_DEV)).astype(int)
 glob_cap = len(valid2)
-dev = np.arange(glob_cap) // (glob_cap // 8)
+dev = np.arange(glob_cap) // (glob_cap // N_DEV)
 assert np.all(pid[valid2] == dev[valid2])
 print("EXCHANGE-OK")
 """)
@@ -153,7 +154,7 @@ def test_graft_entry_dryrun():
             "import __graft_entry__ as g\n"
             "fn, args = g.entry()\n"
             "jax.jit(fn)(*args)\n"
-            "g.dryrun_multichip(8)\n"
+            "g.dryrun_multichip(4)\n"
             "print('DRYRUN-OK')\n",
         ],
         env=CPU_MESH_ENV,

@@ -3,9 +3,14 @@
 The Flight server must not materialize a whole shuffle partition
 (flight_service read_all was an OOM at SF=100 widths), and the shuffle
 reader must re-chunk a batch stream without accumulating the partition.
-Peak-RSS growth while streaming a partition much larger than any single
-batch is asserted in a SUBPROCESS (VmHWM is per-process monotonic, so the
-parent's own high-water mark cannot mask the measurement).
+Heap growth while streaming a partition much larger than any single batch
+is asserted in a SUBPROCESS, as the peak of RssAnon sampled after every
+batch. Anonymous memory, not VmHWM: the Flight server serves the file off a
+memory map (flight_service.do_get), and on a host with free memory every
+page the stream touched stays resident until the map closes, so total RSS
+rises by the whole file although nothing copied it. A ``read_all``
+regression holds its copy on the heap for the whole iteration, which the
+per-batch samples see.
 
 ref: flight_service.rs:203-228 (batch channel), shuffle_reader.rs:44-294.
 """
@@ -23,15 +28,11 @@ import numpy as np
 import pyarrow as pa
 import pyarrow.ipc as paipc
 
-def hwm_kb():
+def anon_kb():
     for line in open("/proc/self/status"):
-        if line.startswith("VmHWM"):
+        if line.startswith("RssAnon"):
             return int(line.split()[1])
-    # kernels without VmHWM (some container hosts): ru_maxrss is the same
-    # per-process monotonic high-water mark, in KB on Linux
-    import resource
-
-    return resource.getrusage(resource.RUSAGE_SELF).ru_maxrss
+    raise SystemExit("no RssAnon in /proc/self/status")
 
 # ~256MB shuffle partition in 2MB record batches
 tmp = tempfile.mkdtemp()
@@ -73,18 +74,18 @@ schema2 = Schema([Field("k", DataType.INT64), Field("v", DataType.FLOAT64)])
 plan = ShuffleReaderExec([[remote]], schema2)
 ctx = TaskContext(config=BallistaConfig())
 
-base = hwm_kb()
+base = peak = anon_kb()
 total = 0
 for b in plan.execute(0, ctx):
     total += int(np.asarray(b.count_valid()))
-growth_mb = (hwm_kb() - base) / 1024
+    peak = max(peak, anon_kb())
+growth_mb = (peak - base) / 1024
 assert total == rows_per * n_batches, (total, rows_per * n_batches)
 # streaming bound: growth must stay well under the 256MB partition. The
 # pre-fix read_all path measured >2x the partition (server copy + client
-# copy + table assembly); streaming measures ~120-175MB here depending on
-# allocator high-water noise (server and client share this process), so
-# 180 keeps a hard non-materialization bound without flaking on the band
-assert growth_mb < 180, f"peak RSS grew {growth_mb:.0f}MB for a {file_mb:.0f}MB partition"
+# copy + table assembly); server and client share this process, and the
+# first batches also pay the backend's start-up and kernel compiles
+assert growth_mb < 180, f"peak heap grew {growth_mb:.0f}MB for a {file_mb:.0f}MB partition"
 print(f"STREAM-OK total={total} growth={growth_mb:.0f}MB file={file_mb:.0f}MB")
 """
 

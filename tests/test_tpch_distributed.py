@@ -153,12 +153,11 @@ def test_distributed_match_local_mesh():
     plan). So: 4 virtual devices and a representative shape subset —
     dense agg (q1), join+agg (q3), 6-way join (q5), filter-sum (q6),
     join+projection agg (q14), semi-join (q18). The full 22 still run
-    distributed in the file-shuffle variant above, and the 8-device mesh
-    program shapes run in the driver's dryrun_multichip(8); on real
+    distributed in the file-shuffle variant above, and the mesh program
+    shapes run in the dryrun (dryrun_multichip(4)); on real
     multi-chip hardware (cached compiles, real cores) the full sweep
     applies."""
-    env = dict(CPU_MESH_ENV)
-    env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+    env = dict(CPU_MESH_ENV)  # 4 virtual devices
     env["BALLISTA_TEST_QUERIES"] = "1,3,5,6,14,18"
     proc = _run_distributed(env)
     assert proc.returncode == 0, (

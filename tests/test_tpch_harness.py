@@ -17,7 +17,7 @@ from tests.conftest import CPU_MESH_ENV
 HARNESS = str(Path(__file__).resolve().parent.parent / "benchmarks" / "tpch.py")
 
 # single-device CPU: the harness exercises the engine CLI, not the mesh
-# tier (whose 8-device env is covered by test_mesh_sql)
+# tier (whose virtual-mesh env is covered by test_mesh_sql)
 ENV = {k: v for k, v in CPU_MESH_ENV.items() if k != "XLA_FLAGS"}
 
 
@@ -97,12 +97,13 @@ def test_benchmark_ballista_remote(tmp_path):
         s.bind(("127.0.0.1", 0))
         port = s.getsockname()[1]
     procs = []
+    # to a file, not to a pipe nobody drains (see test_lifecycle.cluster_procs)
+    log = open(tmp_path / "daemons.log", "w")
     try:
         procs.append(subprocess.Popen(
             [sys.executable, "-m", "ballista_tpu.scheduler",
              "--bind-host", "127.0.0.1", "--bind-port", str(port)],
-            env=ENV, stdout=subprocess.PIPE,
-            stderr=subprocess.STDOUT, text=True,
+            env=ENV, stdout=log, stderr=subprocess.STDOUT,
         ))
         time.sleep(2)
         procs.append(subprocess.Popen(
@@ -110,8 +111,7 @@ def test_benchmark_ballista_remote(tmp_path):
              "--bind-host", "127.0.0.1", "--external-host", "127.0.0.1",
              "--bind-port", "0", "--bind-grpc-port", "0",
              "--scheduler-host", "127.0.0.1", "--scheduler-port", str(port)],
-            env=ENV, stdout=subprocess.PIPE,
-            stderr=subprocess.STDOUT, text=True,
+            env=ENV, stdout=log, stderr=subprocess.STDOUT,
         ))
         time.sleep(3)
         out = _run(
@@ -127,3 +127,4 @@ def test_benchmark_ballista_remote(tmp_path):
                 p.wait(timeout=10)
             except subprocess.TimeoutExpired:
                 p.kill()
+        log.close()

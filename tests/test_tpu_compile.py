@@ -1,0 +1,206 @@
+"""The main path's kernels compile for a TPU v5e that is described, not
+attached (PERF.md, PR 21; the `on-chip-measurement` guide, section 2).
+
+These are the programs the CPU backend never builds: the Pallas one-hot
+kernel and the branches behind ``jax.default_backend() != "cpu"``, at the
+widths TPC-H SF=1 q1/q3 produce, plus the 4-device ``shard_map`` exchange. A
+compile that passes is not a chip run (``chip_smoke.py`` is); it guards every
+later PR against a kernel the chip's compiler refuses, at no chip time.
+
+Sort programs are kept at capacities that compile in seconds: one large sort
+costs this compiler 16-190 s (PERF.md), which is a finding, not a test.
+
+The topology is described inside a module-scoped fixture: only one process may
+load the TPU's library, so nothing here touches it at import or collection.
+"""
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import Mesh, NamedSharding, PartitionSpec, SingleDeviceSharding
+
+N = 1 << 20  # the batch capacity q1's partial aggregate sees at SF=1
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    try:
+        t = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — whatever keeps the library away
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # an entry written for a described chip cannot be read back without one:
+    # the next compile would warn and compile again
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield t
+    jax.config.update("jax_enable_compilation_cache", was)
+    cc.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def S(topo):
+    """``S(shape, dtype)``: an abstract argument placed on the first chip."""
+    one = SingleDeviceSharding(topo.devices[0])
+    return lambda shape, dtype: jax.ShapeDtypeStruct(
+        shape, jnp.dtype(dtype), sharding=one
+    )
+
+
+def _compile(fn, *args):
+    f = fn if hasattr(fn, "lower") else jax.jit(fn)
+    compiled = f.lower(*args).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes < 12 << 30
+    return compiled.as_text()
+
+
+def test_pallas_onehot_kernel_q1_shape(S):
+    """(n, R, P) as q1 at SF=1 produces it: 9 live flags + 5 f64 sums as
+    (hi, lo) pairs = 19 rows, 12 slots."""
+    from ballista_tpu.ops import pallas_agg
+
+    text = _compile(
+        pallas_agg._program(N, 19, 12), S((1, N), "int32"), S((19, N), "float32")
+    )
+    assert "tpu_custom_call" in text
+
+
+def test_q1_dense_aggregate_lowers_through_the_kernel(S, monkeypatch):
+    from ballista_tpu.ops import aggregate as A
+    from ballista_tpu.ops import pallas_agg
+    from ballista_tpu.ops.aggregate import AggOp
+
+    # the TPU branch: jax.default_backend() is "cpu" in this process
+    monkeypatch.setattr(pallas_agg, "available", lambda: True)
+    ops = (AggOp.SUM,) * 4 + (
+        AggOp.COUNT, AggOp.COUNT, AggOp.SUM, AggOp.COUNT, AggOp.COUNT,
+    )
+
+    def q1_partial(codes, valid, vals):
+        return A._dense_agg(
+            list(codes), [None, None], (3, 2), valid, list(vals),
+            [None] * 9, ops,
+        )
+
+    text = _compile(
+        q1_partial,
+        (S((N,), "int32"), S((N,), "int32")),
+        S((N,), "bool"),
+        tuple(S((N,), "float64") for _ in range(9)),
+    )
+    assert "tpu_custom_call" in text
+
+
+def test_float_prefix_takes_the_matmul_branch(S):
+    from ballista_tpu.ops import aggregate as A
+
+    _compile(
+        lambda x: A._mm_prefix(x, A._PREFIX_BLOCK), S((2 * N, 2), "float64")
+    )
+
+
+def test_searchsorted_sort_method(S):
+    """ops/search.py picks method='sort' on accelerators."""
+    _compile(
+        lambda a, v: jnp.searchsorted(a, v, method="sort"),
+        S((4096,), "int64"), S((4096,), "int64"),
+    )
+
+
+@pytest.mark.parametrize("dtype", ["int64", "float64"])
+def test_argsort_pass_program(S, dtype):
+    from ballista_tpu.ops import perm
+
+    prog = perm._argsort_program(dtype, 8192, True, dtype == "float64")
+    assert " sort(" in _compile(prog, S((8192,), dtype))
+
+
+def test_stacked_gather_and_scatter_at_lineitem_capacity(S):
+    from ballista_tpu.ops import aggregate as A
+    from ballista_tpu.ops import perm
+
+    n = 2 * N
+    cols = (
+        S((n,), "int64"), S((n,), "int64"), S((n,), "float64"),
+        S((n,), "float64"), S((n,), "int32"),
+    )
+    _compile(
+        lambda c, valid, p: perm.take_many_split([valid] + list(c), [], p),
+        cols, S((n,), "bool"), S((n,), "int32"),
+    )
+    _compile(
+        lambda rid, c: A._stacked_scatter_set(rid, 1 << 17, list(c)),
+        S((n,), "int32"), tuple(S((n,), "float64") for _ in range(4)),
+    )
+
+
+def test_hash_partition_ids(S):
+    from ballista_tpu.ops import partition
+
+    n = 2 * N
+    _compile(
+        lambda a, b, valid: partition.partition_ids_for(
+            [a, b], [None, None], valid, 2
+        ),
+        S((n,), "int64"), S((n,), "int32"), S((n,), "bool"),
+    )
+
+
+@pytest.fixture
+def tpu_branches(monkeypatch):
+    """Code that asks ``jax.default_backend()`` sees the CPU in this process
+    and would take its CPU branch (an f64 ``cumsum``, which alone costs the
+    TPU compiler two minutes); steer it here, in the test."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+
+
+def test_filter_projection_sorted_aggregate_chain(S, tpu_branches):
+    """``__graft_entry__.entry()``'s step: filter, projected measures and the
+    sort-based grouped aggregate as one program."""
+    from ballista_tpu.ops.aggregate import AggOp, group_aggregate
+
+    def step(flag, qty, price, disc, valid):
+        live = valid & (disc >= 0.02) & (disc <= 0.09)
+        res = group_aggregate(
+            [flag], [None], live, [qty, price, price * (1.0 - disc), qty],
+            [None] * 4, [AggOp.SUM, AggOp.SUM, AggOp.SUM, AggOp.COUNT],
+            capacity=64,
+        )
+        return res.keys[0], res.values[0], res.values[2], res.n_groups
+
+    n = 4096
+    text = _compile(
+        step, S((n,), "int32"), S((n,), "float64"), S((n,), "float64"),
+        S((n,), "float64"), S((n,), "bool"),
+    )
+    assert " sort(" in text and "cumsum" not in text
+
+
+def test_mesh_aggregate_stage_on_four_chips(topo, tpu_branches):
+    """One whole stage program of parallel/stage.py (partial aggregate,
+    hash exchange, final merge) on a 4-device mesh of the described chips:
+    it compiles, and the all-to-all is in it."""
+    import types
+
+    from ballista_tpu.ops.aggregate import AggOp
+    from ballista_tpu.parallel.mesh import SHARD_AXIS
+    from ballista_tpu.parallel.stage import MeshStageRunner
+
+    mesh = Mesh(np.array(topo.devices), (SHARD_AXIS,))
+    rows = NamedSharding(mesh, PartitionSpec(SHARD_AXIS))
+    n = 4 * 4096
+
+    def arg(dt):
+        return jax.ShapeDtypeStruct((n,), jnp.dtype(dt), sharding=rows)
+
+    cols = (arg("int32"), arg("int32"), arg("float64"), arg("float64"))
+    nulls = (None,) * 4
+    prog = MeshStageRunner(mesh)._compile_aggregate(
+        types.SimpleNamespace(columns=cols, nulls=nulls),
+        (0, 1), (2, 3, 2), (AggOp.SUM, AggOp.SUM, AggOp.COUNT), 2048, 2048,
+    )
+    assert "all-to-all" in _compile(prog, cols, nulls, arg("bool"))

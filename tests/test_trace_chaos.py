@@ -205,7 +205,7 @@ print("TRACE-CHAOS-OK")
 @pytest.mark.chaos
 def test_executor_kill_recovery_produces_connected_span_tree():
     # single CPU device: stage plans keep real shuffle boundaries (the
-    # 8-device mesh env fuses whole chains into near-instant single-stage
+    # virtual-mesh env fuses whole chains into near-instant single-stage
     # plans, leaving no mid-query kill window)
     env = {k: v for k, v in CPU_MESH_ENV.items() if k != "XLA_FLAGS"}
     proc = subprocess.run(

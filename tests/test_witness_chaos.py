@@ -48,7 +48,7 @@ cfg = (
     BallistaConfig()
     .with_setting("ballista.tpu.fetch_backoff_ms", "10")
     .with_setting("ballista.shuffle.partitions", "2")
-    # force real shuffle stages: under the 8-device CPU mesh env the
+    # force real shuffle stages: under the virtual CPU mesh env the
     # planner would otherwise fuse q3 into ONE mesh stage — no shuffle
     # output to lose, no recovery path for the witness to observe
     .with_setting("ballista.tpu.collective_shuffle", "false")
