@@ -165,35 +165,45 @@ class BallistaContext(TpuContext):
 
         if _scans_system_table(logical):
             return DataFrame(self, logical).collect()
-        if self.config.verify_plans():
-            # client-side gate: a plan that cannot execute fails HERE with
-            # an operator path (and SQL span when known) instead of as an
-            # opaque failed-job error from an executor. The scheduler
-            # re-verifies its physical/stage plans server-side.
-            from ballista_tpu.analysis import verify_logical
-            from ballista_tpu.plan.optimizer import optimize
+        from ballista_tpu.obs import trace as obs_trace
 
-            verify_logical(optimize(logical), sql=sql)
-        node = logical_to_proto(logical)
-        result = self._stub.ExecuteQuery(
-            pb.ExecuteQueryParams(
-                logical_plan=node.SerializeToString(),
-                session_id=self.session_id,
-                settings=[
-                    pb.KeyValuePair(key=k, value=v)
-                    for k, v in self.config.settings().items()
-                ],
+        with obs_trace.phase("client.submit"):
+            if self.config.verify_plans():
+                # client-side gate: a plan that cannot execute fails HERE
+                # with an operator path (and SQL span when known) instead
+                # of as an opaque failed-job error from an executor. The
+                # scheduler re-verifies its physical/stage plans
+                # server-side.
+                from ballista_tpu.analysis import verify_logical
+                from ballista_tpu.plan.optimizer import optimize
+
+                verify_logical(optimize(logical), sql=sql)
+            node = logical_to_proto(logical)
+            result = self._stub.ExecuteQuery(
+                pb.ExecuteQueryParams(
+                    logical_plan=node.SerializeToString(),
+                    session_id=self.session_id,
+                    settings=[
+                        pb.KeyValuePair(key=k, value=v)
+                        for k, v in self.config.settings().items()
+                    ],
+                )
             )
-        )
         job_id = result.job_id
         deadline = time.time() + 600
+        # the poll sleep below is deliberately NO phase: the client always
+        # sleeps while the executor works, and on a trace its span would
+        # take every idle gap's label (docs/observability.md)
         while True:
             status = self._stub.GetJobStatus(
                 pb.GetJobStatusParams(job_id=job_id)
             ).status
             kind = status.WhichOneof("status")
             if kind == "completed":
-                return self._fetch_results(status.completed, logical)
+                with obs_trace.phase("client.fetch_results") as ph:
+                    table = self._fetch_results(status.completed, logical)
+                    ph.nbytes = table.nbytes
+                return table
             if kind == "failed":
                 raise BallistaError(
                     f"job {job_id} failed: {status.failed.error}"

@@ -177,14 +177,18 @@ def _column_to_np(
 
 def batch_from_arrow(rb: pa.RecordBatch | pa.Table, capacity: int | None = None) -> DeviceBatch:
     """One Arrow batch/table -> one DeviceBatch."""
+    from ballista_tpu.obs import trace as obs_trace
+
     schema = schema_from_arrow(rb.schema)
     arrays, nulls, dicts = [], [], {}
-    for field, name in zip(schema, rb.schema.names):
-        arr, nm, d = _column_to_np(rb.column(name), field.dtype)
-        arrays.append(arr)
-        nulls.append(nm)
-        if d is not None:
-            dicts[field.name] = d
+    with obs_trace.phase("task.scan_host") as ph:
+        for field, name in zip(schema, rb.schema.names):
+            arr, nm, d = _column_to_np(rb.column(name), field.dtype)
+            ph.nbytes += arr.nbytes
+            arrays.append(arr)
+            nulls.append(nm)
+            if d is not None:
+                dicts[field.name] = d
     return DeviceBatch.from_host(
         schema, arrays, num_rows=rb.num_rows, dictionaries=dicts, nulls=nulls,
         capacity=capacity,
@@ -228,20 +232,25 @@ def table_from_arrow(
     ``fixed_dicts``: {column name: Dictionary} pre-built dictionaries for
     STRING columns — the streaming scan passes its whole-file vocabulary
     so every slice encodes identical codes (see _column_to_np)."""
+    from ballista_tpu.obs import trace as obs_trace
+
     schema = schema_from_arrow(table.schema)
-    if narrow_cols is None:
-        narrow_cols = narrowable_int64_cols(table)
     # Encode strings table-wide so all slices share dictionaries.
     cols_np, nulls_np, dicts = [], [], {}
-    for field, name in zip(schema, table.schema.names):
-        arr, nm, d = _column_to_np(
-            table.column(name), field.dtype, narrow=name in narrow_cols,
-            fixed_dict=(fixed_dicts or {}).get(name),
-        )
-        cols_np.append(arr)
-        nulls_np.append(nm)
-        if d is not None:
-            dicts[field.name] = d
+    # Arrow to the host numpy the device will hold: "task.scan_host"
+    with obs_trace.phase("task.scan_host") as ph:
+        if narrow_cols is None:
+            narrow_cols = narrowable_int64_cols(table)
+        for field, name in zip(schema, table.schema.names):
+            arr, nm, d = _column_to_np(
+                table.column(name), field.dtype, narrow=name in narrow_cols,
+                fixed_dict=(fixed_dicts or {}).get(name),
+            )
+            ph.nbytes += arr.nbytes
+            cols_np.append(arr)
+            nulls_np.append(nm)
+            if d is not None:
+                dicts[field.name] = d
     n = table.num_rows
     if n == 0:
         return [DeviceBatch.empty(schema)]
@@ -259,9 +268,12 @@ def table_from_arrow(
     return out
 
 
-def batch_to_arrow(batch: DeviceBatch) -> pa.RecordBatch:
-    """Gather live rows to host and decode dictionaries back to strings."""
-    schema, cols, nulls = batch.to_host()
+def batch_to_arrow(
+    batch: DeviceBatch, site: str = "to_arrow"
+) -> pa.RecordBatch:
+    """Gather live rows to host and decode dictionaries back to strings.
+    ``site`` names the caller for the device read (DeviceBatch.to_host)."""
+    schema, cols, nulls = batch.to_host(site)
     arrays = []
     import pyarrow.compute as pc
 

@@ -220,34 +220,43 @@ class DeviceBatch:
         cap = capacity if capacity is not None else round_capacity(n)
         if cap < n:
             raise InternalError(f"capacity {cap} < num_rows {n}")
-        cols = []
-        for field, arr in zip(schema, arrays):
-            want = field.dtype.to_np()
-            a = np.asarray(arr)
-            if a.dtype != want and not (
-                want == np.int64 and a.dtype == np.int32
-            ):
-                # int32 is a permitted physical form of a logical INT64
-                # column (see arrow_interop narrowing)
-                a = a.astype(want)
-            padded = np.zeros(cap, dtype=a.dtype)
-            padded[:n] = a[:n]
-            cols.append(jnp.asarray(padded))
-        valid = np.zeros(cap, dtype=bool)
-        valid[:n] = True
-        null_cols: list[jnp.ndarray | None] = []
-        for i in range(len(schema)):
-            nm = None if nulls is None else nulls[i]
-            if nm is None:
-                null_cols.append(None)
-            else:
-                pm = np.zeros(cap, dtype=bool)
-                pm[:n] = np.asarray(nm, dtype=bool)[:n]
-                null_cols.append(jnp.asarray(pm))
+        # the host-to-device funnel (obs.trace.phase "task.h2d"): pad on
+        # the host, hand each padded array to the device
+        from ballista_tpu.obs import trace as obs_trace
+
+        with obs_trace.phase("task.h2d") as ph:
+            cols = []
+            for field, arr in zip(schema, arrays):
+                want = field.dtype.to_np()
+                a = np.asarray(arr)
+                if a.dtype != want and not (
+                    want == np.int64 and a.dtype == np.int32
+                ):
+                    # int32 is a permitted physical form of a logical
+                    # INT64 column (see arrow_interop narrowing)
+                    a = a.astype(want)
+                padded = np.zeros(cap, dtype=a.dtype)
+                padded[:n] = a[:n]
+                ph.nbytes += padded.nbytes
+                cols.append(jnp.asarray(padded))
+            valid = np.zeros(cap, dtype=bool)
+            valid[:n] = True
+            null_cols: list[jnp.ndarray | None] = []
+            for i in range(len(schema)):
+                nm = None if nulls is None else nulls[i]
+                if nm is None:
+                    null_cols.append(None)
+                else:
+                    pm = np.zeros(cap, dtype=bool)
+                    pm[:n] = np.asarray(nm, dtype=bool)[:n]
+                    ph.nbytes += pm.nbytes
+                    null_cols.append(jnp.asarray(pm))
+            valid = jnp.asarray(valid)
+            ph.nbytes += cap
         return cls(
             schema=schema,
             columns=tuple(cols),
-            valid=jnp.asarray(valid),
+            valid=valid,
             nulls=tuple(null_cols),
             dictionaries=dict(dictionaries or {}),
         )
@@ -287,9 +296,12 @@ class DeviceBatch:
         """Number of live rows, as a device scalar."""
         return jnp.sum(self.valid.astype(jnp.int32))
 
-    def num_rows(self) -> int:
-        """Number of live rows, blocking on device (host-side use only)."""
-        return int(self.count_valid())
+    def num_rows(self, site: str = "num_rows") -> int:
+        """Number of live rows, blocking on device (host-side use only);
+        ``site`` names the caller for the ``task.d2h`` counters."""
+        from ballista_tpu.ops.fetch import read_array
+
+        return int(read_array(self.count_valid(), site))
 
     def with_columns(
         self,
@@ -344,10 +356,14 @@ class DeviceBatch:
     # is not measured on the attached chip).
     _SLICED_FETCH_BYTES = 4 << 20
 
-    def to_host(self) -> tuple[Schema, list[np.ndarray], list[np.ndarray | None]]:
+    def to_host(
+        self, site: str = "to_host"
+    ) -> tuple[Schema, list[np.ndarray], list[np.ndarray | None]]:
         """Gather live rows back to host (compacts: drops invalid rows).
 
         Returns (schema, columns, null_masks) with exact row count.
+        ``site`` names the caller for the ``task.d2h`` counters
+        (ops/fetch.py); the count sync reads as ``<site>.count``.
 
         Two fetch strategies, chosen by padded size: small batches fetch
         the whole capacity in ONE batched device_get (a single host round
@@ -375,7 +391,9 @@ class DeviceBatch:
             # far more than the one round trip it saves).
             n = getattr(self, "host_rows_max", None)
             if n is None or n * 4 > self.capacity:
-                n = int(fetch_arrays([self.count_valid()])[0])
+                n = int(
+                    fetch_arrays([self.count_valid()], site=f"{site}.count")[0]
+                )
             if n * 4 <= self.capacity:
                 from ballista_tpu.ops.compact import compact
 
@@ -384,7 +402,8 @@ class DeviceBatch:
                     m <<= 1
                 b = compact(self).head(m)
         fetched = fetch_arrays(
-            [b.valid, *b.columns, *[m for m in b.nulls if m is not None]]
+            [b.valid, *b.columns, *[m for m in b.nulls if m is not None]],
+            site=site,
         )
         valid = fetched[0]
         cols_h = fetched[1 : 1 + len(b.columns)]

@@ -20,6 +20,9 @@ telemetry uses) and keeps process-global counters:
   cache (compilecache.tracecache), recorded by that module.
 - ``prewarmed_signatures`` / ``prewarm_seconds`` — AOT prewarm progress
   (compilecache.prewarm).
+- ``phase.<name>.seconds`` / ``.count`` / ``.bytes`` — the host phases of
+  the served path (``obs.trace.phase``, docs/observability.md), and for
+  ``task.d2h`` the same three per call site (``...:<site>``).
 
 Counters surface per executor through the heartbeat -> scheduler REST
 path (docs/compile_cache.md) and per query through bench.py's tracked
@@ -54,6 +57,13 @@ def add(name: str, value: float = 1) -> None:
     """Record a counter increment (used by tracecache/prewarm too)."""
     with _LOCK:
         _COUNTERS[name] = _COUNTERS.get(name, 0) + value
+
+
+def add_many(pairs) -> None:
+    """Several increments under one lock acquisition (obs.trace.phase)."""
+    with _LOCK:
+        for name, value in pairs:
+            _COUNTERS[name] = _COUNTERS.get(name, 0) + value
 
 
 def _on_event(event: str, **kw) -> None:

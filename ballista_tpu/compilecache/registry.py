@@ -32,9 +32,10 @@ from typing import Callable
 
 # -- the kernel vocabulary ---------------------------------------------------
 #
-# Keys match static_signature_report: "<pkg>.<module>.<jitted function>"
-# (factory-inner functions report under their def name; lambda-jitted
-# helpers inside the same factories ride the factory's entry). ``aot``
+# Keys match static_signature_report: "<pkg>.<module>.<jitted function>".
+# The function's name is also the program's name on a device trace
+# (``jit_<name>`` on the ``XLA Modules`` line, docs/observability.md), so
+# every jitted function says operator and step and none is a lambda. ``aot``
 # names the prewarm strategy: "lower" (fixed avals -> lower().compile()),
 # "execute" (composition-derived dtypes -> one zeros-execution through the
 # public path), None (signature depends on plan content — expressions,
@@ -50,20 +51,44 @@ class KernelSpec:
 
 VOCABULARY: dict[str, KernelSpec] = {
     # ops/: the closed data-movement + kernel substrate
-    "ops.perm.f": KernelSpec(
-        "lower", "argsort / stacked-gather passes per (dtype, capacity)"
+    "ops.perm.sort_argsort": KernelSpec(
+        "lower", "one stable argsort pass per (dtype, capacity)"
+    ),
+    "ops.perm.perm_take": KernelSpec(
+        "execute", "one-column gather per (dtype, capacity)"
+    ),
+    "ops.perm.perm_take_batch": KernelSpec(
+        None, "stacked gather of a column set (dtypes + null layout)"
+    ),
+    "ops.compact.compact_invalid": KernelSpec(
+        "execute", "invalid flags for the compaction sort, per capacity"
+    ),
+    "ops.compact.compact_front_valid": KernelSpec(
+        "execute", "front-packed valid mask, per capacity"
     ),
     "ops.concat._concat_device": KernelSpec(
         None, "operand count + per-column dtypes of the concatenated set"
     ),
-    "ops.fetch.f": KernelSpec(
-        None, "fetched-array count/dtypes (host materialization packing)"
+    "ops.fetch.fetch_flat": KernelSpec(
+        None, "one fetched array flattened (host materialization)"
+    ),
+    "ops.fetch.fetch_concat": KernelSpec(
+        None, "fetched-array count of one dtype (host materialization)"
+    ),
+    "ops.fetch.fetch_concat_f64": KernelSpec(
+        None, "fetched-array count/dtypes widened into one f64 buffer"
     ),
     "ops.join._build_finish": KernelSpec(
         None, "static key indexes + build mode from the join plan"
     ),
-    "ops.join.f": KernelSpec(
-        None, "probe key indexes + join kind from the join plan"
+    "ops.join.join_build_prep": KernelSpec(
+        None, "build key indexes + pack mode: the sort-pass operands"
+    ),
+    "ops.join.join_exact2_range": KernelSpec(
+        None, "two-int-key pack range check, per capacity"
+    ),
+    "ops.join.join_lut": KernelSpec(
+        None, "direct-address probe table (key range, build capacity)"
     ),
     "ops.aggregate._seg_part1": KernelSpec(
         None, "static op/layout tuples from the aggregate spec"
@@ -77,52 +102,125 @@ VOCABULARY: dict[str, KernelSpec] = {
     "ops.aggregate._scalar_agg": KernelSpec(
         None, "static op tuple from the aggregate spec"
     ),
-    "ops.pallas_agg.f": KernelSpec(
+    "ops.aggregate.agg_zero_null_keys": KernelSpec(
+        None, "NULL-key zeroing per (key dtype, capacity)"
+    ),
+    "ops.aggregate.agg_invalid": KernelSpec(
+        None, "invalid flags for the group sort, per capacity"
+    ),
+    "ops.pallas_agg.agg_onehot_sums": KernelSpec(
         None, "pallas segment-reduction tile layout (TPU-only path)"
     ),
     # exec/: operator-level programs (expression/schema parameterized)
-    "exec.pipeline.run": KernelSpec(
+    "exec.pipeline.pipeline_filter": KernelSpec(
+        None, "one filter predicate + input schema"
+    ),
+    "exec.pipeline.pipeline_project": KernelSpec(
+        None, "one projection's expressions + input schema"
+    ),
+    "exec.pipeline.pipeline_fused": KernelSpec(
         None, "fused filter/projection chain expressions + input schema"
     ),
-    "exec.repartition.f": KernelSpec(
+    "exec.repartition.repartition_hash": KernelSpec(
         None, "hash key indexes + partition count from the plan"
     ),
-    "exec.aggregate.f": KernelSpec(
+    "exec.repartition.repartition_mask": KernelSpec(
+        None, "hash key indexes + partition count; one partition's mask"
+    ),
+    "exec.aggregate.agg_ones": KernelSpec(None, "count column, per capacity"),
+    "exec.aggregate.agg_dec_learn": KernelSpec(
+        None, "decimal-scale discovery per (capacity, null layout)"
+    ),
+    "exec.aggregate.agg_dec_scale": KernelSpec(
+        None, "decimal scaling to int64 per (capacity, scale)"
+    ),
+    "exec.aggregate.agg_dec_unscale": KernelSpec(
+        None, "scaled sum columns back to value units, per layout"
+    ),
+    "exec.aggregate.agg_state_bounds": KernelSpec(
+        None, "key bounds of a single-int-key state"
+    ),
+    "exec.aggregate.agg_boundary_merge": KernelSpec(
+        None, "merge of two clustered states' boundary group"
+    ),
+    "exec.aggregate.agg_state_batch": KernelSpec(
         None, "aggregate spec (ops, state schema, group exprs)"
     ),
-    "exec.aggregate.scalar_final": KernelSpec(
+    "exec.aggregate.agg_scalar_state": KernelSpec(
+        None, "scalar aggregate slots + input schema"
+    ),
+    "exec.aggregate.agg_scalar_final": KernelSpec(
         None, "aggregate finals layout"
     ),
-    "exec.joins.f": KernelSpec(None, "join keys/kind from the plan"),
-    "exec.joins.fn": KernelSpec(
-        None, "semi/anti mask + expansion programs (keys, kind, capacity)"
+    "exec.joins.join_probe": KernelSpec(
+        None, "probe key indexes + join kind from the plan"
     ),
-    "exec.joins.run": KernelSpec(
+    "exec.joins.join_probe_counts": KernelSpec(
+        None, "probe key indexes: matches per probe row"
+    ),
+    "exec.joins.join_expand_total": KernelSpec(
+        None, "expansion output rows (LEFT keeps unmatched)"
+    ),
+    "exec.joins.join_semi_mask": KernelSpec(
+        None, "semi/anti mask from match counts (keys, kind)"
+    ),
+    "exec.joins.join_expand": KernelSpec(
         None, "expansion-join body (filter expr, kind, output capacity)"
     ),
-    "exec.sort.f": KernelSpec(None, "fetch bound from the plan"),
-    "exec.shrink.f": KernelSpec(None, "shrink target capacity"),
-    "exec.window.f": KernelSpec(None, "window frame/function layout"),
-    "exec.percentile.f": KernelSpec(None, "quantile set from the plan"),
+    "exec.joins.join_probe_filter": KernelSpec(
+        None, "probe with a residual filter (keys, kind, filter expr)"
+    ),
+    "exec.sort.limit_mask": KernelSpec(None, "fetch bound from the plan"),
+    "exec.shrink.shrink_compact": KernelSpec(None, "shrink target capacity"),
+    "exec.window.window_rank": KernelSpec(
+        None, "ranking function + partition/order null layout"
+    ),
+    "exec.window.window_frame": KernelSpec(
+        None, "window frame/function layout"
+    ),
+    "exec.percentile.percentile_interp": KernelSpec(
+        None, "quantile set from the plan"
+    ),
 }
 
 # Physical operator class -> vocabulary kernels it may dispatch. The gate
 # walks every TPC-H physical/stage plan and fails on an operator class
 # missing here (a NEW operator cannot ship without declaring its compile
 # surface) or a mapping naming an unknown kernel (mappings cannot rot).
-_PIPELINE = ("exec.pipeline.run", "exec.shrink.f", "ops.perm.f")
-_SCAN = ("ops.perm.f", "ops.concat._concat_device")
+_PERM = (
+    "ops.perm.sort_argsort", "ops.perm.perm_take", "ops.perm.perm_take_batch",
+    "ops.compact.compact_invalid", "ops.compact.compact_front_valid",
+)
+_FETCH = (
+    "ops.fetch.fetch_flat", "ops.fetch.fetch_concat",
+    "ops.fetch.fetch_concat_f64",
+)
+_CONCAT = ("ops.concat._concat_device",)
+_PIPELINE = (
+    "exec.pipeline.pipeline_filter", "exec.pipeline.pipeline_project",
+    "exec.pipeline.pipeline_fused", "exec.shrink.shrink_compact",
+) + _PERM
+_SCAN = _PERM + _CONCAT
 _AGG = (
-    "exec.aggregate.f", "exec.aggregate.scalar_final",
+    "exec.aggregate.agg_ones", "exec.aggregate.agg_dec_learn",
+    "exec.aggregate.agg_dec_scale", "exec.aggregate.agg_dec_unscale",
+    "exec.aggregate.agg_state_bounds", "exec.aggregate.agg_boundary_merge",
+    "exec.aggregate.agg_state_batch", "exec.aggregate.agg_scalar_state",
+    "exec.aggregate.agg_scalar_final",
     "ops.aggregate._seg_part1", "ops.aggregate._seg_part2",
     "ops.aggregate._dense_agg", "ops.aggregate._scalar_agg",
-    "ops.pallas_agg.f", "ops.perm.f", "ops.concat._concat_device",
-    "ops.fetch.f",
-)
+    "ops.aggregate.agg_zero_null_keys", "ops.aggregate.agg_invalid",
+    "ops.pallas_agg.agg_onehot_sums",
+) + _PERM + _CONCAT + _FETCH
 _JOIN = (
-    "exec.joins.f", "exec.joins.fn", "exec.joins.run",
-    "ops.join._build_finish", "ops.join.f", "ops.perm.f",
-    "ops.concat._concat_device", "ops.fetch.f",
+    "exec.joins.join_probe", "exec.joins.join_probe_counts",
+    "exec.joins.join_expand_total", "exec.joins.join_semi_mask",
+    "exec.joins.join_expand", "exec.joins.join_probe_filter",
+    "ops.join._build_finish", "ops.join.join_build_prep",
+    "ops.join.join_exact2_range", "ops.join.join_lut",
+) + _PERM + _CONCAT + _FETCH
+_REPARTITION = (
+    "exec.repartition.repartition_hash", "exec.repartition.repartition_mask",
 )
 
 OPERATOR_KERNELS: dict[str, tuple[str, ...]] = {
@@ -137,30 +235,31 @@ OPERATOR_KERNELS: dict[str, tuple[str, ...]] = {
     "ProjectionExec": _PIPELINE,
     "RenameExec": (),
     "CoalescePartitionsExec": (),
-    "UnionExec": ("ops.concat._concat_device",),
+    "UnionExec": _CONCAT,
     # sorts / limits
-    "SortExec": ("exec.sort.f", "ops.perm.f", "ops.concat._concat_device"),
-    "GlobalLimitExec": ("ops.perm.f",),
+    "SortExec": ("exec.sort.limit_mask",) + _PERM + _CONCAT,
+    "GlobalLimitExec": ("exec.sort.limit_mask",) + _PERM,
     # aggregates / joins / windows
     "HashAggregateExec": _AGG,
     "HashJoinExec": _JOIN,
     "CrossJoinExec": _JOIN,
-    "WindowExec": ("exec.window.f", "ops.perm.f"),
-    "PercentileExec": ("exec.percentile.f", "ops.perm.f"),
+    "WindowExec": (
+        "exec.window.window_rank", "exec.window.window_frame",
+    ) + _PERM,
+    "PercentileExec": ("exec.percentile.percentile_interp",) + _PERM,
     # exchange boundary
-    "HashRepartitionExec": ("exec.repartition.f", "ops.perm.f"),
-    "ShuffleWriterExec": (
-        "exec.repartition.f", "ops.perm.f", "ops.fetch.f",
-        "ops.concat._concat_device",
-    ),
-    "ShuffleReaderExec": ("ops.perm.f", "ops.concat._concat_device"),
+    "HashRepartitionExec": _REPARTITION + _PERM,
+    "ShuffleWriterExec": _REPARTITION + _PERM + _FETCH + _CONCAT,
+    "ShuffleReaderExec": _PERM + _CONCAT,
     "UnresolvedShuffleExec": (),
     # mesh tier (shard_map stage programs compile through parallel/stage.py,
     # outside the jaxlint report targets; host-side they reuse ops/)
     "MeshAggregateExec": _AGG,
     "MeshJoinExec": _JOIN,
-    "MeshSortExec": ("exec.sort.f", "ops.perm.f"),
-    "MeshWindowExec": ("exec.window.f", "ops.perm.f"),
+    "MeshSortExec": ("exec.sort.limit_mask",) + _PERM,
+    "MeshWindowExec": (
+        "exec.window.window_rank", "exec.window.window_frame",
+    ) + _PERM,
 }
 
 
@@ -250,7 +349,7 @@ def enumerate_prewarm(
             # flags sort through the int32 program (ops/perm.stable_argsort)
             for desc in (False, True) if dt != "bool" else ():
                 sigs.append(PrewarmSignature(
-                    "ops.perm.f", cap, (dt,),
+                    "ops.perm.sort_argsort", cap, (dt,),
                     variant=f"argsort,desc={int(desc)}",
                     compile=(
                         lambda dt=dt, cap=cap, desc=desc:
@@ -258,11 +357,12 @@ def enumerate_prewarm(
                     ),
                 ))
             sigs.append(PrewarmSignature(
-                "ops.perm.f", cap, (dt,), variant="take",
+                "ops.perm.perm_take", cap, (dt,), variant="take",
                 compile=lambda dt=dt, cap=cap: _warm_sort_pass(dt, cap),
             ))
         sigs.append(PrewarmSignature(
-            "ops.perm.f", cap, ("int64", "float64"), variant="compact",
+            "ops.compact.compact_invalid", cap, ("int64", "float64"),
+            variant="compact",
             compile=lambda cap=cap: _warm_compact(cap),
         ))
     return sigs

@@ -37,7 +37,9 @@ from ballista_tpu.ops.aggregate import (
     group_aggregate,
     scalar_aggregate,
 )
+from ballista_tpu.obs import trace as obs_trace
 from ballista_tpu.ops.concat import concat_batches
+from ballista_tpu.ops.fetch import read_array
 
 
 @dataclasses.dataclass(frozen=True)
@@ -206,7 +208,10 @@ import functools
 
 @functools.lru_cache(maxsize=None)
 def _ones_program(cap: int):
-    return jax.jit(lambda: jnp.ones(cap, dtype=jnp.int64))
+    def agg_ones():
+        return jnp.ones(cap, dtype=jnp.int64)
+
+    return jax.jit(agg_ones)
 
 
 # -- disjoint clustered states (the streaming wide-cardinality path) ---------
@@ -243,7 +248,7 @@ def _dec_learn_program(cap: int, has_null: bool):
     int32 so defer_learn's cross-batch MAX picks a scale covering every
     batch (any 99 vetoes)."""
 
-    def f(col, valid, null):
+    def agg_dec_learn(col, valid, null):
         live = valid & ~null if has_null else valid
         code = jnp.int32(99)
         for k in (6, 4, 2):  # evaluate big->small so `code` ends smallest
@@ -255,7 +260,7 @@ def _dec_learn_program(cap: int, has_null: bool):
             code = jnp.where(ok, jnp.int32(k), code)
         return code
 
-    return jax.jit(f)
+    return jax.jit(agg_dec_learn)
 
 
 @functools.lru_cache(maxsize=None)
@@ -267,7 +272,7 @@ def _dec_scale_program(cap: int, has_null: bool, k: int):
     exact), while the x64 rewrite's int64 arithmetic is exact integer
     math on every backend — the sums come out bit-identical CPU vs TPU."""
 
-    def f(col, valid, null):
+    def agg_dec_scale(col, valid, null):
         live = valid & ~null if has_null else valid
         s = col * float(10 ** k)
         r = jnp.round(s)
@@ -276,7 +281,7 @@ def _dec_scale_program(cap: int, has_null: bool, k: int):
         ok = (dev <= _DEC_TOL) & (total < _DEC_BOUND)
         return jnp.where(live, r, 0.0).astype(jnp.int64), ok
 
-    return jax.jit(f)
+    return jax.jit(agg_dec_scale)
 
 
 @functools.lru_cache(maxsize=None)
@@ -284,13 +289,13 @@ def _dec_unscale_program(sig: tuple):
     """Divide the scaled sum columns back to value units. sig: tuple of
     (col index, scale) pairs — one fused program per layout."""
 
-    def f(cols):
+    def agg_dec_unscale(cols):
         cols = list(cols)
         for i, scale in sig:
             cols[i] = cols[i] / scale
         return tuple(cols)
 
-    return jax.jit(f)
+    return jax.jit(agg_dec_unscale)
 
 
 @functools.lru_cache(maxsize=None)
@@ -302,7 +307,7 @@ def _bounds_program(cap: int, dtype: str, has_null_mask: bool):
     would alias a real key-0 group, so a state carrying one must leave
     the disjoint path."""
 
-    def f(key_col, valid, key_nulls):
+    def agg_state_bounds(key_col, valid, key_nulls):
         n = jnp.sum(valid).astype(jnp.int32)
         big = jnp.iinfo(key_col.dtype).max
         kmin = jnp.min(jnp.where(valid, key_col, big))
@@ -313,7 +318,7 @@ def _bounds_program(cap: int, dtype: str, has_null_mask: bool):
             has_null = jnp.zeros((), dtype=bool)
         return kmin, kmax, n, has_null
 
-    return jax.jit(f)
+    return jax.jit(agg_state_bounds)
 
 
 def _state_bounds_dev(st: DeviceBatch):
@@ -363,8 +368,8 @@ def _boundary_merge_program(
             v = jnp.where(a_nl, b, jnp.where(b_nl, a, jnp.maximum(a, b)))
         return v, a_nl & b_nl
 
-    def f(prev_cols, prev_nulls, prev_valid, next_cols, next_nulls,
-          next_valid, key):
+    def agg_boundary_merge(prev_cols, prev_nulls, prev_valid, next_cols,
+                           next_nulls, next_valid, key):
         ip = jnp.argmax(prev_valid & (prev_cols[0] == key))
         inx = jnp.argmax(next_valid & (next_cols[0] == key))
         out_cols, out_nulls = [prev_cols[0]], [prev_nulls[0]]
@@ -388,7 +393,7 @@ def _boundary_merge_program(
         nx_valid = next_valid.at[inx].set(False)
         return tuple(out_cols), tuple(out_nulls), nx_valid
 
-    return jax.jit(f)
+    return jax.jit(agg_boundary_merge)
 
 
 def _merge_boundary(
@@ -418,7 +423,7 @@ def _state_batch_program(dtypes: tuple):
     """GroupAggResult -> state-shaped DeviceBatch with target dtypes (one
     cheap jitted cast/pack program per layout)."""
 
-    def f(res, state_schema):
+    def agg_state_batch(res, state_schema):
         import numpy as np
 
         cols = list(res.keys) + list(res.values)
@@ -442,7 +447,7 @@ def _state_batch_program(dtypes: tuple):
             dictionaries={},
         )
 
-    return jax.jit(f, static_argnames=("state_schema",))
+    return jax.jit(agg_state_batch, static_argnames=("state_schema",))
 
 
 def _stat_final(outs_at, idxs, kind):
@@ -1059,7 +1064,7 @@ class HashAggregateExec(ExecutionPlan):
             raw = []
             for _, dev, _c in entries:
                 raw.extend(dev)
-            vals = [int(v) for v in fetch_arrays(raw)]
+            vals = [int(v) for v in fetch_arrays(raw, site="agg.bounds")]
             ok = disjoint
             for i, (st, _, _c) in enumerate(entries):
                 first, last, n, has_null = vals[4 * i : 4 * i + 4]
@@ -1127,8 +1132,6 @@ class HashAggregateExec(ExecutionPlan):
                     # overlaps the next window's dispatch. Folds never
                     # fire below _FOLD_WIDTH batches, so short queries
                     # pay nothing.
-                    import numpy as _np
-
                     flag = partials[0].valid[:1]
                     if self._bp_async_ok:
                         try:
@@ -1140,7 +1143,7 @@ class HashAggregateExec(ExecutionPlan):
                             # copy/dispatch overlap
                             self._bp_async_ok = False
                     if bp_prev is not None:
-                        _np.asarray(bp_prev)
+                        read_array(bp_prev, "agg.backpressure")
                     bp_prev = flag
             self.metrics.add("input_batches")
         if entries:
@@ -1215,11 +1218,15 @@ class HashAggregateExec(ExecutionPlan):
             # a bound method would pin this whole plan subtree (scan
             # tables, uploaded device batches) in the process-wide cache
             slots, schema = self.spec.slots, self._schema
+
+            def build():
+                def agg_scalar_state(b):
+                    return _scalar_state_program(slots, schema, b)
+
+                return jax.jit(agg_scalar_state)
+
             self._scalar_jit = shared_callable(
-                ("agg_scalar_state",) + self._spec_cache_key(),
-                lambda: jax.jit(
-                    lambda b: _scalar_state_program(slots, schema, b)
-                ),
+                ("agg_scalar_state",) + self._spec_cache_key(), build
             )
         return self._scalar_jit
 
@@ -1262,7 +1269,7 @@ class HashAggregateExec(ExecutionPlan):
                 finals, schema = self.spec.finals, self._schema
 
                 def build():
-                    def scalar_final(sts):
+                    def agg_scalar_final(sts):
                         merged = (
                             concat_batches(sts) if len(sts) > 1 else sts[0]
                         )
@@ -1276,7 +1283,7 @@ class HashAggregateExec(ExecutionPlan):
                             finals, schema, outs, nulls
                         )
 
-                    return jax.jit(scalar_final)
+                    return jax.jit(agg_scalar_final)
 
                 self._scalar_final_jit = shared_callable(
                     ("agg_scalar_final",) + self._spec_cache_key(), build
@@ -1335,12 +1342,19 @@ class HashAggregateExec(ExecutionPlan):
                     if dev is not None:
                         # host copy already in flight since the partial
                         # queued it — resolving here costs no round trip
-                        bounds[i] = tuple(int(np.asarray(v)) for v in dev)
+                        with obs_trace.phase(
+                            "task.d2h", site="agg.bounds_ready"
+                        ):
+                            bounds[i] = tuple(
+                                int(np.asarray(v)) for v in dev
+                            )
                     else:
                         missing.append(i)
                         raw.extend(_state_bounds_dev(st))
             if raw:
-                vals = [int(v) for v in fetch_arrays(raw)]
+                vals = [
+                    int(v) for v in fetch_arrays(raw, site="agg.bounds")
+                ]
                 for j, i in enumerate(missing):
                     bounds[i] = tuple(vals[4 * j : 4 * j + 4])
             live = sorted(

@@ -204,9 +204,15 @@ class TaskContext:
             # fresh round trip each
             import numpy as _np
 
-            fetched = [_np.asarray(v) for v in queued]
+            from ballista_tpu.obs import trace as obs_trace
+
+            with obs_trace.phase("task.d2h", site="deferred_checks") as ph:
+                fetched = [_np.asarray(v) for v in queued]
+                ph.nbytes = sum(v.nbytes for v in fetched)
         else:
-            fetched = fetch_arrays([jnp.asarray(v) for v in queued])
+            fetched = fetch_arrays(
+                [jnp.asarray(v) for v in queued], site="deferred_checks"
+            )
         flags, reqs = fetched[:n], fetched[n : 2 * n]
         spec_flags = fetched[2 * n : 2 * n + ns]
         learned = fetched[2 * n + ns :]
@@ -463,10 +469,20 @@ class Metrics:
         diffs), counters as python ints/floats (device scalars recorded
         without syncing on the hot path resolve here, at report time),
         timers always float seconds rounded to microsecond precision."""
-        out: dict[str, float] = {
-            k: v if isinstance(v, (int, float)) else int(v)
-            for k, v in self.counters.items()
-        }
+        out: dict[str, float] = dict(self.counters)
+        import jax
+
+        lazy = [k for k, v in out.items() if isinstance(v, jax.Array)]
+        if lazy:
+            from ballista_tpu.obs import trace as obs_trace
+
+            # one blocking read per device scalar still unresolved
+            with obs_trace.phase("task.d2h", site="operator_metrics"):
+                for k in lazy:
+                    out[k] = int(out[k])
+        for k, v in out.items():
+            if not isinstance(v, (int, float)):
+                out[k] = int(v)  # numpy scalars
         out.update({k: round(float(v), 6) for k, v in self.timers.items()})
         return dict(sorted(out.items()))
 

@@ -36,6 +36,7 @@ from ballista_tpu.exec.scan import (
     MemoryScanExec,
     ParquetScanExec,
 )
+from ballista_tpu.obs import trace as obs_trace
 from ballista_tpu.plan.logical import LogicalPlan
 from ballista_tpu.plan.optimizer import optimize
 from ballista_tpu.sql import ast
@@ -609,7 +610,6 @@ class TpuContext(Catalog, TableProvider):
         import time as _time
 
         from ballista_tpu.obs import profile
-        from ballista_tpu.obs import trace as obs_trace
 
         phys = PhysicalPlanner(
             self,
@@ -620,7 +620,8 @@ class TpuContext(Catalog, TableProvider):
             from ballista_tpu.analysis import verify_physical
 
             verify_physical(phys, sql=sql)
-        profile.instrument_plan(phys)
+        # the one caller that syncs: the verb reports device-complete time
+        profile.instrument_plan(phys, sync=True)
         part = phys.output_partitioning()
         n = part.n
 
@@ -676,7 +677,8 @@ class TpuContext(Catalog, TableProvider):
                 else:
                     os.environ["BALLISTA_TPU_NO_FUSE"] = prev_no_fuse
         elapsed = _time.perf_counter() - t0
-        self._hints.save_if_changed(self._capacity_hint, self._plan_cache)
+        with obs_trace.phase("task.hints_save"):
+            self._hints.save_if_changed(self._capacity_hint, self._plan_cache)
         from ballista_tpu.scheduler.aqe import narrate as aqe_narrate
 
         rows = [
@@ -961,9 +963,10 @@ class DataFrame:
                 self.ctx.config, run, hint=self.ctx._capacity_hint,
                 plan_cache=self.ctx._plan_cache
             )
-        self.ctx._hints.save_if_changed(
-            self.ctx._capacity_hint, self.ctx._plan_cache
-        )
+        with obs_trace.phase("task.hints_save"):
+            self.ctx._hints.save_if_changed(
+                self.ctx._capacity_hint, self.ctx._plan_cache
+            )
         if not record_batches:
             from ballista_tpu.columnar.arrow_interop import schema_to_arrow
 

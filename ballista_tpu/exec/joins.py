@@ -30,6 +30,7 @@ from ballista_tpu.expr.physical import compile_expr
 from ballista_tpu.columnar.batch import round_capacity
 from ballista_tpu.ops.compact import compact
 from ballista_tpu.ops.concat import concat_batches
+from ballista_tpu.ops.fetch import read_array
 from ballista_tpu.ops.join import (
     JoinSide,
     build_side,
@@ -65,32 +66,36 @@ def _collect_partition(
 # probe is a single fast-compiling program per shape.
 @functools.lru_cache(maxsize=None)
 def _jit_probe(probe_keys: tuple, kind: JoinSide, contiguous: bool = False):
-    return jax.jit(
-        lambda bt, pb: probe_side(
+
+    def join_probe(bt, pb):
+        return probe_side(
             bt, pb, list(probe_keys), kind, contiguous=contiguous
         )
-    )
+
+    return jax.jit(join_probe)
 
 
 @functools.lru_cache(maxsize=None)
 def _jit_counts(probe_keys: tuple):
-    return jax.jit(
-        lambda bt, pb: probe_counts(bt, pb, list(probe_keys))
-    )
+
+    def join_probe_counts(bt, pb):
+        return probe_counts(bt, pb, list(probe_keys))
+
+    return jax.jit(join_probe_counts)
 
 
 @functools.lru_cache(maxsize=None)
 def _jit_expand_total(preserve_probe: bool):
     """Output rows the expansion will need (host-fetched for sizing)."""
 
-    def f(pb, count):
+    def join_expand_total(pb, count):
         if preserve_probe:  # LEFT: unmatched live probe rows emit one row
             eff = jnp.where(pb.valid, jnp.maximum(count, 1), 0)
         else:
             eff = count
         return jnp.sum(eff)
 
-    return jax.jit(f)
+    return jax.jit(join_expand_total)
 
 
 class HashJoinExec(ExecutionPlan):
@@ -1086,13 +1091,13 @@ class HashJoinExec(ExecutionPlan):
             def build():
                 keep_match = kind == JoinSide.SEMI
 
-                def fn(pb, count):
+                def join_semi_mask(pb, count):
                     m = count > 0
                     return pb.with_valid(
                         pb.valid & (m if keep_match else ~m)
                     )
 
-                return jax.jit(fn)
+                return jax.jit(join_semi_mask)
 
             fn = shared_callable(
                 ("join_semi_counts", tuple(probe_keys), kind), build
@@ -1124,7 +1129,12 @@ class HashJoinExec(ExecutionPlan):
             )
         else:
             with self.metrics.time("probe_time"):
-                total = int(_jit_expand_total(preserve)(probe, count))
+                total = int(
+                    read_array(
+                        _jit_expand_total(preserve)(probe, count),
+                        "join.expand_total",
+                    )
+                )
             out_cap = round_capacity(max(total, 1))
             if cache is not None and cap_key:
                 cache[cap_key] = max(out_cap, cache.get(cap_key) or 0)
@@ -1140,7 +1150,7 @@ class HashJoinExec(ExecutionPlan):
         def build():
             filt = self.filter
 
-            def run(bt, pb, first, count):
+            def join_expand(bt, pb, first, count):
                 if kind == JoinSide.LEFT:
                     eff = jnp.where(pb.valid, jnp.maximum(count, 1), 0)
                     ekind = JoinSide.LEFT
@@ -1189,7 +1199,7 @@ class HashJoinExec(ExecutionPlan):
                     dictionaries=dict(batch.dictionaries),
                 )
 
-            return jax.jit(run)
+            return jax.jit(join_expand)
 
         fn = shared_callable(key, build)
         with self.metrics.time("probe_time"):
@@ -1241,7 +1251,7 @@ class HashJoinExec(ExecutionPlan):
             filt = self.filter
             pk = list(probe_keys)
 
-            def run(bt, probe):
+            def join_probe_filter(bt, probe):
                 # Residual filters see probe ++ build columns: join LEFT-like
                 # first, evaluate, then adjust validity per join kind.
                 joined = probe_side(
@@ -1277,7 +1287,7 @@ class HashJoinExec(ExecutionPlan):
                     dictionaries=dict(joined.dictionaries),
                 )
 
-            return jax.jit(run)
+            return jax.jit(join_probe_filter)
 
         fn = shared_callable(key, build)
         with self.metrics.time("probe_time"):
@@ -1420,7 +1430,7 @@ class CrossJoinExec(ExecutionPlan):
     def execute(self, partition: int, ctx: TaskContext) -> Iterator[DeviceBatch]:
         one = _collect(self.right, ctx)
         one = compact(one)
-        n = one.num_rows()
+        n = one.num_rows(site="cross_join.rows")
         if n != 1:
             raise ExecutionError(
                 f"CrossJoinExec supports a 1-row broadcast side, got {n} "

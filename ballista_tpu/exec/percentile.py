@@ -41,7 +41,9 @@ def _pct_program(
     interpolated gathers per percentile. Returns (per-q value arrays,
     per-q null flags, group-start flags) all in SORTED row space."""
 
-    def f(key_cols, key_nmasks, val, val_nmask, valid_sorted):
+    def percentile_interp(
+        key_cols, key_nmasks, val, val_nmask, valid_sorted
+    ):
         cap_i = jnp.arange(cap, dtype=jnp.int32)
         changed = jnp.zeros(cap, dtype=bool).at[0].set(True)
         for col, nm in zip(key_cols, key_nmasks):
@@ -86,7 +88,7 @@ def _pct_program(
             nulls.append(cnt == 0)
         return outs, nulls, changed & valid_sorted
 
-    return jax.jit(f)
+    return jax.jit(percentile_interp)
 
 
 class PercentileExec(ExecutionPlan):

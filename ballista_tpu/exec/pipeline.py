@@ -105,13 +105,22 @@ def fused_batch_fn(ops: list) -> Callable[[DeviceBatch], DeviceBatch]:
 
     from ballista_tpu.compilecache import shared_callable
 
+    # class names only: capturing the operators would pin their plan
+    # subtrees (scan tables, device batches) in the process-wide cache
+    scopes = [type(op).__name__ for op in ops]
+    steps = "_".join(op._cache_key()[0] for op in ops)
+
     def build():
-        def run(batch: DeviceBatch) -> DeviceBatch:
-            for f in fns:
-                batch = f(batch)
+        def pipeline_fused(batch: DeviceBatch) -> DeviceBatch:
+            for scope, f in zip(scopes, fns):
+                # a fusion's op_name leads back to its physical operator
+                with jax.named_scope(scope):
+                    batch = f(batch)
             return batch
 
-        return jax.jit(run)
+        # e.g. pipeline_filter_project: the program's name on the trace
+        pipeline_fused.__name__ = f"pipeline_{steps}"
+        return jax.jit(pipeline_fused)
 
     return shared_callable(
         ("fused_chain",) + tuple(op._cache_key() for op in ops), build
@@ -195,14 +204,14 @@ class FilterExec(_FusedPipeline, ExecutionPlan):
             def build():
                 phys = compile_expr(self.predicate, self.input.schema())
 
-                def run(batch: DeviceBatch) -> DeviceBatch:
+                def pipeline_filter(batch: DeviceBatch) -> DeviceBatch:
                     cv = phys.evaluate(batch)
                     keep = cv.values.astype(bool)
                     if cv.nulls is not None:
                         keep = keep & ~cv.nulls  # NULL predicate = drop row
                     return batch.with_valid(batch.valid & keep)
 
-                return jax.jit(run)
+                return jax.jit(pipeline_filter)
 
             self._fn = shared_callable(self._cache_key(), build)
         return self._fn
@@ -251,7 +260,7 @@ class ProjectionExec(_FusedPipeline, ExecutionPlan):
             def build():
                 phys = [compile_expr(e, ins) for e in self.exprs]
 
-                def run(batch: DeviceBatch) -> DeviceBatch:
+                def pipeline_project(batch: DeviceBatch) -> DeviceBatch:
                     cols, nulls, dicts = [], [], {}
                     import numpy as np
 
@@ -274,7 +283,7 @@ class ProjectionExec(_FusedPipeline, ExecutionPlan):
                             dicts[field.name] = cv.dictionary
                     return batch.with_columns(out_schema, cols, nulls, dicts)
 
-                return jax.jit(run)
+                return jax.jit(pipeline_project)
 
             self._fn = shared_callable(self._cache_key(), build)
         return self._fn

@@ -38,11 +38,11 @@ from ballista_tpu.ops.partition import partition_ids, string_key_tables
 
 @functools.lru_cache(maxsize=None)
 def _jit_mask_partition(key_idxs: tuple, n: int):
-    def f(batch: DeviceBatch, tables, p: int):
+    def repartition_mask(batch: DeviceBatch, tables, p: int):
         pid = partition_ids(batch, list(key_idxs), n, tables)
         return batch.with_valid(batch.valid & (pid == p))
 
-    return jax.jit(f, static_argnames=("p",))
+    return jax.jit(repartition_mask, static_argnames=("p",))
 
 
 @functools.lru_cache(maxsize=None)
@@ -52,11 +52,11 @@ def jit_partition_ids(key_idxs: tuple, num_partitions: int):
     the grace-hash spill paths (exec/spill.py callers). Dictionary hash
     tables ride as runtime args (they change per batch dictionary; baking
     them at trace time would mis-route later batches)."""
-    return jax.jit(
-        lambda b, tables: partition_ids(
-            b, list(key_idxs), num_partitions, tables
-        )
-    )
+
+    def repartition_hash(b, tables):
+        return partition_ids(b, list(key_idxs), num_partitions, tables)
+
+    return jax.jit(repartition_hash)
 
 
 class HashRepartitionExec(ExecutionPlan):

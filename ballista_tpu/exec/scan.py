@@ -31,6 +31,7 @@ from ballista_tpu.exec.base import (
     TaskContext,
     UnknownPartitioning,
 )
+from ballista_tpu.obs import trace as obs_trace
 
 
 class MemoryScanExec(ExecutionPlan):
@@ -189,7 +190,7 @@ class _StagedFileScanExec(ExecutionPlan):
                 # a rewritten file drops BOTH tiers for the old mtime
                 self.scan_cache.clear()
             dev_cache = self.scan_cache.setdefault(("dev", mt), {})
-        with self.metrics.time("read_time"):
+        with self.metrics.time("read_time"), obs_trace.phase("task.scan_host"):
             t = self._read()
         if self.scan_cache is not None:
             self.scan_cache[hkey] = t
@@ -500,8 +501,11 @@ class ParquetScanExec(ExecutionPlan):
             t = self.scan_cache.get(hkey)
             dev_cache = self.scan_cache.setdefault(("dev",) + sub, {})
         if t is None:
-            with self.metrics.time("read_time"):
+            with self.metrics.time("read_time"), obs_trace.phase(
+                "task.scan_host"
+            ) as ph:
                 t = f.read_row_groups(groups, columns=cols)
+                ph.nbytes = t.nbytes
             # column order must match the projected schema
             t = t.select([fld.name for fld in self._schema])
             if self.scan_cache is not None:
@@ -626,8 +630,11 @@ class ParquetScanExec(ExecutionPlan):
         prefetch worker when enabled; DeviceBatch.from_host starts the
         host->device transfer, so the next slice's upload overlaps the
         current slice's compute."""
-        with self.metrics.time("read_time"):
+        with self.metrics.time("read_time"), obs_trace.phase(
+            "task.scan_host"
+        ) as ph:
             t = f.read_row_groups(groups, columns=self.projection or None)
+            ph.nbytes = t.nbytes
         t = t.select(names)
         return table_from_arrow(t, batch_rows, narrow, fixed_dicts=dicts)
 

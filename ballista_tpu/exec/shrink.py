@@ -58,7 +58,7 @@ def _shrink_program(
     bool argsort pass touches the full batch."""
     from ballista_tpu.ops.perm import argsort_i32, take_many_split
 
-    def f(cols, nulls, valid):
+    def shrink_compact(cols, nulls, valid):
         order = argsort_i32((~valid).astype(jnp.int32))[:new_cap]
         out_cols, out_nulls = take_many_split(
             list(cols), list(nulls), order
@@ -68,7 +68,7 @@ def _shrink_program(
         overflow = n_live > new_cap
         return tuple(out_cols), tuple(out_nulls), out_valid, overflow
 
-    return jax.jit(f)
+    return jax.jit(shrink_compact)
 
 
 def _run_shrink(batch: DeviceBatch, new_cap: int):
@@ -130,7 +130,7 @@ def maybe_shrink(
     # cached across queries
     from ballista_tpu.ops.fetch import fetch_arrays
 
-    n = int(fetch_arrays([batch.count_valid()])[0])
+    n = int(fetch_arrays([batch.count_valid()], site="shrink.count")[0])
     new_cap = round_capacity(max(SHRINK_HEADROOM * n, SHRINK_MIN_CAP))
     if new_cap > cap // SHRINK_RATIO:
         cache[key] = 0

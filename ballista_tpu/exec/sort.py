@@ -18,11 +18,11 @@ import jax.numpy as jnp
 
 @functools.lru_cache(maxsize=None)
 def _fetch_program(cap: int, fetch: int):
-    def f(b):
+    def limit_mask(b):
         keep = jnp.arange(cap) < fetch
         return b.with_valid(b.valid & keep)
 
-    return jax.jit(f)
+    return jax.jit(limit_mask)
 
 from ballista_tpu.columnar.batch import DeviceBatch
 from ballista_tpu.datatypes import Schema
@@ -34,6 +34,7 @@ from ballista_tpu.exec.base import (
 )
 from ballista_tpu.expr import logical as L
 from ballista_tpu.ops.concat import concat_batches
+from ballista_tpu.ops.fetch import read_array
 from ballista_tpu.ops.sort import SortKey, sort_batch
 from ballista_tpu.plan.logical import SortExpr
 
@@ -171,7 +172,11 @@ class GlobalLimitExec(ExecutionPlan):
             out = mask(b, remaining_skip, remaining)
             # multi-batch streams need the live count to carry skip/fetch
             # across batches — one scalar sync per batch, rare shape
-            n_live = int(jnp.sum(b.valid.astype(jnp.int32)))
+            n_live = int(
+                read_array(
+                    jnp.sum(b.valid.astype(jnp.int32)), "limit.live_count"
+                )
+            )
             taken = max(0, n_live - remaining_skip)
             if remaining is not None:
                 taken = min(taken, remaining)

@@ -36,6 +36,7 @@ import pyarrow.ipc as paipc
 from ballista_tpu.columnar.arrow_interop import batch_to_arrow
 from ballista_tpu.columnar.batch import DeviceBatch
 from ballista_tpu.errors import ExecutionError
+from ballista_tpu.ops.fetch import read_array
 
 # Shared temp root for spills of contexts without a work_dir; swept by
 # executor.cleanup.clean_spill_data on executors, and removed per-attempt
@@ -135,9 +136,9 @@ class SpillSet:
         rows carry the drop id and are excluded by batch_to_arrow's
         live-row gather). Returns bytes written."""
         before = self.manager.total_bytes
-        rb = batch_to_arrow(batch)
+        rb = batch_to_arrow(batch, site="spill.rows")
         if rb.num_rows:
-            live = pids[np.asarray(batch.valid)]
+            live = pids[read_array(batch.valid, "spill.valid")]
             # one stable argsort groups rows by bucket; searchsorted slices
             # give each bucket's contiguous index range — one pass over the
             # ids instead of a full `live == b` scan per occupied bucket
@@ -201,8 +202,9 @@ def spill_batch_by_keys(
     from ballista_tpu.ops.partition import string_key_tables
 
     tables = string_key_tables(batch, list(key_idxs))
-    pids = np.asarray(
-        jit_partition_ids(tuple(key_idxs), spill_set.buckets)(batch, tables)
+    pids = read_array(
+        jit_partition_ids(tuple(key_idxs), spill_set.buckets)(batch, tables),
+        "spill.pids",
     )
     return spill_set.write_split(batch, pids)
 

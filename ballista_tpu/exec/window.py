@@ -64,7 +64,7 @@ def _rank_program(
                 )
         return changed
 
-    def f(part_cols, part_nmasks, order_cols, order_nmasks, perm):
+    def window_rank(part_cols, part_nmasks, order_cols, order_nmasks, perm):
         idx = jnp.arange(cap, dtype=jnp.int64)
         part_changed = (
             changed_of(part_cols, part_nmasks)
@@ -96,7 +96,7 @@ def _rank_program(
             .set(vals, unique_indices=True)
         )
 
-    return jax.jit(f)
+    return jax.jit(window_rank)
 
 
 def _changed_of(cols, nulls, cap):
@@ -158,8 +158,8 @@ def _agg_window_program(
     """Aggregate / lag / lead window finisher on SORTED rows. Returns the
     output column and its null mask at ORIGINAL row positions."""
 
-    def f(part_cols, part_nmasks, order_cols, order_nmasks,
-          arg, arg_nmask, valid_sorted, perm):
+    def window_frame(part_cols, part_nmasks, order_cols, order_nmasks,
+                     arg, arg_nmask, valid_sorted, perm):
         idx = jnp.arange(cap, dtype=jnp.int32)
         part_changed = _changed_of(part_cols, part_nmasks, cap)
         # the dead tail (invalid rows sort last) forms its own region so
@@ -293,7 +293,7 @@ def _agg_window_program(
         )
         return out_vals, out_nulls
 
-    return jax.jit(f)
+    return jax.jit(window_frame)
 
 
 class WindowExec(ExecutionPlan):

@@ -27,6 +27,7 @@ from ballista_tpu.executor import (
     effective_task_slots,
     visible_devices,
 )
+from ballista_tpu.obs import trace as obs_trace
 from ballista_tpu.proto import pb
 from ballista_tpu.scheduler.rpc import scheduler_stub
 from ballista_tpu.serde import BallistaCodec
@@ -278,14 +279,6 @@ class Executor:
             from ballista_tpu.plugin import load_plugins
 
             load_plugins(plugin_dir)
-        node = pb.PhysicalPlanNode()
-        node.ParseFromString(task.plan)
-        plan = self.codec.physical_from_proto(node)
-        if not isinstance(plan, ShuffleWriterExec):
-            raise ExecutionError(
-                "task plan root must be ShuffleWriterExec "
-                f"(got {type(plan).__name__})"
-            )
         props = props_early
         config = BallistaConfig(props) if props else BallistaConfig()
         # shape canonicalization (docs/compile_cache.md): the session's
@@ -295,23 +288,30 @@ class Executor:
         from ballista_tpu.columnar.batch import set_capacity_buckets
 
         set_capacity_buckets(config.capacity_buckets())
-        if self.verify_decoded_plans and config.verify_plans():
-            from ballista_tpu.analysis import verify_physical
+        with obs_trace.phase("task.decode", nbytes=len(task.plan)):
+            node = pb.PhysicalPlanNode()
+            node.ParseFromString(task.plan)
+            plan = self.codec.physical_from_proto(node)
+            if not isinstance(plan, ShuffleWriterExec):
+                raise ExecutionError(
+                    "task plan root must be ShuffleWriterExec "
+                    f"(got {type(plan).__name__})"
+                )
+            if self.verify_decoded_plans and config.verify_plans():
+                from ballista_tpu.analysis import verify_physical
 
-            verify_physical(plan)
+                verify_physical(plan)
         from ballista_tpu.executor.metrics import collector_for
 
         collector = collector_for(config, self.metrics_collector)
         if collector.wants_instrumentation():
-            # per-operator rows/bytes/elapsed metering (obs.profile):
+            # per-operator rows/bytes/dispatch_s metering (obs.profile):
             # wrapped BEFORE execution; counters stay lazy device scalars
             # on the hot path and resolve once at record_stage
             from ballista_tpu.obs import profile
 
             profile.instrument_plan(plan)
         import contextlib
-
-        from ballista_tpu.obs import trace as obs_trace
 
         if trace_id:
             # executor-side JSONL export follows the session's trace mode;
@@ -398,7 +398,8 @@ class Executor:
         from ballista_tpu.exec.base import evict_plan_cache
 
         evict_plan_cache(self._plan_cache)
-        self._hints.save_if_changed(self._capacity_hint, self._plan_cache)
+        with obs_trace.phase("task.hints_save"):
+            self._hints.save_if_changed(self._capacity_hint, self._plan_cache)
         from ballista_tpu.analysis import replay
 
         if replay.enabled():
@@ -607,7 +608,6 @@ class PollLoop:
 
     def start(self) -> None:
         from ballista_tpu.compilecache.prewarm import start_server_prewarm
-        from ballista_tpu.obs import trace as obs_trace
 
         # executor role: recorded spans stage in the outbox and ride the
         # poll home (docs/observability.md)
@@ -699,7 +699,6 @@ class PollLoop:
             can_accept = free_slots > 0
             from ballista_tpu.compilecache import metrics as compile_metrics
             from ballista_tpu.obs import hist as obs_hist
-            from ballista_tpu.obs import trace as obs_trace
 
             spans = obs_trace.drain_outbox()
             hist_deltas = obs_hist.REGISTRY.drain_deltas()
@@ -745,6 +744,13 @@ class PollLoop:
             if tasks:
                 for td in tasks:
                     self._run_task(td)
+            elif free_slots == self.task_slots:
+                # asleep with NO task running: idle the scheduler (or the
+                # client) has to fill. With a task running the same sleep
+                # is no phase: on a trace it would cover, and so take the
+                # label of, every gap the running tasks can explain
+                with obs_trace.phase("executor.poll_sleep"):
+                    time.sleep(POLL_INTERVAL)
             else:
                 time.sleep(POLL_INTERVAL)
 
@@ -769,12 +775,13 @@ class PollLoop:
                 )
             finally:
                 self._available.release()
-            self._statuses.put(
-                as_task_status(
-                    task.task_id, self.executor.executor_id, result, error,
-                    cost=cost,
+            with obs_trace.phase("task.report"):
+                self._statuses.put(
+                    as_task_status(
+                        task.task_id, self.executor.executor_id, result,
+                        error, cost=cost,
+                    )
                 )
-            )
 
         # fire-and-forget by design: concurrency is bounded by the task
         # slot semaphore and completion is observed through the status

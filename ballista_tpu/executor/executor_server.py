@@ -11,6 +11,7 @@ scheduler (:176-254). StopExecutor — ``todo!()`` in the reference
 
 from __future__ import annotations
 
+import contextlib
 import logging
 import queue
 import threading
@@ -80,6 +81,10 @@ class ExecutorServer:
         self.task_slots = task_slots
         self.heartbeat_interval_s = heartbeat_interval_s
         self._queue: queue.Queue = queue.Queue()
+        # tasks running now: a slot's wait is the "executor.poll_sleep"
+        # phase only while there are none (executor.py _poll has why)
+        self._running = 0
+        self._running_lock = threading.Lock()
         self._stop = threading.Event()
         self._threads: list[threading.Thread] = []
         self._grpc_server: grpc.Server | None = None
@@ -240,10 +245,22 @@ class ExecutorServer:
     def _runner_loop(self) -> None:
         """ref run_task :176-254 — decode, execute, push status back."""
         while not self._stop.is_set():
+            from ballista_tpu.obs import trace as obs_trace
+
+            with self._running_lock:
+                idle = self._running == 0
             try:
-                task = self._queue.get(timeout=0.2)
+                # every slot asleep with nothing to run (the pull loop's
+                # POLL_INTERVAL sleep is the same phase); summed over slots
+                with (
+                    obs_trace.phase("executor.poll_sleep")
+                    if idle else contextlib.nullcontext()
+                ):
+                    task = self._queue.get(timeout=0.2)
             except queue.Empty:
                 continue
+            with self._running_lock:
+                self._running += 1
             error = None
             result = []
             cost = None
@@ -262,24 +279,28 @@ class ExecutorServer:
                     _time.perf_counter() - t0,
                     _time.thread_time() - c0,
                 )
-            status = as_task_status(
-                task.task_id, self.executor.executor_id, result, error,
-                cost=cost,
-            )
-            from ballista_tpu.obs import trace as obs_trace
-
+            finally:
+                with self._running_lock:
+                    self._running -= 1
             # drain trace spans with the status so task-attempt spans
             # arrive WITH their completion, not a heartbeat later
             spans = obs_trace.drain_outbox()
             try:
-                self._sched.UpdateTaskStatus(
-                    pb.UpdateTaskStatusParams(
-                        executor_id=self.executor.executor_id,
-                        task_status=[status],
-                        spans=[obs_trace.span_to_proto(s) for s in spans],
-                    ),
-                    timeout=RPC_TIMEOUT_S,
-                )
+                with obs_trace.phase("task.report"):
+                    status = as_task_status(
+                        task.task_id, self.executor.executor_id, result,
+                        error, cost=cost,
+                    )
+                    self._sched.UpdateTaskStatus(
+                        pb.UpdateTaskStatusParams(
+                            executor_id=self.executor.executor_id,
+                            task_status=[status],
+                            spans=[
+                                obs_trace.span_to_proto(s) for s in spans
+                            ],
+                        ),
+                        timeout=RPC_TIMEOUT_S,
+                    )
             except grpc.RpcError as e:
                 log.warning("UpdateTaskStatus failed: %s", e)
                 obs_trace.requeue_outbox(spans)

@@ -51,6 +51,7 @@ from ballista_tpu.exec.base import (
     TaskContext,
     UnknownPartitioning,
 )
+from ballista_tpu.obs import trace as obs_trace
 from ballista_tpu.scheduler_types import PartitionLocation
 
 BATCH_ROWS = 1 << 17
@@ -613,7 +614,9 @@ def _iter_location_batches(
             it = fetch_one(loc)
             try:
                 while True:
-                    with metrics.time("fetch_time"):
+                    with metrics.time("fetch_time"), obs_trace.phase(
+                        "task.shuffle_fetch"
+                    ):
                         rb = next(it, None)
                     if rb is None:
                         break
@@ -698,7 +701,9 @@ def _iter_location_batches(
                     # shuffle-fetch-wait histogram (obs/hist.py) shipped
                     # home on poll/heartbeat (docs/observability.md).
                     wait_t0 = _time.perf_counter()
-                    with metrics.time("fetch_time"):
+                    with metrics.time("fetch_time"), obs_trace.phase(
+                        "task.shuffle_fetch"
+                    ):
                         item = q.get()
                     fetch_wait_hist.observe(
                         _time.perf_counter() - wait_t0

@@ -49,6 +49,8 @@ from ballista_tpu.exec.base import (
 )
 from ballista_tpu.exec.repartition import jit_partition_ids
 from ballista_tpu.expr import logical as L
+from ballista_tpu.obs import trace as obs_trace
+from ballista_tpu.ops.fetch import read_array
 from ballista_tpu.ops.partition import string_key_tables
 from ballista_tpu.scheduler_types import ShuffleWritePartitionMeta
 
@@ -170,37 +172,47 @@ class ShuffleWriterExec(ExecutionPlan):
             with self.metrics.time("write_time"):
                 for batch in self.input.execute(input_partition, ctx):
                     if not self.partition_keys or self.output_partitions == 1:
-                        rb = batch_to_arrow(batch)
+                        rb = batch_to_arrow(batch, site="shuffle_write.rows")
                         if rb.num_rows:
-                            appender(0).write(rb)
+                            with obs_trace.phase(
+                                "task.shuffle_write", nbytes=rb.nbytes
+                            ):
+                                appender(0).write(rb)
                         continue
                     with self.metrics.time("repart_time"):
                         tables = string_key_tables(batch, list(key_idxs))
-                        pids = np.asarray(
+                        pids = read_array(
                             jit_partition_ids(
                                 key_idxs, self.output_partitions
-                            )(batch, tables)
+                            )(batch, tables),
+                            "shuffle_write.pids",
                         )
-                    rb = batch_to_arrow(batch)
-                    live_pids = pids[np.asarray(batch.valid)]
+                    rb = batch_to_arrow(batch, site="shuffle_write.rows")
+                    live_pids = pids[
+                        read_array(batch.valid, "shuffle_write.valid")
+                    ]
                     # Single sort-based scatter: ONE stable argsort + ONE
                     # gather into bucket order, then zero-copy slices per
                     # bucket — the per-unique-pid rb.take loop re-walked
                     # every column's buffers once per populated bucket
                     # (K gathers of the whole batch instead of one).
-                    order = np.argsort(live_pids, kind="stable")
-                    sorted_rb = rb.take(pa.array(order))
-                    sorted_pids = live_pids[order]
-                    bounds = np.searchsorted(
-                        sorted_pids, np.arange(self.output_partitions + 1)
-                    )
-                    for out_part in range(self.output_partitions):
-                        lo = int(bounds[out_part])
-                        hi = int(bounds[out_part + 1])
-                        if hi > lo:
-                            appender(out_part).write(
-                                sorted_rb.slice(lo, hi - lo)
-                            )
+                    with obs_trace.phase(
+                        "task.shuffle_write", nbytes=rb.nbytes
+                    ):
+                        order = np.argsort(live_pids, kind="stable")
+                        sorted_rb = rb.take(pa.array(order))
+                        sorted_pids = live_pids[order]
+                        bounds = np.searchsorted(
+                            sorted_pids,
+                            np.arange(self.output_partitions + 1),
+                        )
+                        for out_part in range(self.output_partitions):
+                            lo = int(bounds[out_part])
+                            hi = int(bounds[out_part + 1])
+                            if hi > lo:
+                                appender(out_part).write(
+                                    sorted_rb.slice(lo, hi - lo)
+                                )
         except BaseException:
             # a failed ATTEMPT must leave nothing observable: push streams
             # are aborted (the registry key frees for the retry); partial
@@ -212,7 +224,8 @@ class ShuffleWriterExec(ExecutionPlan):
 
         out = []
         for out_part, w in sorted(writers.items()):
-            num_rows, num_batches, num_bytes, pushed = w.close()
+            with obs_trace.phase("task.shuffle_write"):
+                num_rows, num_batches, num_bytes, pushed = w.close()
             self.metrics.add("output_rows", num_rows)
             out.append(
                 ShuffleWritePartitionMeta(

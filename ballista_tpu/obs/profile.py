@@ -12,9 +12,15 @@ batch) to meter, per operator:
 - ``output_batches`` / ``output_bytes`` — batch count and the device
   residency of what was produced (capacity x dtype widths, host
   arithmetic — no sync).
-- ``elapsed`` (timer) — wall seconds spent INSIDE this operator's
-  iterator, i.e. cumulative over the operator and its inputs (the Spark
-  UI convention; subtracting a child's elapsed gives self time).
+- ``elapsed`` (timer, ``EXPLAIN ANALYZE`` only) — wall seconds spent
+  INSIDE this operator's iterator until the batch it yields is COMPLETE
+  on the device (the timer ends in ``block_until_ready``), cumulative
+  over the operator and its inputs (the Spark UI convention; subtracting
+  a child's elapsed gives self time).
+- ``dispatch_s`` (timer, the shipping collector's path) — the same
+  bracket WITHOUT the sync: JAX dispatch is asynchronous, so this is the
+  host time to trace, look up and enqueue the operator's programs, not
+  the time they run. Nothing on the served path syncs for a timer.
 
 The same counters feed three consumers: ``EXPLAIN ANALYZE`` renders
 :func:`annotated_display`; the executor's ShippingMetricsCollector
@@ -26,6 +32,8 @@ item re-plans from exactly these per-partition row/byte stats.
 from __future__ import annotations
 
 import time
+
+import jax
 
 from ballista_tpu.datatypes import DataType
 
@@ -51,10 +59,14 @@ def batch_nbytes(batch) -> int:
     return cap * (per_row + 1)  # +1 for the valid mask
 
 
-def instrument_plan(plan) -> None:
+def instrument_plan(plan, sync: bool = False) -> None:
     """Wrap every node's ``execute`` with the metering shim (idempotent:
     re-instrumenting an already-wrapped node is a no-op, so cached plan
-    instances survive repeated EXPLAIN ANALYZE runs)."""
+    instances survive repeated EXPLAIN ANALYZE runs). ``sync`` (EXPLAIN
+    ANALYZE) ends each timed step in ``block_until_ready`` on the batch
+    and calls the timer ``elapsed``; without it the timer is what it
+    measures, ``dispatch_s``."""
+    timer = "elapsed" if sync else "dispatch_s"
 
     def wrap(node) -> None:
         if getattr(node, "_obs_metered", False):
@@ -69,12 +81,14 @@ def instrument_plan(plan) -> None:
                     t0 = time.perf_counter()
                     try:
                         batch = next(it)
+                        if sync:
+                            jax.block_until_ready(batch)
                     except StopIteration:
-                        m.timers["elapsed"] = m.timers.get("elapsed", 0.0) + (
+                        m.timers[timer] = m.timers.get(timer, 0.0) + (
                             time.perf_counter() - t0
                         )
                         break
-                    m.timers["elapsed"] = m.timers.get("elapsed", 0.0) + (
+                    m.timers[timer] = m.timers.get(timer, 0.0) + (
                         time.perf_counter() - t0
                     )
                     m.add("output_batches")
@@ -150,7 +164,8 @@ def merge_counter_maps(maps) -> dict:
 
 def annotated_display(plan, extra: dict | None = None) -> str:
     """The physical plan display re-printed with measured
-    rows/bytes/elapsed per operator (the EXPLAIN ANALYZE body).
+    rows/bytes/elapsed per operator (the EXPLAIN ANALYZE body; records
+    shipped from executors carry ``dispatch`` in place of ``elapsed``).
     ``extra``: {path: counter-map} merged in (e.g. scheduler-side
     aggregates for operators that ran remotely)."""
     lines = []
@@ -162,6 +177,7 @@ def annotated_display(plan, extra: dict | None = None) -> str:
         rows = counters.pop("output_rows", None)
         nbytes = counters.pop("output_bytes", None)
         elapsed = counters.pop("elapsed", None)
+        dispatch = counters.pop("dispatch_s", None)
         parts = []
         if rows is not None:
             parts.append(f"rows={int(rows)}")
@@ -169,6 +185,8 @@ def annotated_display(plan, extra: dict | None = None) -> str:
             parts.append(f"bytes={int(nbytes)}")
         if elapsed is not None:
             parts.append(f"elapsed={float(elapsed):.6f}s")
+        if dispatch is not None:
+            parts.append(f"dispatch={float(dispatch):.6f}s")
         parts += [f"{k}={v}" for k, v in sorted(counters.items())]
         line = "  " * d + node.describe()
         if parts:

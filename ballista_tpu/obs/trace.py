@@ -21,6 +21,15 @@ append one JSON line there (``ballista.tpu.trace=<path>``); ``on`` keeps
 spans in the ring only. The ring is the debugging surface
 (:func:`snapshot`); chaos tests assert span-tree SHAPE from the
 scheduler-side store (docs/observability.md).
+
+Host phases (:func:`phase`, PR 25) are the second, always-on output of
+this module: a closed list of leaf stretches of the served path (a
+blocking device read, the executor's idle sleep, a shuffle file write...)
+that each feed three things from ONE call site: a
+``jax.profiler.TraceAnnotation`` on the profiler's own clock, the
+process-wide counters of ``compilecache/metrics.py`` that ride the
+executor's poll, and, under ``ballista.tpu.trace``, the enclosing
+``task_attempt`` span's attrs. No span is minted per phase.
 """
 
 from __future__ import annotations
@@ -29,11 +38,17 @@ import collections
 import contextlib
 import dataclasses
 import json
+import os
 import threading
 import time
 import uuid
 
+# no backend is touched: the package's __init__ has imported jax already,
+# in the scheduler and the remote client too
+from jax.profiler import TraceAnnotation
+
 from ballista_tpu.analysis.witness import make_lock
+from ballista_tpu.compilecache import metrics
 
 # Bounded stores: tracing must never become a memory leak on a long-lived
 # daemon. The ring is a debugging window, not a database; the outbox holds
@@ -196,14 +211,19 @@ def requeue_outbox(spans: list[Span]) -> None:
 def current() -> tuple[str, str] | None:
     """The active ``(trace_id, span_id)`` on this thread, or None."""
     stack = getattr(_TLS, "stack", None)
-    return stack[-1] if stack else None
+    if not stack:
+        return None
+    return stack[-1].trace_id, stack[-1].span_id
 
 
-def _push(ctx: tuple[str, str]) -> None:
+def _push(live: Span) -> None:
+    """``live`` becomes this thread's ambient span (a stack of the open
+    Spans themselves: :func:`phase` adds its seconds to the nearest
+    ``task_attempt``)."""
     stack = getattr(_TLS, "stack", None)
     if stack is None:
         stack = _TLS.stack = []
-    stack.append(ctx)
+    stack.append(live)
 
 
 def _pop() -> None:
@@ -240,7 +260,7 @@ def span(
         start_s=time.time(),
         attrs=dict(attrs or {}),
     )
-    _push((trace_id, s.span_id))
+    _push(s)
     try:
         yield s
     except BaseException as e:
@@ -303,6 +323,116 @@ def finish(s: Span, outcome: str = "ok") -> Span:
     s.outcome = outcome
     record(s)
     return s
+
+
+# ---------------------------------------------------------------------------
+# host phases: profiler annotations + poll-shipped counters
+# ---------------------------------------------------------------------------
+
+# The closed list of host phases (docs/observability.md has the table of
+# what each brackets). Closed like obs/history.COST_KEYS: every reader
+# (perf/layers, PERF.md, /api/state) uses exactly these names.
+PHASES = (
+    "client.submit",
+    "client.fetch_results",
+    "scheduler.plan",
+    "scheduler.grant",
+    "scheduler.status",
+    "executor.poll_sleep",
+    "task.decode",
+    "task.scan_host",
+    "task.h2d",
+    "task.d2h",
+    "task.shuffle_write",
+    "task.shuffle_fetch",
+    "task.hints_save",
+    "task.report",
+)
+# the phase whose counters are kept by call site as well (the list of
+# round trips)
+_SITED = "task.d2h"
+_PHASE_NAMES: dict[tuple[str, str], tuple] = {}
+
+
+def _phase_names(name: str, site: str) -> tuple:
+    """(annotation label, attr key, counter keys by (seconds, count,
+    bytes) for the phase and, where sited, for the site), built once per
+    (phase, site): sites are static strings, so the table stays small."""
+    names = _PHASE_NAMES.get((name, site))
+    if names is None:
+        if name not in PHASES:
+            raise ValueError(f"{name!r} is not in obs.trace.PHASES")
+        keys = [f"phase.{name}.{k}" for k in ("seconds", "count", "bytes")]
+        sited = (
+            [f"{k}:{site}" for k in keys]
+            if site and name == _SITED else None
+        )
+        label = f"ballista/{name}:{site}" if site else f"ballista/{name}"
+        names = (label, f"phase.{name}_s", keys, sited)
+        _PHASE_NAMES[(name, site)] = names
+    return names
+
+
+class phase:
+    """One leaf stretch of host work or waiting on the served path::
+
+        with obs_trace.phase("task.d2h", site="shrink.count") as ph:
+            host = np.asarray(dev)
+            ph.nbytes = host.nbytes
+
+    Entering and leaving does three things: a ``TraceAnnotation`` named
+    ``ballista/<name>[:<site>]`` (a flag test with no profiler running,
+    in this process or in one that never opens a backend), the counters
+    ``phase.<name>.seconds|count|bytes`` in ``compilecache/metrics.py``
+    (always on: two clock reads and one locked add), and, under an
+    ambient ``task_attempt`` span, ``phase.<name>_s`` on that span's attrs.
+
+    Phases are LEAVES: the trace reduction gives an idle gap to the host
+    event that covers most of it, so an enclosing phase would swallow
+    every label inside it. A phase entered inside a phase raises under
+    the tests and otherwise does nothing but count ``phase.nested``.
+    Never hold one across a ``yield``."""
+
+    __slots__ = ("name", "nbytes", "_names", "_t0", "_ann", "_nested")
+
+    def __init__(self, name: str, nbytes: int = 0, site: str = ""):
+        self.name = name
+        self.nbytes = nbytes
+        self._names = _phase_names(name, site)
+
+    def __enter__(self) -> "phase":
+        self._nested = getattr(_TLS, "in_phase", None)
+        if self._nested is not None:
+            if "PYTEST_CURRENT_TEST" in os.environ:
+                raise AssertionError(
+                    f"phase {self.name!r} entered inside phase "
+                    f"{self._nested!r}: phases are leaves"
+                )
+            return self
+        _TLS.in_phase = self.name
+        self._ann = TraceAnnotation(self._names[0])
+        self._ann.__enter__()
+        self._t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc) -> bool:
+        if self._nested is not None:
+            metrics.add("phase.nested")
+            return False
+        dt = time.perf_counter() - self._t0
+        self._ann.__exit__(*exc)
+        _TLS.in_phase = None
+        _, attr, keys, sited = self._names
+        values = (dt, 1, int(self.nbytes))
+        adds = [kv for kv in zip(keys, values) if kv[1]]
+        if sited is not None:
+            adds += [kv for kv in zip(sited, values) if kv[1]]
+        metrics.add_many(adds)
+        for live in reversed(getattr(_TLS, "stack", None) or ()):
+            if live.name == "task_attempt":
+                live.attrs[attr] = round(live.attrs.get(attr, 0.0) + dt, 6)
+                break
+        return False
 
 
 # ---------------------------------------------------------------------------

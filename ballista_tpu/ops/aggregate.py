@@ -117,12 +117,18 @@ class GroupAggResult:
 
 @functools.lru_cache(maxsize=None)
 def _zeroed_program(kdtype: str, cap: int):
-    return jax.jit(lambda nm, kc: jnp.where(nm, jnp.zeros_like(kc), kc))
+    def agg_zero_null_keys(nm, kc):
+        return jnp.where(nm, jnp.zeros_like(kc), kc)
+
+    return jax.jit(agg_zero_null_keys)
 
 
 @functools.lru_cache(maxsize=None)
 def _not_program(cap: int):
-    return jax.jit(lambda v: ~v)
+    def agg_invalid(v):
+        return ~v
+
+    return jax.jit(agg_invalid)
 
 
 def _stacked_scatter_set(rid, capacity: int, cols: list) -> list:

@@ -19,15 +19,20 @@ from ballista_tpu.ops.perm import stable_argsort, take
 
 @functools.lru_cache(maxsize=None)
 def _invalid_program(cap: int):
-    return jax.jit(lambda v: ~v)
+    def compact_invalid(v):
+        return ~v
+
+    return jax.jit(compact_invalid)
 
 
 @functools.lru_cache(maxsize=None)
 def _front_valid_program(cap: int):
-    return jax.jit(
-        lambda v: jnp.arange(cap, dtype=jnp.int32)
-        < jnp.sum(v.astype(jnp.int32))
-    )
+    def compact_front_valid(v):
+        return jnp.arange(cap, dtype=jnp.int32) < jnp.sum(
+            v.astype(jnp.int32)
+        )
+
+    return jax.jit(compact_front_valid)
 
 
 def compact(batch: DeviceBatch) -> DeviceBatch:

@@ -24,21 +24,32 @@ import numpy as np
 
 @functools.lru_cache(maxsize=None)
 def _concat_program(dtype: str, lengths: tuple):
+    # the program's name on the device trace says what it packs
     if len(lengths) == 1:
-        return jax.jit(lambda x: x.reshape(-1))
-    return jax.jit(lambda *xs: jnp.concatenate([x.reshape(-1) for x in xs]))
+
+        def fetch_flat(x):
+            return x.reshape(-1)
+
+        fetch_flat.__name__ = f"fetch_flat_{dtype}"
+        return jax.jit(fetch_flat)
+
+    def fetch_concat(*xs):
+        return jnp.concatenate([x.reshape(-1) for x in xs])
+
+    fetch_concat.__name__ = f"fetch_concat_{dtype}"
+    return jax.jit(fetch_concat)
 
 
 @functools.lru_cache(maxsize=None)
 def _f64_concat_program(sig: tuple):
     """sig: tuple of (dtype_str, length). One f64 buffer for everything."""
 
-    def f(*xs):
+    def fetch_concat_f64(*xs):
         return jnp.concatenate(
             [x.reshape(-1).astype(jnp.float64) for x in xs]
         )
 
-    return jax.jit(f)
+    return jax.jit(fetch_concat_f64)
 
 
 # Above this total size, f64 widening of narrow columns costs more in
@@ -55,11 +66,30 @@ _F64_EXACT = {
 }
 
 
-def fetch_arrays(arrays: list) -> list[np.ndarray]:
+def read_array(x, site: str) -> np.ndarray:
+    """``np.asarray`` of ONE device value, blocking, as the ``task.d2h``
+    phase at ``site``: for the reads that are a single array already (a
+    count, a flag, a mask), where packing through :func:`fetch_arrays`
+    would only add a dispatch."""
+    from ballista_tpu.obs import trace as obs_trace
+
+    with obs_trace.phase("task.d2h", site=site) as ph:
+        out = np.asarray(x)
+        ph.nbytes = out.nbytes
+    return out
+
+
+def fetch_arrays(arrays: list, site: str = "fetch") -> list[np.ndarray]:
     """Fetch device arrays to host numpy in as few blocking round trips as
     possible: ONE for small batches (everything widened to a single f64
     buffer — value-preserving), one per distinct dtype otherwise. Returns
-    arrays in input order with original shapes."""
+    arrays in input order with original shapes.
+
+    ``site``: a short static string naming the caller. The blocking read
+    is the ``task.d2h`` phase of docs/observability.md, counted per site:
+    the list of round trips a query makes."""
+    from ballista_tpu.obs import trace as obs_trace
+
     arrays = [jnp.asarray(a) for a in arrays]
     if not arrays:
         return []
@@ -74,7 +104,10 @@ def fetch_arrays(arrays: list) -> list[np.ndarray]:
         and total * 8 <= _F64_FETCH_MAX_BYTES
         and dtypes <= _F64_EXACT
     ):
-        buf = np.asarray(jax.device_get(_f64_concat_program(sig)(*arrays)))
+        packed = _f64_concat_program(sig)(*arrays)
+        with obs_trace.phase("task.d2h", site=site) as ph:
+            buf = np.asarray(jax.device_get(packed))
+            ph.nbytes = buf.nbytes
         out = []
         off = 0
         for a, (dt, n) in zip(arrays, sig):
@@ -93,10 +126,11 @@ def fetch_arrays(arrays: list) -> list[np.ndarray]:
         arrs = [arrays[i] for i in idxs]
         lengths = tuple(int(np.prod(a.shape)) if a.shape else 1 for a in arrs)
         packed.append(_concat_program(dt, lengths)(*arrs))
-    host = jax.device_get(tuple(packed))
+    with obs_trace.phase("task.d2h", site=site) as ph:
+        host = [np.asarray(b) for b in jax.device_get(tuple(packed))]
+        ph.nbytes = sum(b.nbytes for b in host)
     out: list[np.ndarray | None] = [None] * len(arrays)
     for buf, (dt, idxs) in zip(host, groups.items()):
-        buf = np.asarray(buf)
         off = 0
         for i in idxs:
             shape = arrays[i].shape

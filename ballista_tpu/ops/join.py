@@ -187,7 +187,8 @@ class BuildTable:
                     contig,
                     self.lo if self.lo is not None else zero,
                     self.hi if self.hi is not None else zero,
-                ]
+                ],
+                site="join.build_flags",
             )
             cached = (bool(d), bool(o), bool(c), int(lo), int(hi))
             object.__setattr__(self, "_flags_cache", cached)
@@ -217,7 +218,7 @@ def _build_prep_program(key_idxs: tuple, cap: int, schema_key: tuple,
                         mode: str):
     """(batch) -> (dead flag, packed key): the sort-pass operands."""
 
-    def f(batch: DeviceBatch):
+    def join_build_prep(batch: DeviceBatch):
         valid = batch.valid
         for i in key_idxs:
             nm = batch.nulls[i]
@@ -226,21 +227,21 @@ def _build_prep_program(key_idxs: tuple, cap: int, schema_key: tuple,
         packed = _pack_key([batch.columns[i] for i in key_idxs], mode)
         return ~valid, packed
 
-    return jax.jit(f)
+    return jax.jit(join_build_prep)
 
 
 @functools.lru_cache(maxsize=None)
 def _exact2_range_program(cap: int):
     """Whether both (masked) int key columns fit the exact2 pack ranges."""
 
-    def f(a, b, live):
+    def join_exact2_range(a, b, live):
         a = jnp.where(live, a.astype(jnp.int64), 0)
         b = jnp.where(live, b.astype(jnp.int64), 0)
         return jnp.all(
             (a >= 0) & (a < 2**31) & (b >= 0) & (b < jnp.int64(2**32))
         )
 
-    return jax.jit(f)
+    return jax.jit(join_exact2_range)
 
 
 def _build_finish(perm, dead, packed, batch: DeviceBatch, key_idxs: tuple,
@@ -355,10 +356,12 @@ def _choose_pack_mode(batch: DeviceBatch, key_idxs: list[int]) -> str:
             nm = batch.nulls[i]
             if nm is not None:
                 live = live & ~nm
+        from ballista_tpu.ops.fetch import read_array
+
         ok = _exact2_range_program(batch.capacity)(
             key_cols[0], key_cols[1], live
         )
-        if bool(ok):
+        if bool(read_array(ok, "join.pack_mode")):
             return "exact2"
     return "hash"
 
@@ -398,7 +401,7 @@ def _lut_program(size: int, cap_b: int):
     scatters ride sorted indices (the build is key-sorted; the dead tail's
     INT64_MAX keys map far out of range and drop)."""
 
-    def f(keys_sorted, lo, n):
+    def join_lut(keys_sorted, lo, n):
         iota = jnp.arange(cap_b, dtype=jnp.int32)
         # Dead-tail rows get a clean ``size`` sentinel BEFORE the i32
         # narrow: the raw INT64_MAX - lo value truncates arbitrarily under
@@ -415,7 +418,7 @@ def _lut_program(size: int, cap_b: int):
         )
         return jnp.stack([jnp.where(count > 0, first, 0), count], axis=1)
 
-    return jax.jit(f)
+    return jax.jit(join_lut)
 
 
 def attach_lut(build: BuildTable, size: int) -> None:

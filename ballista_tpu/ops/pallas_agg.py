@@ -98,7 +98,7 @@ def _program(n: int, R: int, P: int):
             preferred_element_type=jnp.float32,
         )[None]
 
-    def f(rid2, matT):
+    def agg_onehot_sums(rid2, matT):
         # Mosaic rejects 64-bit index types; trace the call in x32 mode
         # (operands are i32/f32 by construction).
         with jax.enable_x64(False):
@@ -113,6 +113,8 @@ def _program(n: int, R: int, P: int):
                 out_specs=pl.BlockSpec(
                     (1, P, R), lambda g: (g // sup, 0, 0)
                 ),
+                # the kernel's own name on the device trace
+                name="agg_onehot_kernel",
             )
             pad = nb * B - n
             if pad:
@@ -121,7 +123,7 @@ def _program(n: int, R: int, P: int):
             partials = call(rid2, matT)
         return partials.astype(jnp.float64).sum(axis=0)
 
-    return jax.jit(f)
+    return jax.jit(agg_onehot_sums)
 
 
 def onehot_sums(rid: jnp.ndarray, rows: list[jnp.ndarray], P: int):

@@ -33,7 +33,7 @@ def argsort_i32(c: jnp.ndarray) -> jnp.ndarray:
 
 @functools.lru_cache(maxsize=None)
 def _argsort_program(dtype: str, cap: int, descending: bool, is_float: bool):
-    def f(col):
+    def sort_argsort(col):
         c = col
         if descending:
             if is_float:
@@ -42,7 +42,7 @@ def _argsort_program(dtype: str, cap: int, descending: bool, is_float: bool):
                 c = ~c  # ~x = -x-1: total order reversal incl. INT_MIN
         return argsort_i32(c)
 
-    return jax.jit(f)
+    return jax.jit(sort_argsort)
 
 
 def stable_argsort(col: jnp.ndarray, descending: bool = False) -> jnp.ndarray:
@@ -62,7 +62,10 @@ def stable_argsort(col: jnp.ndarray, descending: bool = False) -> jnp.ndarray:
 
 @functools.lru_cache(maxsize=None)
 def _take_program(dtype: str, cap: int):
-    return jax.jit(lambda col, perm: col[perm])
+    def perm_take(col, perm):
+        return col[perm]
+
+    return jax.jit(perm_take)
 
 
 def take(col: jnp.ndarray, perm: jnp.ndarray) -> jnp.ndarray:
@@ -124,13 +127,13 @@ def _take_batch_program(sig: tuple, nulls_sig: tuple):
     movement as ONE dispatch instead of one per column. (jax.jit retraces
     per shape on its own, so capacity is deliberately NOT in the key.)"""
 
-    def f(cols, nulls, valid, perm):
+    def perm_take_batch(cols, nulls, valid, perm):
         gathered, out_nulls = take_many_split(
             [valid] + list(cols), list(nulls), perm
         )
         return gathered[1:], out_nulls, gathered[0]
 
-    return jax.jit(f)
+    return jax.jit(perm_take_batch)
 
 
 def take_batch(cols: list, nulls: list, valid, perm):

@@ -15,6 +15,8 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ballista_tpu.columnar.batch import DeviceBatch, round_capacity
 from ballista_tpu.errors import ExecutionError
+from ballista_tpu.obs import trace as obs_trace
+from ballista_tpu.ops.fetch import read_array
 
 SHARD_AXIS = "shards"
 
@@ -50,23 +52,25 @@ def shard_batch(
     masked slots pad each block.
     """
     n_dev = mesh.devices.size
-    n = int(np.sum(np.asarray(batch.valid)))
+    valid_h = read_array(batch.valid, "mesh.shard")
+    n = int(np.sum(valid_h))
     per_dev = -(-n // n_dev)  # ceil
     cap = local_capacity or round_capacity(max(per_dev, 1))
     if per_dev > cap:
         raise ExecutionError(
             f"local capacity {cap} < {per_dev} rows per device"
         )
-    live = np.flatnonzero(np.asarray(batch.valid))
+    live = np.flatnonzero(valid_h)
     sh = row_sharding(mesh, axis)
 
     def place(col, fill=0):
-        col = np.asarray(col)
+        col = read_array(col, "mesh.shard")
         out = np.full((n_dev * cap,) + col.shape[1:], fill, dtype=col.dtype)
         for d in range(n_dev):
             rows = live[d::n_dev]
             out[d * cap : d * cap + len(rows)] = col[rows]
-        return jax.device_put(out, sh)
+        with obs_trace.phase("task.h2d", nbytes=out.nbytes):
+            return jax.device_put(out, sh)
 
     valid = np.zeros(n_dev * cap, dtype=bool)
     for d in range(n_dev):
@@ -100,15 +104,15 @@ def is_row_sharded(batch: DeviceBatch, mesh: Mesh, axis: str = SHARD_AXIS) -> bo
 def unshard_batch(batch: DeviceBatch) -> DeviceBatch:
     """Gather a mesh-sharded batch back to one addressable batch (host
     gather — the client collect path, not a hot path)."""
-    cols = tuple(jnp.asarray(np.asarray(c)) for c in batch.columns)
+
+    def gather(x):
+        return jnp.asarray(read_array(x, "mesh.unshard"))
+
     return DeviceBatch(
         schema=batch.schema,
-        columns=cols,
-        valid=jnp.asarray(np.asarray(batch.valid)),
-        nulls=tuple(
-            None if m is None else jnp.asarray(np.asarray(m))
-            for m in batch.nulls
-        ),
+        columns=tuple(gather(c) for c in batch.columns),
+        valid=gather(batch.valid),
+        nulls=tuple(None if m is None else gather(m) for m in batch.nulls),
         dictionaries=dict(batch.dictionaries),
     )
 

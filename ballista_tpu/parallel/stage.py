@@ -44,6 +44,19 @@ from jax.sharding import PartitionSpec as P
 # completion barrier.
 _COLLECTIVE_LOCK = threading.Lock()
 
+
+def _stage_program(name: str, operator: str, f):
+    """``f`` under the name its jitted program carries on the device
+    trace's ``XLA Modules`` line, its operations scoped to the physical
+    operator that owns the stage (docs/observability.md)."""
+
+    def program(*args):
+        with jax.named_scope(operator):
+            return f(*args)
+
+    program.__name__ = name
+    return program
+
 from ballista_tpu.columnar.batch import DeviceBatch, round_capacity
 from ballista_tpu.datatypes import DataType, Field, Schema
 from ballista_tpu.errors import CapacityError, ExecutionError
@@ -122,7 +135,9 @@ class MeshStageRunner:
                 from ballista_tpu.ops.fetch import fetch_arrays
 
                 # the fetch doubles as the completion barrier the lock needs
-                grp_ovf, need = fetch_arrays([grp_ovf, need])
+                grp_ovf, need = fetch_arrays(
+                    [grp_ovf, need], site="mesh_agg.overflow"
+                )
                 jax.block_until_ready(out_valid)
             if not np.any(grp_ovf):
                 break
@@ -252,7 +267,8 @@ class MeshStageRunner:
             P(axis),
         )
         sm = shard_map(
-            f, mesh=self.mesh, in_specs=in_specs, out_specs=out_specs,
+            _stage_program("mesh_agg_stage", "MeshAggregateExec", f),
+            mesh=self.mesh, in_specs=in_specs, out_specs=out_specs,
             check_vma=False,
         )
         return jax.jit(sm)
@@ -341,7 +357,8 @@ class MeshStageRunner:
             P(),
         )
         sm = shard_map(
-            f, mesh=self.mesh, in_specs=in_specs, out_specs=out_specs,
+            _stage_program("mesh_topk_stage", "MeshSortExec", f),
+            mesh=self.mesh, in_specs=in_specs, out_specs=out_specs,
             check_vma=False,
         )
         return jax.jit(sm)
@@ -377,7 +394,7 @@ class MeshStageRunner:
                 )
                 from ballista_tpu.ops.fetch import fetch_arrays
 
-                (ovf_h,) = fetch_arrays([ovf])
+                (ovf_h,) = fetch_arrays([ovf], site="mesh_sort.overflow")
                 jax.block_until_ready(out_valid)
             if not np.any(ovf_h):
                 break
@@ -495,7 +512,8 @@ class MeshStageRunner:
             P(axis),
         )
         sm = shard_map(
-            f, mesh=self.mesh, in_specs=in_specs, out_specs=out_specs,
+            _stage_program("mesh_sort_stage", "MeshSortExec", f),
+            mesh=self.mesh, in_specs=in_specs, out_specs=out_specs,
             check_vma=False,
         )
         return jax.jit(sm)
@@ -527,7 +545,7 @@ class MeshStageRunner:
                 )
                 from ballista_tpu.ops.fetch import fetch_arrays
 
-                (ovf_h,) = fetch_arrays([ovf])
+                (ovf_h,) = fetch_arrays([ovf], site="mesh_window.overflow")
                 jax.block_until_ready(out_valid)
             if not np.any(ovf_h):
                 break
@@ -583,7 +601,8 @@ class MeshStageRunner:
             P(axis),
         )
         sm = shard_map(
-            f, mesh=self.mesh, in_specs=in_specs, out_specs=out_specs,
+            _stage_program("mesh_window_stage", "MeshWindowExec", f),
+            mesh=self.mesh, in_specs=in_specs, out_specs=out_specs,
             check_vma=False,
         )
         return jax.jit(sm)
@@ -656,7 +675,8 @@ class MeshStageRunner:
 
                 # fetch doubles as the completion barrier the lock needs
                 bucket_ovf, run_ovf, exp_ovf, totals = fetch_arrays(
-                    [bucket_ovf, run_ovf, exp_ovf, totals]
+                    [bucket_ovf, run_ovf, exp_ovf, totals],
+                    site="mesh_join.overflow",
                 )
                 jax.block_until_ready(valid)
             if np.any(run_ovf):
@@ -830,7 +850,8 @@ class MeshStageRunner:
             P(axis),
         )
         sm = shard_map(
-            f, mesh=self.mesh, in_specs=in_specs, out_specs=out_specs,
+            _stage_program("mesh_join_stage", "MeshJoinExec", f),
+            mesh=self.mesh, in_specs=in_specs, out_specs=out_specs,
             check_vma=False,
         )
         return jax.jit(sm)

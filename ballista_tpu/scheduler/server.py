@@ -220,29 +220,43 @@ class QueryStageScheduler(EventAction):
         self.server = server
 
     def on_receive(self, event):
+        from ballista_tpu.obs import trace as obs_trace
+
         s = self.server
         if isinstance(event, ReviveOffers):
-            s._offer_resources()
+            with obs_trace.phase("scheduler.grant"):
+                s._offer_resources()
             return None
         if isinstance(event, JobSubmitted):
-            try:
-                s._generate_stages(event.job_id, event.plan)
-            except Exception as e:  # noqa: BLE001
-                # stage persistence/serialization failures after planning
-                # must FAIL the job — an escaped exception here previously
-                # left it "running" forever (clients poll indefinitely)
-                log.exception("stage submission failed for %s", event.job_id)
-                s._on_job_failed(
-                    event.job_id, f"stage submission failed: {e}"
-                )
-        elif isinstance(event, TaskRescheduled):
-            s._on_task_rescheduled(event)
-        elif isinstance(event, StageFinished):
-            s._on_stage_finished(event.job_id, event.stage_id)
-        elif isinstance(event, JobFinished):
-            s._on_job_finished(event.job_id)
-        elif isinstance(event, JobFailed):
-            s._on_job_failed(event.job_id, event.error)
+            # physical plan to stages: the second half of "scheduler.plan"
+            # (submit_logical is the first)
+            with obs_trace.phase("scheduler.plan"):
+                try:
+                    s._generate_stages(event.job_id, event.plan)
+                except Exception as e:  # noqa: BLE001
+                    # stage persistence/serialization failures after
+                    # planning must FAIL the job — an escaped exception
+                    # here previously left it "running" forever (clients
+                    # poll indefinitely)
+                    log.exception(
+                        "stage submission failed for %s", event.job_id
+                    )
+                    s._on_job_failed(
+                        event.job_id, f"stage submission failed: {e}"
+                    )
+        elif isinstance(
+            event, (TaskRescheduled, StageFinished, JobFinished, JobFailed)
+        ):
+            # stage resolution and promotion: what a status sets off
+            with obs_trace.phase("scheduler.status"):
+                if isinstance(event, TaskRescheduled):
+                    s._on_task_rescheduled(event)
+                elif isinstance(event, StageFinished):
+                    s._on_stage_finished(event.job_id, event.stage_id)
+                elif isinstance(event, JobFinished):
+                    s._on_job_finished(event.job_id)
+                else:
+                    s._on_job_failed(event.job_id, event.error)
         else:
             log.warning("unknown scheduler event %r", event)
             return None
@@ -673,7 +687,11 @@ class SchedulerServer:
         cfg = self._session_config(session_id)
         tctx = self._mint_trace(cfg)
         verify = cfg.verify_plans()
-        with self._trace_step(tctx, "plan"):
+        from ballista_tpu.obs import trace as obs_trace
+
+        with self._trace_step(tctx, "plan"), obs_trace.phase(
+            "scheduler.plan"
+        ):
             optimized = optimize(logical)
             # serving fast path (docs/serving.md): a repeated identical
             # query over unchanged data is answered from the result
@@ -3038,7 +3056,7 @@ class SchedulerGrpcServicer:
             )
         self.s.ingest_spans(list(request.spans))
         self.s.ingest_hists(list(request.hists))
-        self.s.apply_task_statuses(list(request.task_status))
+        self._apply_statuses(request.task_status)
         result = pb.PollWorkResult()
         if request.can_accept_task:
             # batched grants (docs/serving.md): an executor advertising
@@ -3052,13 +3070,26 @@ class SchedulerGrpcServicer:
                     int(request.free_slots),
                     self.s.config.task_grant_batch(),
                 )
-            tasks = self.s.next_tasks(meta.id, max_n)
-            if tasks:
-                result.tasks.extend(tasks)
-                # mirror the first grant into the singular field so a
-                # pre-batching executor still makes progress
-                result.task.CopyFrom(tasks[0])
+            from ballista_tpu.obs import trace as obs_trace
+
+            with obs_trace.phase("scheduler.grant"):
+                tasks = self.s.next_tasks(meta.id, max_n)
+                if tasks:
+                    result.tasks.extend(tasks)
+                    # mirror the first grant into the singular field so a
+                    # pre-batching executor still makes progress
+                    result.task.CopyFrom(tasks[0])
         return result
+
+    def _apply_statuses(self, statuses) -> None:
+        """Status intake as the ``scheduler.status`` phase; a poll that
+        carries none (ten a second from an idle executor) is not one."""
+        if not statuses:
+            return
+        from ballista_tpu.obs import trace as obs_trace
+
+        with obs_trace.phase("scheduler.status"):
+            self.s.apply_task_statuses(list(statuses))
 
     def RegisterExecutor(self, request, context):
         # inverse policy handshake: a push-mode executor registering with a
@@ -3124,7 +3155,7 @@ class SchedulerGrpcServicer:
 
     def UpdateTaskStatus(self, request, context):
         self.s.ingest_spans(list(request.spans))
-        self.s.apply_task_statuses(list(request.task_status))
+        self._apply_statuses(request.task_status)
         n_done = sum(
             1
             for st in request.task_status

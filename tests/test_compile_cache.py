@@ -304,7 +304,7 @@ def test_prewarm_failure_is_nonfatal():
 
     base = metrics.snapshot().get("prewarm_failures", 0)
     sig = PrewarmSignature(
-        "ops.perm.f", 2048, ("int64",), variant="boom",
+        "ops.perm.sort_argsort", 2048, ("int64",), variant="boom",
         compile=lambda: (_ for _ in ()).throw(RuntimeError("boom")),
     )
     prewarm._compile_one(sig)

@@ -214,7 +214,9 @@ def test_instrument_plan_meters_every_operator():
         if r["counters"].get("output_batches"):
             assert r["counters"]["output_rows"] > 0
             assert r["counters"]["output_bytes"] > 0
-            assert r["counters"]["elapsed"] >= 0
+            # the shipping path's timer does not sync, and says so
+            assert r["counters"]["dispatch_s"] >= 0
+            assert "elapsed" not in r["counters"]
     # the root produced the query's rows
     root = recs[0]["counters"]
     assert root["output_rows"] == 3
