@@ -352,6 +352,34 @@ CACHES: tuple[CacheEntry, ...] = (
                       "key",),
     ),
     CacheEntry(
+        name="executor-scan-store",
+        anchors=(
+            "ballista_tpu/exec/scan.py::ScanStore._entries",
+            "ballista_tpu/serde.py::BallistaCodec.scan_store",
+            "ballista_tpu/exec/context.py::TpuContext._scans",
+        ),
+        keyed_by="(file path, what else the data depends on: parquet row "
+        "groups + projected schema; CSV/Avro declared schema, header "
+        "flag, delimiter; a streamed column's name) -> uploaded "
+        "DeviceBatches (CSV/Avro: the parsed file too; streamed: the "
+        "whole-file dictionary), each entry stamped with the file's "
+        "(mtime_ns, size). Handed out at two seams: "
+        "BallistaCodec._scan_from_proto, only by a codec that was given "
+        "a store (the Executor's), and TpuContext.scan",
+        scope="process",
+        coherence="versioned",
+        invalidation=(
+            "the path is statted before the file is opened: entries of "
+            "another (mtime_ns, size) are dropped and the file is read "
+            "again; a read is parked only if the path stats the same "
+            "after it",
+            "byte-bounded by the serving session's "
+            "ballista.tpu.scan_stream_mb: least recently served entry "
+            "evicted first; running tasks keep their own references",
+            "in-memory only: a restarted executor starts cold",
+        ),
+    ),
+    CacheEntry(
         name="capacity-ladder",
         anchors=("ballista_tpu/columnar/batch.py::_LADDER",),
         keyed_by="configured bucket spec -> rounded capacities",

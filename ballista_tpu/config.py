@@ -504,7 +504,9 @@ def _entries() -> dict[str, ConfigEntry]:
             "Keeps tables far larger than HBM (TPC-H SF=100) runnable on "
             "one chip; 0 disables streaming. Materialized residency is "
             "faster when the working set fits, so the threshold should stay "
-            "a healthy fraction of HBM.",
+            "a healthy fraction of HBM. Also bounds the device bytes an "
+            "executor's scan store keeps resident across queries (0: no "
+            "bound).",
             "4096",
             int,
         ),

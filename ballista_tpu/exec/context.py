@@ -35,6 +35,7 @@ from ballista_tpu.exec.scan import (
     CsvScanExec,
     MemoryScanExec,
     ParquetScanExec,
+    ScanStore,
 )
 from ballista_tpu.obs import trace as obs_trace
 from ballista_tpu.plan.logical import LogicalPlan
@@ -103,6 +104,8 @@ class TpuContext(Catalog, TableProvider):
             self.config.prewarm(), max_rows=self.config.tpu_batch_rows()
         )
         self.tables: dict[str, _Registered] = {}
+        # what this context's file scans read and uploaded (exec/scan.py)
+        self._scans = ScanStore()
         self._mesh_runtime = None
         self._mesh_checked = False
         # remembered adaptive-capacity growth (see run_with_capacity_retry)
@@ -338,9 +341,9 @@ class TpuContext(Catalog, TableProvider):
                 r.kw["table"], r.schema, projection, partitions,
                 device_cache=cache,
             )
-        # file scans share a registration-lifetime cache too: parsed host
-        # table + uploaded device batches, invalidated by file mtime
-        scache = r.kw.setdefault("scan_cache", {})
+        # file scans share a context-lifetime cache too: parsed host
+        # table + uploaded device batches, invalidated by file mtime + size
+        scache = self._scans.for_path(r.kw["path"])
         if r.kind == "csv":
             return CsvScanExec(
                 r.kw["path"], r.schema, r.kw["has_header"], r.kw["delimiter"],

@@ -14,23 +14,31 @@ correctness dependence.
 
 from __future__ import annotations
 
-from typing import Iterator
+import collections
+import contextlib
+import os
+import threading
+import weakref
+from typing import Callable, Iterator
 
 import pyarrow as pa
 import pyarrow.csv as pacsv
 import pyarrow.parquet as papq
 
 from ballista_tpu.columnar.arrow_interop import (
+    narrowable_int64_cols,
     schema_to_arrow,
     table_from_arrow,
 )
 from ballista_tpu.columnar.batch import DeviceBatch
+from ballista_tpu.compilecache import metrics
 from ballista_tpu.datatypes import DataType, Schema
 from ballista_tpu.exec.base import (
     ExecutionPlan,
     TaskContext,
     UnknownPartitioning,
 )
+from ballista_tpu.exec.spill import device_nbytes
 from ballista_tpu.obs import trace as obs_trace
 
 
@@ -41,22 +49,29 @@ class MemoryScanExec(ExecutionPlan):
 
     def __init__(
         self,
-        table: pa.Table,
+        table: pa.Table | Callable[[], tuple[pa.Table, frozenset | None]],
         out_schema: Schema,
         projection: list[str] | None = None,
         partitions: int = 1,
         batch_rows: int | None = None,
         device_cache: dict | None = None,
     ) -> None:
-        """``device_cache``: an (optionally shared, table-lifetime) dict the
-        scan parks its uploaded DeviceBatches in. Host->device transfer was
-        the dominant cost of a warm scan when this was written (not
-        measured on the attached chip); a registered
-        table's columns are immutable, and DeviceBatches are functional
-        (operators mask/copy, never mutate), so re-serving the resident
-        arrays is safe. The context passes its per-table cache so repeated
-        queries skip the upload entirely (device data residency — the
-        TPU-idiomatic replacement for the reference's OS page cache)."""
+        """``table``: the Arrow table, or a file scan's read of it: a
+        callable taking nothing and returning the table and its
+        ``narrow_cols`` (None: decide from the table), called only for a
+        partition that is not in ``device_cache``.
+
+        ``device_cache``: an (optionally shared, table-lifetime) dict the
+        scan parks its uploaded DeviceBatches in. Re-reading and
+        re-uploading is what a warm scan costs without it: over parquet
+        an executor with no such cache uploaded 261 MB a query against 43
+        MB with the columns resident, at 0.82 queries/s against 1.13
+        (ledger, PR 25: tpch-sf1-daemons.power, tpch-sf1-mem.power). A
+        registered table's columns are immutable, and DeviceBatches are
+        functional (operators mask/copy, never mutate), so re-serving the
+        resident arrays is safe. The context passes its per-table cache so
+        repeated queries skip the upload entirely (device data residency —
+        the TPU-idiomatic replacement for the reference's OS page cache)."""
         super().__init__()
         self.table = table
         self.projection = projection
@@ -81,6 +96,15 @@ class MemoryScanExec(ExecutionPlan):
         return f"MemoryScanExec: cols={cols}, partitions={self.partitions}"
 
     def execute(self, partition: int, ctx: TaskContext) -> Iterator[DeviceBatch]:
+        for b in self.batches(partition, ctx):
+            # device scalar — resolved lazily at metrics report time (an
+            # int() here would cost a host sync per batch)
+            self.metrics.add("output_rows", b.count_valid())
+            yield b
+
+    def batches(self, partition: int, ctx: TaskContext) -> list[DeviceBatch]:
+        """``partition``'s batches, whole: the device cache's, or uploaded
+        now and parked there."""
         # resolved per task so ballista.tpu.batch_rows travels with the
         # session config across process boundaries (decoded stage plans
         # carry no batch_rows; the config does)
@@ -92,11 +116,11 @@ class MemoryScanExec(ExecutionPlan):
         if self.device_cache is not None:
             cached = self.device_cache.get(key)
             if cached is not None:
-                for b in cached:
-                    self.metrics.add("output_rows", b.count_valid())
-                yield from cached
-                return
-        t = self.table
+                return cached
+        if callable(self.table):
+            t, narrow = self.table()
+        else:
+            t, narrow = self.table, self.narrow_cols
         if self.projection:
             t = t.select(self.projection)
         n = t.num_rows
@@ -109,22 +133,163 @@ class MemoryScanExec(ExecutionPlan):
             chunk = t.slice(start, stop - start)
             # narrowing decided over the WHOLE table so every partition
             # slice shares one physical layout (stable compile shapes)
-            if self.narrow_cols is None:
-                from ballista_tpu.columnar.arrow_interop import (
-                    narrowable_int64_cols,
-                )
-
-                self.narrow_cols = narrowable_int64_cols(t)
-            out = list(
-                table_from_arrow(chunk, batch_rows, self.narrow_cols)
-            )
+            if narrow is None:
+                narrow = self.narrow_cols = narrowable_int64_cols(t)
+            out = list(table_from_arrow(chunk, batch_rows, narrow))
         if self.device_cache is not None:
             self.device_cache[key] = out
-        for b in out:
-            # device scalar — resolved lazily at metrics report time (an
-            # int() here would cost a host sync per batch)
-            self.metrics.add("output_rows", b.count_valid())
-            yield b
+        return out
+
+
+def _file_id(path: str) -> tuple[int, int]:
+    """What a cached scan of ``path`` is valid for: the file's modification
+    time in nanoseconds and its size."""
+    try:
+        st = os.stat(path)
+    except OSError:
+        return (-1, -1)
+    return (st.st_mtime_ns, st.st_size)
+
+
+class _Entry:
+    """What a ScanStore keeps of one ``(path, key)``, all of it read from
+    the file while it was ``file_id``: ``dev`` the dict a MemoryScanExec
+    parks its uploaded batches in; ``host`` and ``narrow`` a CSV or Avro
+    file's parse and its narrowing (every partition slices the one
+    parse), or ``host`` a streamed column's whole-file dictionary;
+    ``nbytes`` what the store's bound counts it at."""
+
+    __slots__ = ("file_id", "dev", "host", "narrow", "nbytes")
+
+    def __init__(self, file_id: tuple[int, int] | None) -> None:
+        self.file_id = file_id
+        self.dev: dict = {}
+        self.host = None
+        self.narrow: frozenset | None = None
+        self.nbytes = 0
+
+    def filled(self) -> tuple[int, bool]:
+        return (len(self.dev), self.host is not None)
+
+
+def _release(entries: dict) -> None:
+    metrics.add(
+        "scan_store.resident_bytes", -sum(e.nbytes for e in entries.values())
+    )
+
+
+class ScanStore:
+    """What file scans read and uploaded, kept for as long as the owner
+    lives: an Executor (tasks decode a fresh plan each, so this is where a
+    scan finds what an earlier task left) or a TpuContext.
+
+    Exact: an entry serves only the file it was read from. A scan stats
+    the path before it opens the file, an entry of another
+    ``(mtime_ns, size)`` is dropped with every other stale entry of the
+    path, and a read is parked only if the path still stats the same after
+    it. Bounded: the bytes of all entries stay under the serving session's
+    ``ballista.tpu.scan_stream_mb`` (what scans may materialise on the
+    device; a scan above it streams and is never parked; 0 turns both
+    off), the least recently served entry going first; a task still
+    reading evicted batches keeps its own reference. Single-flight: a
+    miss is read and uploaded by one task, and the others that want the
+    same ``(path, key)`` wait for it and hit. A parquet entry holds no
+    host table (the device batches are what is served again).
+
+    Counters (``compilecache/metrics.py``): ``scan_store.hits`` (a serve
+    that neither read nor uploaded), ``.misses``, ``.evictions`` and
+    ``.resident_bytes``, the bytes the process's stores hold now."""
+
+    def __init__(self) -> None:
+        self._lock = threading.Lock()
+        # (path, key) -> _Entry, least recently served first
+        self._entries: collections.OrderedDict = collections.OrderedDict()
+        # (path, key) -> [lock, tasks holding or waiting for it]
+        self._flights: dict[tuple, list] = {}
+        weakref.finalize(self, _release, self._entries)
+
+    def for_path(self, path: str) -> "ScanCache":
+        return ScanCache(self, path)
+
+    @contextlib.contextmanager
+    def _flight(self, name: tuple):
+        with self._lock:
+            slot = self._flights.setdefault(name, [threading.Lock(), 0])
+            slot[1] += 1
+        try:
+            with slot[0]:
+                yield
+        finally:
+            with self._lock:
+                slot[1] -= 1
+                if not slot[1]:
+                    del self._flights[name]
+
+    def _take(self, name: tuple, file_id: tuple[int, int]) -> _Entry:
+        """``name``'s entry if it was read from the file as it is now, else
+        a new one that ``_serve`` may park."""
+        with self._lock:
+            e = self._entries.get(name)
+            if e is not None and e.file_id == file_id:
+                return e
+            # a rewritten file: out with every entry read from the old one
+            stale = [
+                n for n, x in self._entries.items()
+                if n[0] == name[0] and x.file_id != file_id
+            ]
+            freed = sum(self._entries.pop(n).nbytes for n in stale)
+        metrics.add("scan_store.resident_bytes", -freed)
+        return _Entry(file_id)
+
+    def _serve(self, name: tuple, e: _Entry, missed: bool, bound: int) -> None:
+        """``e`` was served: count it, make it the most recently served,
+        park what a miss read, and evict down to ``bound`` bytes."""
+        changed = missed and _file_id(name[0]) != e.file_id
+        evictions = 0
+        with self._lock:
+            before = sum(x.nbytes for x in self._entries.values())
+            parked = self._entries.pop(name, None) is not None
+            if not changed and (missed or parked):
+                e.nbytes = getattr(e.host, "nbytes", 0) + sum(
+                    device_nbytes(b) for bs in e.dev.values() for b in bs
+                )
+                self._entries[name] = e
+            held = sum(x.nbytes for x in self._entries.values())
+            while bound and held > bound:
+                held -= self._entries.popitem(last=False)[1].nbytes
+                evictions += 1
+        metrics.add_many([
+            ("scan_store.hits", int(not missed)),
+            ("scan_store.misses", int(missed)),
+            ("scan_store.evictions", evictions),
+            ("scan_store.resident_bytes", held - before),
+        ])
+
+
+class ScanCache:
+    """One file's side of a ScanStore: what a file scan takes as
+    ``scan_cache``."""
+
+    def __init__(self, store: ScanStore, path: str) -> None:
+        self.store = store
+        self.path = path
+
+    @contextlib.contextmanager
+    def entry(
+        self, key: tuple, file_id: tuple[int, int], ctx: TaskContext
+    ) -> Iterator[_Entry]:
+        """The entry of ``key`` (whatever, besides the file, the cached
+        data depends on) for a scan that found the file as ``file_id``
+        BEFORE opening it. Held single-flight while the caller fills what
+        it lacks; what was filled is parked on the way out."""
+        name = (self.path, key)
+        with self.store._flight(name):
+            e = self.store._take(name, file_id)
+            had = e.filled()
+            yield e
+            self.store._serve(
+                name, e, e.filled() != had, ctx.config.scan_stream_mb() << 20
+            )
 
 
 class _StagedFileScanExec(ExecutionPlan):
@@ -140,15 +305,14 @@ class _StagedFileScanExec(ExecutionPlan):
         projection: list[str] | None = None,
         partitions: int = 1,
         batch_rows: int | None = None,
-        scan_cache: dict | None = None,
+        scan_cache: ScanCache | None = None,
     ) -> None:
-        """``scan_cache``: an optionally shared, registration-lifetime dict
-        (the context passes its per-table cache) holding the parsed host
-        table AND the uploaded DeviceBatches across queries, keyed by the
-        file's mtime so an overwritten file invalidates both tiers. The
-        same residency rationale as MemoryScanExec's device_cache — a
-        warm file scan otherwise re-parses AND re-uploads gigabytes per
-        query."""
+        """``scan_cache``: the file's side of a :class:`ScanStore` (a
+        context's or an executor's), holding the parsed host table AND
+        the uploaded DeviceBatches across queries; an overwritten file
+        invalidates both tiers. The same residency rationale as
+        MemoryScanExec's device_cache — a warm file scan otherwise
+        re-parses AND re-uploads gigabytes per query."""
         super().__init__()
         self.path = path
         self.table_schema = table_schema
@@ -159,16 +323,8 @@ class _StagedFileScanExec(ExecutionPlan):
         self.partitions = max(1, partitions)
         self.batch_rows = batch_rows
         self.scan_cache = scan_cache
-        self._table: pa.Table | None = None
-        self._narrow_cols: frozenset | None = None
-
-    def _mtime(self) -> float:
-        import os
-
-        try:
-            return os.stat(self.path).st_mtime
-        except OSError:
-            return -1.0
+        # without a scan cache: the parse, ONCE per operator
+        self._own = _Entry(None)
 
     def schema(self) -> Schema:
         return self._schema
@@ -179,35 +335,42 @@ class _StagedFileScanExec(ExecutionPlan):
     def _read(self) -> pa.Table:  # pragma: no cover — subclasses implement
         raise NotImplementedError
 
-    def execute(self, partition: int, ctx: TaskContext) -> Iterator[DeviceBatch]:
-        dev_cache = None
-        if self.scan_cache is not None:
-            mt = self._mtime()
-            hkey = ("host", mt)
-            if self._table is None:
-                self._table = self.scan_cache.get(hkey)
-            if self._table is None:
-                # a rewritten file drops BOTH tiers for the old mtime
-                self.scan_cache.clear()
-            dev_cache = self.scan_cache.setdefault(("dev", mt), {})
-        with self.metrics.time("read_time"), obs_trace.phase("task.scan_host"):
-            t = self._read()
-        if self.scan_cache is not None:
-            self.scan_cache[hkey] = t
-        if self._narrow_cols is None:
-            # computed ONCE per operator (not per partition) over the full
-            # parsed table, like _read caches the parse itself
-            from ballista_tpu.columnar.arrow_interop import (
-                narrowable_int64_cols,
-            )
+    def _parse_key(self) -> tuple:
+        """What, besides the file, ``_read``'s result depends on."""
+        return (self.table_schema,)
 
-            self._narrow_cols = narrowable_int64_cols(t)
+    def execute(self, partition: int, ctx: TaskContext) -> Iterator[DeviceBatch]:
+        if self.scan_cache is None:
+            yield from self._batches(self._own, None, partition, ctx)
+            return
+        # one entry, and so one flight, a file and a way of parsing it:
+        # the parse is whole-file
+        with self.scan_cache.entry(
+            self._parse_key(), _file_id(self.path), ctx
+        ) as e:
+            out = self._batches(e, e.dev, partition, ctx)
+        yield from out
+
+    def _batches(
+        self, e: _Entry, device_cache: dict | None, partition: int,
+        ctx: TaskContext,
+    ) -> list[DeviceBatch]:
+        def table() -> tuple[pa.Table, frozenset]:
+            if e.host is None:
+                with self.metrics.time("read_time"), obs_trace.phase(
+                    "task.scan_host"
+                ):
+                    t = self._read()
+                # narrowing decided ONCE per parsed table (not per
+                # partition), over all of it
+                e.host, e.narrow = t, narrowable_int64_cols(t)
+            return e.host, e.narrow
+
         mem = MemoryScanExec(
-            t, self.table_schema, self.projection, self.partitions,
-            self.batch_rows, device_cache=dev_cache,
+            table, self.table_schema, self.projection, self.partitions,
+            self.batch_rows, device_cache,
         )
-        mem.narrow_cols = self._narrow_cols
-        yield from mem.execute(partition, ctx)
+        return mem.batches(partition, ctx)
 
 
 class CsvScanExec(_StagedFileScanExec):
@@ -222,7 +385,7 @@ class CsvScanExec(_StagedFileScanExec):
         projection: list[str] | None = None,
         partitions: int = 1,
         batch_rows: int | None = None,
-        scan_cache: dict | None = None,
+        scan_cache: ScanCache | None = None,
     ) -> None:
         super().__init__(
             path, table_schema, projection, partitions, batch_rows,
@@ -234,24 +397,22 @@ class CsvScanExec(_StagedFileScanExec):
     def describe(self) -> str:
         return f"CsvScanExec: {self.path}, partitions={self.partitions}"
 
+    def _parse_key(self) -> tuple:
+        return (self.table_schema, self.has_header, self.delimiter)
+
     def _read(self) -> pa.Table:
-        # parse the file ONCE per operator: every partition slices the same
-        # parsed table (a per-partition read_csv would re-parse the whole
-        # file N times)
-        if self._table is None:
-            arrow_schema = schema_to_arrow(self.table_schema)
-            convert = pacsv.ConvertOptions(
-                column_types={f.name: f.type for f in arrow_schema}
-            )
-            read = pacsv.ReadOptions(
-                column_names=None if self.has_header else arrow_schema.names,
-            )
-            parse = pacsv.ParseOptions(delimiter=self.delimiter)
-            self._table = pacsv.read_csv(
-                self.path, read_options=read, parse_options=parse,
-                convert_options=convert,
-            )
-        return self._table
+        arrow_schema = schema_to_arrow(self.table_schema)
+        convert = pacsv.ConvertOptions(
+            column_types={f.name: f.type for f in arrow_schema}
+        )
+        read = pacsv.ReadOptions(
+            column_names=None if self.has_header else arrow_schema.names,
+        )
+        parse = pacsv.ParseOptions(delimiter=self.delimiter)
+        return pacsv.read_csv(
+            self.path, read_options=read, parse_options=parse,
+            convert_options=convert,
+        )
 
 
 class AvroScanExec(_StagedFileScanExec):
@@ -263,11 +424,9 @@ class AvroScanExec(_StagedFileScanExec):
         return f"AvroScanExec: {self.path}, partitions={self.partitions}"
 
     def _read(self) -> pa.Table:
-        if self._table is None:
-            from ballista_tpu.avro import read_avro
+        from ballista_tpu.avro import read_avro
 
-            self._table = read_avro(self.path)
-        return self._table
+        return read_avro(self.path)
 
 
 def _stat_value(v, dtype: DataType):
@@ -401,7 +560,7 @@ class ParquetScanExec(ExecutionPlan):
         partitions: int = 1,
         batch_rows: int | None = None,
         predicates: list | None = None,
-        scan_cache: dict | None = None,
+        scan_cache: ScanCache | None = None,
     ) -> None:
         super().__init__()
         self.path = path
@@ -466,6 +625,8 @@ class ParquetScanExec(ExecutionPlan):
         return kept
 
     def execute(self, partition: int, ctx: TaskContext) -> Iterator[DeviceBatch]:
+        # what a cache entry must have been read from: taken before the open
+        file_id = _file_id(self.path)
         f = papq.ParquetFile(self.path)
         kept = self._pruned_groups(f, ctx.config.parquet_pruning())
         per = -(-len(kept) // self.partitions) if kept else 0
@@ -474,50 +635,43 @@ class ParquetScanExec(ExecutionPlan):
         if not groups:
             yield DeviceBatch.empty(self._schema)
             return
-        if self.scan_cache is not None:
-            import os
-
-            try:
-                mt = os.stat(self.path).st_mtime
-            except OSError:
-                mt = -1.0
-            if self.scan_cache.get("mtime") != mt:
-                self.scan_cache.clear()  # rewritten file: drop both tiers
-                self.scan_cache["mtime"] = mt
         stream_mb = ctx.config.scan_stream_mb()
         if stream_mb:
             gbytes = self._projected_group_bytes(f, groups)
             if sum(gbytes) > stream_mb << 20:
                 yield from self._execute_streaming(
-                    f, groups, gbytes, ctx
+                    f, file_id, groups, gbytes, ctx
                 )
                 return
-        dev_cache = None
-        t = None
-        hkey = None
-        if self.scan_cache is not None:
-            sub = (tuple(groups), tuple(cols or ()))
-            hkey = ("host",) + sub
-            t = self.scan_cache.get(hkey)
-            dev_cache = self.scan_cache.setdefault(("dev",) + sub, {})
-        if t is None:
+
+        def table() -> tuple[pa.Table, frozenset]:
             with self.metrics.time("read_time"), obs_trace.phase(
                 "task.scan_host"
             ) as ph:
                 t = f.read_row_groups(groups, columns=cols)
                 ph.nbytes = t.nbytes
-            # column order must match the projected schema
-            t = t.select([fld.name for fld in self._schema])
-            if self.scan_cache is not None:
-                self.scan_cache[hkey] = t
-        mem = MemoryScanExec(
-            t, self._schema, None, 1, self.batch_rows,
-            device_cache=dev_cache,
-        )
-        # narrow by FILE-level statistics (all row groups), not this
-        # partition's subset — partitions must share one physical layout
-        mem.narrow_cols = self._narrowable_from_stats(f)
-        yield from mem.execute(0, ctx)
+            # column order must match the projected schema; narrow by
+            # FILE-level statistics (all row groups), not this partition's
+            # subset — partitions must share one physical layout
+            return (
+                t.select([fld.name for fld in self._schema]),
+                self._narrowable_from_stats(f),
+            )
+
+        def batches(device_cache: dict | None) -> list[DeviceBatch]:
+            return MemoryScanExec(
+                table, self._schema, None, 1, self.batch_rows, device_cache
+            ).batches(0, ctx)
+
+        if self.scan_cache is None:
+            yield from batches(None)
+            return
+        # the entry names exactly the row groups and columns served
+        with self.scan_cache.entry(
+            (tuple(groups), self._schema), file_id, ctx
+        ) as e:
+            out = batches(e.dev)
+        yield from out
 
     # -- streaming (larger-than-memory) path --------------------------------
 
@@ -546,45 +700,46 @@ class ParquetScanExec(ExecutionPlan):
             )
         return out
 
-    def _stream_dicts(self, f: "papq.ParquetFile") -> dict:
+    def _stream_dicts(
+        self, f: "papq.ParquetFile", file_id: tuple[int, int],
+        ctx: TaskContext,
+    ) -> dict:
         """Whole-file dictionary per projected STRING column, so every
-        streamed slice encodes identical codes (cached per registration —
+        streamed slice encodes identical codes (kept in the scan cache —
         the union pass reads just that column once)."""
-        import pyarrow.compute as pc
-
-        from ballista_tpu.columnar.batch import Dictionary
-
         out = {}
         for fld in self._schema:
             if fld.dtype != DataType.STRING:
                 continue
-            key = ("sdict", fld.name)
-            d = (
-                self.scan_cache.get(key)
-                if self.scan_cache is not None
-                else None
-            )
-            if d is None:
-                vals: set = set()
-                with self.metrics.time("dict_scan_time"):
-                    for rb in f.iter_batches(
-                        columns=[fld.name], batch_size=1 << 20
-                    ):
-                        uniq = pc.unique(rb.column(0))
-                        if pa.types.is_dictionary(uniq.type):
-                            uniq = uniq.cast(uniq.type.value_type)
-                        vals.update(
-                            v for v in uniq.to_pylist() if v is not None
-                        )
-                d = Dictionary(tuple(sorted(vals)))
-                if self.scan_cache is not None:
-                    self.scan_cache[key] = d
-            out[fld.name] = d
+            if self.scan_cache is None:
+                out[fld.name] = self._file_dict(f, fld.name)
+                continue
+            with self.scan_cache.entry(
+                ("sdict", fld.name), file_id, ctx
+            ) as e:
+                if e.host is None:
+                    e.host = self._file_dict(f, fld.name)
+                out[fld.name] = e.host
         return out
+
+    def _file_dict(self, f: "papq.ParquetFile", column: str):
+        import pyarrow.compute as pc
+
+        from ballista_tpu.columnar.batch import Dictionary
+
+        vals: set = set()
+        with self.metrics.time("dict_scan_time"):
+            for rb in f.iter_batches(columns=[column], batch_size=1 << 20):
+                uniq = pc.unique(rb.column(0))
+                if pa.types.is_dictionary(uniq.type):
+                    uniq = uniq.cast(uniq.type.value_type)
+                vals.update(v for v in uniq.to_pylist() if v is not None)
+        return Dictionary(tuple(sorted(vals)))
 
     def _execute_streaming(
         self,
         f: "papq.ParquetFile",
+        file_id: tuple[int, int],
         groups: list[int],
         gbytes: list[int],
         ctx: TaskContext,
@@ -593,7 +748,7 @@ class ParquetScanExec(ExecutionPlan):
 
         batch_rows = self.batch_rows or ctx.config.tpu_batch_rows()
         narrow = self._narrowable_from_stats(f)
-        dicts = self._stream_dicts(f)
+        dicts = self._stream_dicts(f, file_id, ctx)
         self.metrics.add("stream_slices", 0)
         names = [fld.name for fld in self._schema]
         slices: list[list[int]] = []

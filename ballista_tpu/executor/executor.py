@@ -22,6 +22,7 @@ from ballista_tpu.config import BallistaConfig
 from ballista_tpu.errors import ExecutionError
 from ballista_tpu.exec.base import run_with_capacity_retry
 from ballista_tpu.exec.planner import TableProvider
+from ballista_tpu.exec.scan import ScanStore
 from ballista_tpu.executor.shuffle import ShuffleWriterExec
 from ballista_tpu.executor import (
     effective_task_slots,
@@ -52,7 +53,9 @@ class Executor:
         self.executor_id = executor_id
         self.work_dir = work_dir
         self.provider = provider
-        self.codec = BallistaCodec(provider=provider)
+        # file scans decoded here share one executor-lifetime, byte-
+        # bounded store of what they read and uploaded (exec/scan.py)
+        self.codec = BallistaCodec(provider=provider, scan_store=ScanStore())
         # eager shuffle (docs/shuffle.md): readers poll the scheduler for
         # published map-output locations through a lazily-dialed channel;
         # the task loops (PollLoop/ExecutorServer) stamp the address and

@@ -599,9 +599,15 @@ class BallistaCodec:
         provider: TableProvider | None = None,
         extension: PhysicalExtensionCodec | None = None,
         mesh_runtime=None,
+        scan_store=None,
     ):
         self.provider = provider
         self.extension = extension or PhysicalExtensionCodec()
+        # an executor's exec.scan.ScanStore: decoded file scans share what
+        # earlier tasks read and uploaded. None (the scheduler's codec, a
+        # client's) decodes them with no cache: only an executor may hold
+        # table data
+        self.scan_store = scan_store
         # binds decoded Mesh*Exec nodes to THIS process's device mesh (an
         # executor decodes a scheduler-planned mesh stage-chain against its
         # own devices); None = build one lazily over all local devices
@@ -1118,19 +1124,22 @@ class BallistaCodec:
             )
         else:
             schema = schema_from_proto(n.table_schema)
+            scans = self.scan_store and self.scan_store.for_path(n.path)
             if n.kind == "csv":
                 plan = CsvScanExec(
                     n.path, schema, n.has_header, n.delimiter or ",",
-                    projection, n.partitions or 1,
+                    projection, n.partitions or 1, scan_cache=scans,
                 )
             elif n.kind == "avro":
                 plan = AvroScanExec(
                     n.path, schema, projection, n.partitions or 1,
+                    scan_cache=scans,
                 )
             else:
                 plan = ParquetScanExec(
                     n.path, schema, projection, n.partitions or 1,
                     predicates=[expr_from_proto(e) for e in n.filters],
+                    scan_cache=scans,
                 )
         # the physical planner stamps table_name on the plan it encodes;
         # dropping it on decode made decoded plans un-RE-encodable (memory
