@@ -2,8 +2,12 @@
 """The controls of ``correct``: the plain reference put in the program's
 place and computed in a lower precision. Each has to come out as not correct.
 
-    python3 perf/control.py --traffic power --seeds 11 12 13 [--sf 1]
+    python3 perf/control.py --traffic power --seeds 11 12 13
+                            [--config tpch-sf1-mem] [--sf 0.05]
                             [--precision float32|bfloat16]
+
+The data is the configuration's data set at its own size; ``--sf`` takes the
+size's place as ``--rehearse-sf`` does in ``run.py``.
 
 ``float32`` is the nearest precision below the float64 the configurations
 state, and the step a later PR would be tempted by (float64 is emulated on
@@ -31,7 +35,7 @@ import numpy as np
 HERE = pathlib.Path(__file__).resolve().parent
 sys.path.insert(0, str(HERE))
 
-import datagen  # noqa: E402
+import dataset  # noqa: E402
 import traffic  # noqa: E402
 import verify  # noqa: E402
 
@@ -51,15 +55,19 @@ PRECISIONS = {
 }
 
 
-def control_run(mix: dict, sf: float, seed: int,
-                precision: str = "float32") -> dict:
+def control_run(mix: dict, sf: float | None, seed: int,
+                precision: str = "float32", cfg: dict | None = None) -> dict:
     """One seed: the lower-precision reference judged as if it were the
-    program's answers. Returns ``verify.judge``'s verdict."""
+    program's answers. ``cfg`` is the configuration whose data set it is
+    (TPC-H without one), ``sf`` the rehearsal's size or ``None`` for the
+    configuration's own. Returns ``verify.judge``'s verdict."""
     import pyarrow as pa
 
+    cfg = cfg or {}
     templates = traffic.load_templates(dict.fromkeys(mix["templates"]))
     pool = traffic.pool(mix, templates)
-    frames = verify.frames(datagen.gen_all(sf, seed), templates)
+    frames = verify.frames(dataset.load(cfg).tables(cfg, seed, sf),
+                           templates)
     answers, references = [], {}
     for name, mod in templates.items():
         for k, params in enumerate(pool[name]):
@@ -75,16 +83,20 @@ def main() -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--traffic", default="power")
     ap.add_argument("--seeds", type=int, nargs="+", required=True)
-    ap.add_argument("--sf", type=float, default=1.0)
+    ap.add_argument("--config", default="tpch-sf1-mem",
+                    help="a configuration under perf/configs/")
+    ap.add_argument("--sf", type=float, default=None)
     ap.add_argument("--precision", choices=sorted(PRECISIONS),
                     default="float32")
     args = ap.parse_args()
     mix = traffic.load(args.traffic)
+    cfg = json.loads((HERE / "configs" / f"{args.config}.json").read_text())
     passed = 0
     for seed in args.seeds:
-        v = control_run(mix, args.sf, seed, args.precision)
+        v = control_run(mix, args.sf, seed, args.precision, cfg)
         print(json.dumps({
-            "seed": seed, "sf": args.sf, "precision": args.precision,
+            "seed": seed, "config": args.config, "sf": args.sf,
+            "precision": args.precision,
             "control_correct": v["correct"], "numbers": v["numbers"],
         }), flush=True)
         passed += bool(v["correct"])

@@ -10,7 +10,8 @@ every child is reaped on every exit path.
 
 Both give the harness the same few things: a client, the device as JAX
 reports it, compile counters, the scheduler's history, a profiler window and
-the device's peak memory.
+the device's peak memory. Both open the client's session under the
+configuration's ``session_settings``.
 """
 
 from __future__ import annotations
@@ -46,6 +47,26 @@ def fresh_hints(tmp: str) -> None:
     os.environ["BALLISTA_TPU_HINT_CACHE"] = hints
 
 
+def session_config(cfg: dict):
+    """The configuration's ``session_settings`` (the program's keys, as
+    ``docs/config.md`` lists them) as the ``BallistaConfig`` the
+    client's session is opened with; ``None``, the program's defaults, for
+    ``{}``. A key the program does not know, or a value it cannot parse,
+    ends the run here, before any work."""
+    settings = cfg.get("session_settings")
+    if not settings:
+        return None
+    from ballista_tpu.config import BallistaConfig, ConfigError
+
+    try:
+        return BallistaConfig({
+            k: str(v).lower() if isinstance(v, bool) else str(v)
+            for k, v in settings.items()
+        })
+    except ConfigError as e:
+        raise SystemExit(f"configuration: session_settings: {e}")
+
+
 class NoChip(Exception):
     """JAX found no TPU, or another number of chips than the cell asks for."""
 
@@ -79,6 +100,7 @@ def profiler_options():
 class Standalone:
     def __init__(self, cfg: dict, chips: int, rehearse: bool, trace: bool):
         self.cfg, self.chips, self.rehearse = cfg, chips, rehearse
+        self.session = session_config(cfg)
         self.ctx = None
         self.device: dict = {}
         self.trace_dir = None
@@ -97,7 +119,8 @@ class Standalone:
         from ballista_tpu.client.context import BallistaContext
 
         self.ctx = BallistaContext.standalone(
-            concurrent_tasks=self.cfg["concurrent_tasks"]
+            config=self.session,
+            concurrent_tasks=self.cfg["concurrent_tasks"],
         )
         for name, t in tables.items():
             self.ctx.register_table(name, t)
@@ -150,6 +173,7 @@ class Daemons:
     def __init__(self, cfg: dict, chips: int, rehearse: bool, trace: bool):
         self.cfg, self.chips, self.rehearse = cfg, chips, rehearse
         self.trace = trace
+        self.session = session_config(cfg)
         self.ctx = None
         self.device: dict = {}
         self.procs: list[subprocess.Popen] = []
@@ -261,7 +285,8 @@ class Daemons:
         check_device(self.device, self.chips, self.rehearse)
         from ballista_tpu.client.context import BallistaContext
 
-        self.ctx = BallistaContext.remote("127.0.0.1", self.sched_port)
+        self.ctx = BallistaContext.remote("127.0.0.1", self.sched_port,
+                                          self.session)
         for name in tables:
             self.ctx.register_parquet(
                 name, os.path.join(data, f"{name}.parquet")

@@ -5,7 +5,8 @@
 
 Everything about the cell comes from data: ``BENCHMARK.json`` names the
 cell's configuration and traffic mix and the metrics it reports;
-``perf/configs/<config>.json`` says how the system is deployed,
+``perf/configs/<config>.json`` says how the system is deployed, over which
+data set (``perf/datasets/<dataset>.py``) and under which session settings,
 ``perf/traffic/<traffic>.json`` what the clients send, ``perf/queries/`` holds
 the templates with their plain references, ``perf/layers/<metric>.py`` the
 reader of each per-layer metric. ``perf/README.md`` says how to add one.
@@ -13,7 +14,8 @@ reader of each per-layer metric. ``perf/README.md`` says how to add one.
 A run makes the data from ``--seed``, deploys, warms up every query the
 window may send (set-up, with all compilation), drives the closed loop for
 ``--seconds``, then frees the system and compares every answer the window
-returned with its reference. The last line of standard output is the result.
+returned with its reference. The last line of standard output is the result;
+standard error says where the run's seconds went, of the 360 it may take.
 It refuses to report on anything but a TPU; ``--rehearse-sf`` runs the whole
 of it on whatever JAX finds, at a small scale, and always says ``correct:
 false``.
@@ -41,7 +43,7 @@ for p in (str(ROOT), str(HERE)):
     if p not in sys.path:
         sys.path.insert(0, p)
 
-import datagen  # noqa: E402
+import dataset  # noqa: E402
 import deployments  # noqa: E402
 import traffic  # noqa: E402
 import verify  # noqa: E402
@@ -56,14 +58,40 @@ def die(msg: str, code: int = 1):
     raise SystemExit(code)
 
 
+# the driver stops a run that is still going after this many seconds, the
+# first of a checkout, which compiles, included (perf/README.md)
+RUN_LIMIT_S = 360
+
+
+class Stages:
+    """Where the run's seconds went: each stage ends where the next begins,
+    from the start of the process, so they add up to the run."""
+
+    def __init__(self, t0: float):
+        self.seconds: dict[str, float] = {}
+        self._t0 = self._at = t0
+
+    def end(self, name: str) -> float:
+        """The seconds since the last stage ended, counted under ``name``."""
+        now = time.time()
+        took, self._at = now - self._at, now
+        self.seconds[name] = self.seconds.get(name, 0.0) + took
+        return took
+
+    def line(self) -> str:
+        each = ", ".join(f"{n} {s:.1f}" for n, s in self.seconds.items())
+        return (f"stages: {each}; total {time.time() - self._t0:.1f} s "
+                f"of {RUN_LIMIT_S}")
+
+
 # -- the cell, from BENCHMARK.json ------------------------------------------
 
 
-def load_cell(name: str) -> dict:
-    bench = json.loads((ROOT / "BENCHMARK.json").read_text())
+def load_cell(name: str, bench_file: pathlib.Path) -> dict:
+    bench = json.loads(bench_file.read_text())
     cells = {w["name"]: w for w in bench["workloads"]}
     if name not in cells:
-        die(f"no workload {name!r} in BENCHMARK.json; there are "
+        die(f"no workload {name!r} in {bench_file.name}; there are "
             f"{sorted(cells)}", 2)
     cell = cells[name]
     config = next(c for c in bench["configs"] if c["name"] == cell["config"])
@@ -168,15 +196,18 @@ def end_to_end(name: str, obs: dict):
 # -- one run ------------------------------------------------------------------
 
 
-def run_cell(args) -> dict:
+def run_cell(args, bench_file=ROOT / "BENCHMARK.json") -> dict:
     """One run; returns the result line as a dict. With ``args.rehearse_sf``
     the look for a chip is skipped and the data is that small: ``correct`` is
-    then what the comparison said, and ``main`` never prints it as such."""
+    then what the comparison said, and ``main`` never prints it as such.
+    ``bench_file`` is the table of cells, for a test to hand in its own."""
     rehearse = args.rehearse_sf is not None
     if not (ROOT / "ballista_tpu" / "__init__.py").exists():
         die("the system under test (ballista_tpu/) is not in this checkout")
-    cell = load_cell(args.workload)
+    stages = Stages(T_PROCESS)
+    cell = load_cell(args.workload, bench_file)
     cfg, mix = cell["config"], cell["traffic"]
+    data = dataset.load(cfg)
     templates = traffic.load_templates(dict.fromkeys(mix["templates"]))
     pool = traffic.pool(mix, templates)
     peaks = json.loads((HERE / "peaks.json").read_text())
@@ -187,13 +218,12 @@ def run_cell(args) -> dict:
     try:
         try:
             dep.start()  # standalone: finds the chip or refuses, before any work
-            t0 = time.time()
-            scale = args.rehearse_sf if rehearse else cfg["scale_factor"]
-            tables = datagen.gen_all(scale, args.seed)
-            say(f"data: SF {scale} from seed {args.seed} in "
-                f"{time.time() - t0:.1f} s")
+            stages.end("start")
+            tables = data.tables(cfg, args.seed, args.rehearse_sf)
             rows = {n: t.num_rows for n, t in tables.items()}
-            t0 = time.time()
+            say(f"data: {dataset.name_of(cfg)} from seed {args.seed} in "
+                f"{stages.end('data'):.1f} s: "
+                + ", ".join(f"{n} {r} rows" for n, r in rows.items()))
             dep.load(tables)  # daemons: the executor names its device here
         except deployments.NoChip as e:
             die(str(e), 3)
@@ -201,10 +231,9 @@ def run_cell(args) -> dict:
             die(f"no peaks for device kind {dep.device['kind']!r} in "
                 "perf/peaks.json", 3)
         say(f"deployed {cfg['deployment']} on {dep.device} in "
-            f"{time.time() - t0:.1f} s")
-        t0 = time.time()
+            f"{stages.end('deploy'):.1f} s")
         warm_failed = warm_up(dep.ctx, mix, templates, pool)
-        say(f"warm-up: {time.time() - t0:.1f} s, {warm_failed} failed")
+        say(f"warm-up: {stages.end('warm-up'):.1f} s, {warm_failed} failed")
 
         # -- the window -------------------------------------------------
         before = dep.counters()
@@ -224,6 +253,7 @@ def run_cell(args) -> dict:
         if args.trace:
             traced["t1"] = time.time()
             xplane = dep.trace_stop()
+        stages.end("window")
         after = dep.counters()
         history = {}
         for table in ("system.queries", "system.task_attempts"):
@@ -239,9 +269,9 @@ def run_cell(args) -> dict:
         del tables
         dep.stop()
         peak = dep.peak_bytes()
+        stages.end("stop and history")
 
         # -- correct? every answer of the window, system freed ------------
-        t0 = time.time()
         failed = sum(1 for r in queries if r["error"] is not None)
         for r in queries:
             if r["error"]:
@@ -255,7 +285,7 @@ def run_cell(args) -> dict:
         verdict = verify.judge(answers, templates, references,
                                failed + warm_failed)
         say(f"verified {len(answers)} answers against {len(references)} "
-            f"references in {time.time() - t0:.1f} s")
+            f"references in {stages.end('verification'):.1f} s")
 
         obs = {
             "queries": queries, "setup_s": setup_s,
@@ -274,13 +304,12 @@ def run_cell(args) -> dict:
         if args.trace:
             import reduce_trace
 
-            t0 = time.time()
             if xplane is None:
                 die("the profiler wrote no trace")
             obs["trace"] = reduce_trace.reduce(
                 xplane, traced["t0"], traced["t1"], queries
             )
-            say(f"reduced the trace in {time.time() - t0:.1f} s")
+            say(f"reduced the trace in {stages.end('trace reduction'):.1f} s")
             device["busy_s"] = obs["trace"]["busy_s"]
             device["window_s"] = obs["trace"]["window_s"]
             if obs["trace"]["breakdown"]:
@@ -291,6 +320,7 @@ def run_cell(args) -> dict:
                 if value is not None:
                     result["metrics"][m["name"]] = {
                         "value": value, "unit": m["unit"]}
+            stages.end("trace reduction")
         else:
             for m in cell["end_to_end"]:
                 value = end_to_end(m["name"], obs)
@@ -298,6 +328,7 @@ def run_cell(args) -> dict:
                     result["metrics"][m["name"]] = {
                         "value": value, "unit": m["unit"]}
         result["compared"] = verdict["numbers"]
+        say(stages.line())
         if verdict["first_mismatch"]:
             say(f"first mismatch: {verdict['first_mismatch']}")
         say(f"largest relative error by column: {verdict['by_column']}")

@@ -39,3 +39,14 @@ def test_float64_reference_is_correct():
     answers = [(t, k, pa.Table.from_pandas(r, preserve_index=False))
                for (t, k), r in refs.items()]
     assert verify.judge(answers, templates, refs, 0)["correct"]
+
+
+def test_control_is_not_correct_on_the_q3_mix():
+    """``load-q3-4c`` draws q3's parameters anew (the pool is seeded by the
+    template's place in the mix): its one number separates float32 too."""
+    mix = traffic.load("load-q3-4c")
+    for seed in (1, 2, 3):
+        verdict = control.control_run(mix, 0.05, seed)
+        n = verdict["numbers"]
+        assert not verdict["correct"]
+        assert n["relerr_q3"]["value"] > n["relerr_q3"]["limit"]

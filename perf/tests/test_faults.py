@@ -2,7 +2,8 @@
 whatever JAX finds) comes out correct; with the timed path broken underneath
 it comes out not correct. The faults a query engine can have: an answer
 altered where it is produced, rows left out, and a stale answer (another
-parameter draw's) served in place of the query's own."""
+parameter draw's) served in place of the query's own. Each cell of templates
+of its own has them planted in its own templates."""
 
 import argparse
 
@@ -13,7 +14,14 @@ import pytest
 import run
 
 
-def drive(monkeypatch, fault=None):
+USUAL = {"altered": "q6", "rows_left_out": "q3", "stale": "q1"}
+FLOAT_COLUMN = {"q6": 0, "q3": 1}  # revenue
+
+
+def drive(monkeypatch, fault=None, workload="tpch-sf1-mem.power",
+          where=None):
+    """One rehearsal of ``workload`` with ``fault`` planted in the answers of
+    the template ``where`` (the fault's usual one without it)."""
     from ballista_tpu.client import context
 
     sound = context.RemoteDataFrame.collect
@@ -23,19 +31,20 @@ def drive(monkeypatch, fault=None):
         table = sound(self)
         kind = "q6" if table.num_columns == 1 else (
             "q1" if table.num_columns == 10 else "q3")
-        if fault == "altered" and kind == "q6":
-            col = pc.multiply(table.column(0), 1 + 1e-6)
-            return table.set_column(0, table.schema.field(0), col)
-        if fault == "rows_left_out" and kind == "q3":
+        if kind != (where or USUAL[fault]):
+            return table
+        if fault == "altered":
+            at = FLOAT_COLUMN[kind]
+            col = pc.multiply(table.column(at), 1 + 1e-6)
+            return table.set_column(at, table.schema.field(at), col)
+        if fault == "rows_left_out":
             return table.slice(0, table.num_rows - 1)
-        if fault == "stale" and kind == "q1":
-            return seen.setdefault(kind, table)
-        return table
+        return seen.setdefault(kind, table)  # stale
 
     if fault:
         monkeypatch.setattr(context.RemoteDataFrame, "collect", broken)
     return run.run_cell(argparse.Namespace(
-        workload="tpch-sf1-mem.power", seed=2_400_000_011, seconds=4.0,
+        workload=workload, seed=2_400_000_011, seconds=4.0,
         trace=0, rehearse_sf=0.01,
     ))
 
@@ -56,3 +65,17 @@ def test_broken_path_is_not_correct(monkeypatch, fault):
         assert n["mismatched"]["value"] == 0
     else:
         assert n["mismatched"]["value"] > 0
+
+
+@pytest.mark.parametrize("fault", [None, "altered", "rows_left_out", "stale"])
+def test_the_q3_cell_under_four_callers(monkeypatch, fault):
+    result = drive(monkeypatch, fault, "tpch-sf1-mem.load-q3-4c", "q3")
+    n = result["compared"]
+    assert set(result["metrics"]) == {"queries_per_s", "setup_s"}
+    if fault is None:
+        assert result["correct"] and result["failed"] == 0
+    elif fault == "altered":
+        assert not result["correct"]
+        assert n["relerr_q3"]["value"] > n["relerr_q3"]["limit"]
+    else:
+        assert not result["correct"] and n["mismatched"]["value"] > 0
