@@ -175,20 +175,45 @@ def _column_to_np(
     return arr, null_mask, None
 
 
-def batch_from_arrow(rb: pa.RecordBatch | pa.Table, capacity: int | None = None) -> DeviceBatch:
-    """One Arrow batch/table -> one DeviceBatch."""
+def _columns_to_np(
+    table: pa.RecordBatch | pa.Table,
+    schema: Schema,
+    narrow_cols: frozenset | None = None,
+    fixed_dicts: dict | None = None,
+):
+    """Every column of ``table`` through ``_column_to_np``: Arrow to the
+    host numpy the device will hold is the ``task.scan_host`` phase, but
+    for the STRING columns, whose encoding against a sorted dictionary is
+    host work per dictionary entry and the ``task.dict_merge`` phase.
+    Returns the arrays, the null masks and the dictionaries by field name."""
     from ballista_tpu.obs import trace as obs_trace
 
+    fields = list(zip(schema, table.schema.names))
+    done: dict[str, tuple] = {}
+    for phase, strings in (("task.scan_host", False), ("task.dict_merge", True)):
+        mine = [
+            (f, n) for f, n in fields if (f.dtype == DataType.STRING) == strings
+        ]
+        if not mine:
+            continue
+        with obs_trace.phase(phase) as ph:
+            for field, name in mine:
+                done[name] = _column_to_np(
+                    table.column(name), field.dtype,
+                    narrow=None if narrow_cols is None else name in narrow_cols,
+                    fixed_dict=(fixed_dicts or {}).get(name),
+                )
+                ph.nbytes += done[name][0].nbytes
+    arrays = [done[n][0] for _, n in fields]
+    nulls = [done[n][1] for _, n in fields]
+    dicts = {f.name: done[n][2] for f, n in fields if done[n][2] is not None}
+    return arrays, nulls, dicts
+
+
+def batch_from_arrow(rb: pa.RecordBatch | pa.Table, capacity: int | None = None) -> DeviceBatch:
+    """One Arrow batch/table -> one DeviceBatch."""
     schema = schema_from_arrow(rb.schema)
-    arrays, nulls, dicts = [], [], {}
-    with obs_trace.phase("task.scan_host") as ph:
-        for field, name in zip(schema, rb.schema.names):
-            arr, nm, d = _column_to_np(rb.column(name), field.dtype)
-            ph.nbytes += arr.nbytes
-            arrays.append(arr)
-            nulls.append(nm)
-            if d is not None:
-                dicts[field.name] = d
+    arrays, nulls, dicts = _columns_to_np(rb, schema)
     return DeviceBatch.from_host(
         schema, arrays, num_rows=rb.num_rows, dictionaries=dicts, nulls=nulls,
         capacity=capacity,
@@ -235,22 +260,13 @@ def table_from_arrow(
     from ballista_tpu.obs import trace as obs_trace
 
     schema = schema_from_arrow(table.schema)
-    # Encode strings table-wide so all slices share dictionaries.
-    cols_np, nulls_np, dicts = [], [], {}
-    # Arrow to the host numpy the device will hold: "task.scan_host"
-    with obs_trace.phase("task.scan_host") as ph:
-        if narrow_cols is None:
+    if narrow_cols is None:
+        with obs_trace.phase("task.scan_host"):
             narrow_cols = narrowable_int64_cols(table)
-        for field, name in zip(schema, table.schema.names):
-            arr, nm, d = _column_to_np(
-                table.column(name), field.dtype, narrow=name in narrow_cols,
-                fixed_dict=(fixed_dicts or {}).get(name),
-            )
-            ph.nbytes += arr.nbytes
-            cols_np.append(arr)
-            nulls_np.append(nm)
-            if d is not None:
-                dicts[field.name] = d
+    # Encode strings table-wide so all slices share dictionaries.
+    cols_np, nulls_np, dicts = _columns_to_np(
+        table, schema, narrow_cols, fixed_dicts
+    )
     n = table.num_rows
     if n == 0:
         return [DeviceBatch.empty(schema)]
@@ -273,6 +289,8 @@ def batch_to_arrow(
 ) -> pa.RecordBatch:
     """Gather live rows to host and decode dictionaries back to strings.
     ``site`` names the caller for the device read (DeviceBatch.to_host)."""
+    from ballista_tpu.obs import trace as obs_trace
+
     schema, cols, nulls = batch.to_host(site)
     arrays = []
     import pyarrow.compute as pc
@@ -294,11 +312,13 @@ def batch_to_arrow(
                 # All rows of this column were null at encode time.
                 arr = pa.nulls(len(col), type=pa.string())
             else:
-                values = pa.array(d.values, type=pa.string())
-                codes = np.clip(col, 0, len(d) - 1).astype(np.int32)
-                arr = pa.DictionaryArray.from_arrays(
-                    pa.array(codes, type=pa.int32()), values
-                ).cast(pa.string())
+                # codes back to strings: host work per dictionary entry
+                with obs_trace.phase("task.dict_merge"):
+                    values = pa.array(d.values, type=pa.string())
+                    codes = np.clip(col, 0, len(d) - 1).astype(np.int32)
+                    arr = pa.DictionaryArray.from_arrays(
+                        pa.array(codes, type=pa.int32()), values
+                    ).cast(pa.string())
         elif field.dtype == DataType.DATE32:
             arr = pa.array(col.astype("int32"), type=pa.int32()).cast(pa.date32())
         elif field.dtype == DataType.TIMESTAMP_US:

@@ -15,6 +15,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ballista_tpu.columnar.batch import Dictionary
+from ballista_tpu.obs import trace as obs_trace
 
 
 def merge_dictionaries(
@@ -24,13 +25,31 @@ def merge_dictionaries(
 
     ``remap_a[old_code] = new_code`` (and likewise ``remap_b``). Sorted-merge
     keeps the merged dictionary order-preserving, so remapped codes still
-    compare like the strings they encode.
+    compare like the strings they encode. Host work per entry: the
+    ``task.dict_merge`` phase (docs/observability.md).
     """
-    merged = tuple(sorted(set(a.values) | set(b.values)))
+    with obs_trace.phase("task.dict_merge"):
+        merged, (remap_a, remap_b) = merge_many([a, b])
+    return merged, remap_a, remap_b
+
+
+def merge_many(
+    dicts: list[Dictionary],
+) -> tuple[Dictionary, list[np.ndarray]]:
+    """The sorted union of ``dicts`` and, for each, the table from its
+    codes to the union's: one pass over the entries, whatever the number
+    of dictionaries, where folding ``merge_dictionaries`` over them sorts
+    the growing union once per dictionary. The caller holds the
+    ``task.dict_merge`` phase."""
+    merged = tuple(sorted(set().union(*(d.values for d in dicts))))
     pos = {v: i for i, v in enumerate(merged)}
-    remap_a = np.asarray([pos[v] for v in a.values], dtype=np.int32)
-    remap_b = np.asarray([pos[v] for v in b.values], dtype=np.int32)
-    return Dictionary(merged), remap_a, remap_b
+    tables: dict[int, np.ndarray] = {}
+    for d in dicts:
+        if id(d) not in tables:
+            tables[id(d)] = np.asarray(
+                [pos[v] for v in d.values], dtype=np.int32
+            )
+    return Dictionary(merged), [tables[id(d)] for d in dicts]
 
 
 def remap_codes(codes: jnp.ndarray, table: np.ndarray) -> jnp.ndarray:

@@ -23,6 +23,11 @@ telemetry uses) and keeps process-global counters:
 - ``phase.<name>.seconds`` / ``.count`` / ``.bytes`` — the host phases of
   the served path (``obs.trace.phase``, docs/observability.md), and for
   ``task.d2h`` the same three per call site (``...:<site>``).
+- ``agg.capacity_retries`` / ``agg.sort_passes`` / ``agg.dense_passes`` /
+  ``agg.groups_out`` — the grouped aggregate (docs/observability.md):
+  tasks run again after a ``CapacityError``, device passes dispatched by
+  kind, rows the final aggregates emitted. Declared at 0, so that a
+  reader tells "none" from "a program without the counter".
 
 Counters surface per executor through the heartbeat -> scheduler REST
 path (docs/compile_cache.md) and per query through bench.py's tracked
@@ -34,7 +39,11 @@ from __future__ import annotations
 import threading
 
 _LOCK = threading.Lock()
-_COUNTERS: dict[str, float] = {}
+AGG_COUNTERS = (
+    "agg.capacity_retries", "agg.sort_passes", "agg.dense_passes",
+    "agg.groups_out",
+)
+_COUNTERS: dict[str, float] = dict.fromkeys(AGG_COUNTERS, 0)
 _INSTALLED = False
 
 # jax monitoring event -> (counter incremented per event, duration-sum

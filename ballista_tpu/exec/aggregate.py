@@ -37,6 +37,7 @@ from ballista_tpu.ops.aggregate import (
     group_aggregate,
     scalar_aggregate,
 )
+from ballista_tpu.compilecache import metrics as compile_metrics
 from ballista_tpu.obs import trace as obs_trace
 from ballista_tpu.ops.concat import concat_batches
 from ballista_tpu.ops.fetch import read_array
@@ -634,6 +635,11 @@ def finalize_state(
     )
 
 
+_OVERFLOW_MESSAGE = (
+    "aggregate exceeded group capacity; raise ballista.tpu.agg_capacity"
+)
+
+
 class HashAggregateExec(ExecutionPlan):
     """mode='partial' emits group keys + state columns per input partition;
     mode='final' merges partial outputs into final values (single output
@@ -867,6 +873,7 @@ class HashAggregateExec(ExecutionPlan):
         else:
             dec_unscale = [None] * len(val_cols)
         if vocab is not None:
+            compile_metrics.add("agg.dense_passes")
             res = dense_group_aggregate(
                 key_cols, key_nulls, vocab, batch.valid, val_cols,
                 val_nulls, list(ops),
@@ -892,6 +899,7 @@ class HashAggregateExec(ExecutionPlan):
                 else None
             )
             cached = cache.get(skey) if skey is not None else None
+            compile_metrics.add("agg.sort_passes")
             res = group_aggregate(
                 key_cols, key_nulls, batch.valid, val_cols, val_nulls,
                 list(ops), cap, presorted=cached is True,
@@ -911,11 +919,14 @@ class HashAggregateExec(ExecutionPlan):
                 ctx.defer_learn(skey, res.input_was_sorted)
         if ctx is not None:
             ctx.defer_check(
-                res.overflow,
-                "aggregate exceeded group capacity; raise "
-                "ballista.tpu.agg_capacity",
-                required=res.n_groups,
+                res.overflow, _OVERFLOW_MESSAGE, required=res.n_groups
             )
+            if vocab is None:
+                # a dense state has a slot for every key: only the sort
+                # path's can overflow (see _raise_overflow)
+                ctx.run_state.setdefault("agg_overflow", []).append(
+                    (res.overflow, res.n_groups)
+                )
         else:
             res.check_overflow()
         state_schema = batch.schema if from_state else self._schema
@@ -958,6 +969,30 @@ class HashAggregateExec(ExecutionPlan):
             nulls=out.nulls,
             dictionaries=dicts,
         )
+
+    @staticmethod
+    def _raise_overflow(ctx: TaskContext) -> None:
+        """Raise the ``CapacityError`` of a sort-path pass that overflowed,
+        before a fold and not at the task's end. An overflowed state is
+        truncated, so folding it is work the retry throws away: on a cold
+        process, the sort and segment programs of a state size the retry
+        never uses (PR 29: the first h2oai group-by of a process compiled
+        them at 3 x 65,536 rows and again at 3 x 131,072). The flags were
+        computed with their passes and their host copies started then
+        (``defer_check``); the read waits for the last pass."""
+        pending = ctx.run_state.pop("agg_overflow", None)
+        if not pending:
+            return
+        import numpy as np
+
+        from ballista_tpu.errors import CapacityError
+
+        with obs_trace.phase("task.d2h", site="agg.overflow") as ph:
+            read = [(np.asarray(f), np.asarray(r)) for f, r in pending]
+            ph.nbytes = sum(f.nbytes + r.nbytes for f, r in read)
+        fired = [int(required) for flag, required in read if flag]
+        if fired:
+            raise CapacityError(_OVERFLOW_MESSAGE, required=max(fired))
 
     @staticmethod
     def _dense_vocab(batch: DeviceBatch, n_groups: int) -> list[int] | None:
@@ -1022,6 +1057,7 @@ class HashAggregateExec(ExecutionPlan):
         bp_prev = None  # previous fold's async-copied backpressure flag
 
         def fold(states: list[DeviceBatch]) -> DeviceBatch:
+            self._raise_overflow(ctx)
             # slice states down to a learned capacity first (they are
             # front-compacted), keeping the fold's row count proportional
             # to actual groups, not capacity

@@ -417,6 +417,11 @@ def run_with_capacity_retry(
             ctx.deferred_checks.clear()
             ctx.speculative_checks.clear()
             ctx.clean_commits.clear()
+            # the whole of ``fn`` runs again: a window that counts one of
+            # these ran a task twice (docs/observability.md)
+            from ballista_tpu.compilecache import metrics
+
+            metrics.add("agg.capacity_retries")
             base = override or config.agg_capacity()
             need = max(e.required + 1, base * 2)
             # grown capacities snap to the capacity-bucket ladder: an

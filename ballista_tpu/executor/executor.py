@@ -433,6 +433,18 @@ class Executor:
             task.task_id.job_id, task.task_id.stage_id,
             task.task_id.partition_id, plan,
         )
+        # rows the task's final aggregates emitted, from the counters the
+        # collector has just resolved: no device read of its own
+        groups_out = sum(
+            r["counters"].get("output_rows", 0)
+            for r in op_metrics or ()
+            if r["operator"] == "HashAggregateExec"
+            and "mode=final" in r["describe"]
+        )
+        if groups_out:
+            from ballista_tpu.compilecache import metrics as compile_metrics
+
+            compile_metrics.add("agg.groups_out", groups_out)
         # cost accounting (docs/observability.md): this attempt's
         # resource vector — wall/CPU around the run, the plan's
         # data-plane counters (shuffle read, spill, push), the committed

@@ -345,6 +345,7 @@ PHASES = (
     "task.d2h",
     "task.shuffle_write",
     "task.shuffle_fetch",
+    "task.dict_merge",
     "task.hints_save",
     "task.report",
 )

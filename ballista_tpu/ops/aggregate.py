@@ -383,39 +383,129 @@ def _stacked_reduce(
 _PREFIX_BLOCK = 512
 
 
-def _mm_prefix(x2: jnp.ndarray, block: int) -> jnp.ndarray:
-    """(n, M) -> inclusive prefix along axis 0 via recursive blocked
-    upper-triangular matmuls (no cumsum ops anywhere)."""
-    n, m = x2.shape
-    prec = jax.lax.Precision.HIGHEST
-    if n <= block:
-        u = (
-            jax.lax.broadcasted_iota(jnp.int32, (n, n), 0)
-            <= jax.lax.broadcasted_iota(jnp.int32, (n, n), 1)
-        ).astype(x2.dtype)
-        return jnp.einsum("kj,km->jm", u, x2, precision=prec)
-    nb = -(-n // block)
-    xp = jnp.pad(x2, ((0, nb * block - n), (0, 0)))
-    x3 = xp.reshape(nb, block, m)
+def _block_prefix(x3: jnp.ndarray) -> jnp.ndarray:
+    """(nb, block, M) floats -> the inclusive prefix along axis 1, as ONE
+    2-D matmul of the rows (nb * M, block) with the upper-triangular ones
+    (no cumsum ops anywhere). As an ``einsum("kj,bkm->bjm")`` the same
+    product costs the TPU compiler 40 s where M is 1 and 2M rows deep, 3-10 s
+    otherwise; as rows times a square it costs 1-7 s at every M (PR 29,
+    compiled here for a described v5e)."""
+    nb, block, m = x3.shape
     u = (
         jax.lax.broadcasted_iota(jnp.int32, (block, block), 0)
         <= jax.lax.broadcasted_iota(jnp.int32, (block, block), 1)
-    ).astype(x2.dtype)
-    inner = jnp.einsum("kj,bkm->bjm", u, x3, precision=prec)
+    ).astype(x3.dtype)
+    rows = x3.transpose(0, 2, 1).reshape(nb * m, block)
+    out = jnp.dot(rows, u, precision=jax.lax.Precision.HIGHEST)
+    return out.reshape(nb, m, block).transpose(0, 2, 1)
+
+
+def _mm_prefix(x2: jnp.ndarray, block: int) -> jnp.ndarray:
+    """(n, M) -> inclusive prefix along axis 0 via recursive blocked
+    upper-triangular matmuls."""
+    n, m = x2.shape
+    nb = -(-n // block)
+    x3 = jnp.pad(x2, ((0, nb * block - n), (0, 0))).reshape(nb, block, m)
+    inner = _block_prefix(x3)
+    if nb == 1:
+        return inner.reshape(block, m)[:n]
     bsums = x3.sum(axis=1)
     offs = _mm_prefix(bsums, block) - bsums
     return (inner + offs[:, None, :]).reshape(nb * block, m)[:n]
 
 
+def _blocked_prefix(x2: jnp.ndarray, block: int) -> jnp.ndarray:
+    """(n, M) integers -> inclusive prefix along axis 0 as cumsums of
+    ``block`` rows and a recursive prefix over the blocks' sums: integer
+    addition is associative, so the result is the stock op's, bit for
+    bit."""
+    n, m = x2.shape
+    if n <= block:
+        return jnp.cumsum(x2, axis=0)
+    nb = -(-n // block)
+    xp = jnp.pad(x2, ((0, nb * block - n), (0, 0)))
+    inner = jnp.cumsum(xp.reshape(nb, block, m), axis=1)
+    bsums = inner[:, -1, :]
+    offs = _blocked_prefix(bsums, block) - bsums
+    return (inner + offs[:, None, :]).reshape(nb * block, m)[:n]
+
+
+# Integer cumsums are exact but, on the TPU, as slow to compile over a long
+# axis as the float ones: the stock op over (524288, 2) int64 costs this
+# compiler 213-314 s and over (2M, 2) 55-69 s, int32[2M] 14-21 s (PR 29,
+# compiled here for a described v5e), and every (rows, columns, dtype) a
+# query reaches is a program of its own. The same prefix as cumsums along a
+# short axis compiles in 2-5 s at any length.
+_INT_PREFIX_BLOCK = 2048
+
+
 def _prefix_sum_2d(x2: jnp.ndarray) -> jnp.ndarray:
     """Inclusive prefix along axis 0, routed per dtype/backend (see the
-    compile-time note above)."""
-    if (
-        jnp.issubdtype(x2.dtype, jnp.floating)
-        and jax.default_backend() != "cpu"
-    ):
+    compile-time notes above)."""
+    if jax.default_backend() == "cpu":
+        return jnp.cumsum(x2, axis=0)
+    if jnp.issubdtype(x2.dtype, jnp.floating):
         return _mm_prefix(x2, _PREFIX_BLOCK)
-    return jnp.cumsum(x2, axis=0)
+    return _blocked_prefix(x2, _INT_PREFIX_BLOCK)
+
+
+def _float_prefix_parts(x2: jnp.ndarray, block: int = _PREFIX_BLOCK):
+    """(n, M) floats -> the inclusive prefix along axis 0 in two levels,
+    for ``_float_seg_totals``: ``inner`` (nb * block, M), the prefix within
+    each block of ``block`` rows; ``bsums`` (nb + 1, M), the blocks' sums
+    and a zero; ``offs`` (nb + 1, M), the sums of the blocks before each,
+    so ``offs[nb]`` is the grand total. ``offs[b] + inner[i]`` is the
+    global prefix, and is never formed: see ``_float_seg_totals``."""
+    n, m = x2.shape
+    nb = -(-n // block)
+    x3 = jnp.pad(x2, ((0, nb * block - n), (0, 0))).reshape(nb, block, m)
+    if jax.default_backend() == "cpu":
+        inner = jnp.cumsum(x3, axis=1)
+        bsums = inner[:, -1, :]
+        incl = jnp.cumsum(bsums, axis=0)
+    else:
+        inner = _block_prefix(x3)
+        bsums = x3.sum(axis=1)
+        incl = _mm_prefix(bsums, block)
+    zero = jnp.zeros((1, m), x2.dtype)
+    return (
+        inner.reshape(nb * block, m),
+        jnp.concatenate([bsums, zero]),
+        jnp.concatenate([zero, incl]),
+    )
+
+
+def _float_seg_totals(parts, ps, out_valid, block: int = _PREFIX_BLOCK):
+    """Per-segment totals from ``_float_prefix_parts`` and the segments'
+    start rows ``ps`` (increasing over the live slots ``out_valid``).
+
+    A total is the prefix before the next segment's start less the prefix
+    before this one's. Taken of the global prefix, that difference loses
+    what the prefix's magnitude costs: 2M rows of values near 50 run to
+    1e8, and the TPU's emulated float64 carries some 1e-14 of it, 5e-10 of
+    a 20-row group's sum (measured, PR 29), where float32 gives 7e-8. In
+    two levels the large terms cancel exactly or never arise. With
+    ``b0, b1`` the blocks of the two starts and ``w0, w1`` the prefixes
+    within them,
+
+        total = (w1 - w0) + [b1 > b0] * bsums[b0]
+                + (offs[b1] - offs[min(b0 + 1, b1)])
+
+    and the last term is x - x = 0 unless the segment holds a whole block
+    between its ends, so a segment within two blocks never sees a number
+    larger than a block's sum, and a longer one sees the blocks' prefix
+    only against a sum of its own size."""
+    inner, bsums, offs = parts
+    end = inner.shape[0]  # one past the last row: block nb, nothing within
+    st = jnp.where(out_valid, jnp.clip(ps, 0, end), end)
+    b0 = st // block
+    within = (st % block) > 0
+    w0 = jnp.where(within[:, None], inner[jnp.clip(st - 1, 0, end - 1)], 0)
+    b1 = jnp.concatenate([b0[1:], jnp.full(1, end // block, b0.dtype)])
+    w1 = jnp.concatenate([w0[1:], jnp.zeros_like(w0[:1])])
+    whole = offs[b1] - offs[jnp.minimum(b0 + 1, b1)]
+    first = jnp.where((b1 > b0)[:, None], bsums[b0], 0)
+    return (w1 - w0) + first + whole
 
 
 def _same_val(a, b):
@@ -578,7 +668,7 @@ def _seg_part1(
                 (perm[1:] > perm[:-1]) | (iota[1:] >= n_live)
             )
 
-    seg = jnp.cumsum(changed.astype(jnp.int32)) - 1
+    seg = _prefix_sum_2d(changed.astype(jnp.int32)[:, None])[:, 0] - 1
     n_groups = jnp.sum(changed.astype(jnp.int32))
     overflow = n_groups > capacity
     # dead rows (and overflow segments) scatter out of bounds -> dropped.
@@ -613,7 +703,7 @@ def _seg_part1(
         or [jnp.zeros(n, jnp.int32)],
         axis=1,
     )
-    cnt_cs = jnp.cumsum(cnt_stack, axis=0)
+    cnt_cs = _prefix_sum_2d(cnt_stack)
 
     # running sums, stacked per accumulator dtype
     sum_cs = []
@@ -625,7 +715,12 @@ def _seg_part1(
             ).astype(acc_t)
             for i in idxs
         ]
-        sum_cs.append(_prefix_sum_2d(jnp.stack(contribs, axis=1)))
+        stacked = jnp.stack(contribs, axis=1)
+        sum_cs.append(
+            _float_prefix_parts(stacked)
+            if jnp.issubdtype(acc_t, jnp.floating)
+            else _prefix_sum_2d(stacked)
+        )
     mm_vals = []
     for i in mm_idx:
         vc, live = val_cols[i], lives[i]
@@ -690,7 +785,12 @@ def _seg_part2(
     cnt_tot = seg_totals(cnt_cs)
     live_slot = {k: j for j, k in enumerate(live_layout)}
     sum_slot: dict[int, tuple[int, int]] = {}
-    sum_tots = [seg_totals(cs2d) for cs2d in sum_cs]
+    sum_tots = [
+        _float_seg_totals(cs, ps, out_valid)
+        if isinstance(cs, (tuple, list))
+        else seg_totals(cs)
+        for cs in sum_cs
+    ]
     for gi, (dt, idxs) in enumerate(sum_layout):
         for j, i in enumerate(idxs):
             sum_slot[i] = (gi, j)

@@ -12,15 +12,18 @@ from __future__ import annotations
 import jax.numpy as jnp
 
 from ballista_tpu.columnar.batch import DeviceBatch, Dictionary, round_capacity
-from ballista_tpu.columnar.dict_util import merge_dictionaries, remap_codes
+from ballista_tpu.columnar.dict_util import merge_many, remap_codes
 from ballista_tpu.datatypes import DataType, Schema
 from ballista_tpu.errors import InternalError
+from ballista_tpu.obs import trace as obs_trace
 
 
 def unify_dictionaries(
     batches: list[DeviceBatch], schema: Schema
 ) -> list[DeviceBatch]:
-    """Remap STRING columns of all batches onto shared dictionaries."""
+    """Remap STRING columns of all batches onto shared dictionaries. The
+    host side of it (comparing, merging, the remap tables: work per
+    dictionary entry) is the ``task.dict_merge`` phase."""
     out = batches
     for i, field in enumerate(schema):
         if field.dtype != DataType.STRING:
@@ -31,15 +34,16 @@ def unify_dictionaries(
             raise InternalError(
                 f"string column {field.name!r} missing dictionary in concat"
             )
-        if all(d.values == dicts[0].values for d in dicts):
-            continue
-        merged = dicts[0]
-        for d in dicts[1:]:
-            merged, _, _ = merge_dictionaries(merged, d)
+        if all(d is dicts[0] for d in dicts):
+            continue  # one partition's states: nothing to compare
+        with obs_trace.phase("task.dict_merge"):
+            if all(d.values == dicts[0].values for d in dicts):
+                continue
+            # one merge over all of them, and one remap table for each
+            # distinct dictionary object
+            merged, remaps = merge_many(dicts)
         new_batches = []
-        for b, n, d in zip(out, names, dicts):
-            _, remap, _ = merge_dictionaries(d, merged)
-            # remap maps d-codes into merge(d, merged) == merged order
+        for b, n, remap in zip(out, names, remaps):
             cols = list(b.columns)
             cols[i] = remap_codes(b.columns[i], remap)
             dd = dict(b.dictionaries)

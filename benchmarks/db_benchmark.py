@@ -8,6 +8,13 @@ the official x.csv inputs aren't downloadable here; pass --data to use a
 real G1 file). Questions the engine doesn't support yet are skipped with
 a note, mirroring how the reference comments out unsupported questions.
 
+This script runs the questions through the local ``TpuContext`` and has
+produced no tracked number. The tracked measurement of the group-by
+questions is the benchmark's cell ``h2o-g1-1e7-mem.groupby`` (BENCHMARK.json,
+``perf/queries/g1q{3,5,2,7}.sql``: questions 3, 5, 2 and 7 of the SQL below
+on the served path at 1e7 rows, each answer held to a plain reference;
+PERF.md §4).
+
 Usage: python benchmarks/db_benchmark.py [--n 1e6] [--k 100] [--iterations 2]
 """
 

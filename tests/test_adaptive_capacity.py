@@ -72,3 +72,46 @@ def test_scalar_aggregate_unaffected():
     ctx.register_table("t", t)
     out = ctx.sql("SELECT SUM(v) AS s FROM t").collect().to_pandas()
     assert out.s[0] == pytest.approx(4950.0)
+
+
+def test_overflow_is_raised_before_the_fold_not_after_it():
+    """Ten batches of two keys (no single int key, so the partial folds every
+    four states): the first four passes overflow 256 slots, and the attempt
+    ends at the first fold, with those four passes and no fold behind it.
+    The retry and the warm run take the same passes."""
+    from ballista_tpu.compilecache import metrics
+
+    def moved(before):
+        now = metrics.snapshot()
+        return {k: now[k] - before.get(k, 0) for k in (
+            "agg.capacity_retries", "agg.sort_passes",
+            "phase.task.d2h.count:agg.overflow") if k in now}
+
+    n = 40_000
+    rng = np.random.default_rng(5)
+    a, b = rng.integers(0, 60, n), rng.integers(0, 50, n)
+    t = pa.table({"a": pa.array(a), "b": pa.array(b),
+                  "v": pa.array(rng.integers(1, 6, n))})
+    cfg = (BallistaConfig()
+           .with_setting("ballista.tpu.agg_capacity", "256")
+           .with_setting("ballista.tpu.batch_rows", "4096"))
+    ctx = TpuContext(cfg)
+    ctx.register_table("t", t)
+    sql = "SELECT a, b, SUM(v) AS s FROM t GROUP BY a, b"
+    before = metrics.snapshot()
+    out = ctx.sql(sql).collect().to_pandas()
+    first = moved(before)
+    before = metrics.snapshot()
+    again = ctx.sql(sql).collect().to_pandas()
+    warm = moved(before)
+    want = (t.to_pandas().groupby(["a", "b"]).agg(s=("v", "sum"))
+            .reset_index())
+    for got in (out, again):
+        got = got.sort_values(["a", "b"]).reset_index(drop=True)
+        assert got.equals(want.astype(got.dtypes.to_dict()))
+    assert first["agg.capacity_retries"] == 1
+    assert warm["agg.capacity_retries"] == 0
+    assert first["agg.sort_passes"] == warm["agg.sort_passes"] + 4
+    # a read before every fold that follows sort-path passes
+    assert first["phase.task.d2h.count:agg.overflow"] == (
+        warm["phase.task.d2h.count:agg.overflow"] + 1)

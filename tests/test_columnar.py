@@ -102,3 +102,32 @@ def test_tz_timestamp_normalized_to_utc():
     back = batch_to_arrow(batch_from_arrow(t))
     assert back.schema.field("ts").type == pa.timestamp("us")
     assert [x.timestamp() for x in back.column("ts").to_pylist()] == [1.0, 2.0]
+
+
+def test_concat_unifies_many_dictionaries_in_one_merge():
+    """Three batches whose string column was encoded apart: one sorted union,
+    a remap table each, and one entry of the ``task.dict_merge`` phase for the
+    column; batches that share a dictionary object merge nothing."""
+    from ballista_tpu.compilecache import metrics
+    from ballista_tpu.ops.concat import concat_batches
+
+    def merges():
+        return metrics.snapshot().get("phase.task.dict_merge.count", 0)
+
+    words = [["pear", "apple", "pear"], ["fig", "apple"], ["zest", "fig", "kiwi"]]
+    batches = [
+        batch_from_arrow(pa.table({"s": pa.array(w), "n": pa.array(range(len(w)))}))
+        for w in words
+    ]
+    assert len({b.dictionaries["s"].values for b in batches}) == 3
+    before = merges()
+    out = concat_batches(batches)
+    assert merges() == before + 1
+    assert out.dictionaries["s"].values == ("apple", "fig", "kiwi", "pear", "zest")
+    live = np.asarray(out.valid)
+    got = np.asarray(out.columns[0])[live]
+    assert [out.dictionaries["s"].values[c] for c in got] == sum(words, [])
+    before = merges()
+    same = concat_batches([batches[0], batches[0]])
+    assert merges() == before  # the same object: not even compared
+    assert same.dictionaries["s"] is batches[0].dictionaries["s"]

@@ -177,7 +177,11 @@ def test_filter_projection_sorted_aggregate_chain(S, tpu_branches):
         step, S((n,), "int32"), S((n,), "float64"), S((n,), "float64"),
         S((n,), "float64"), S((n,), "bool"),
     )
-    assert " sort(" in text and "cumsum" not in text
+    assert " sort(" in text
+    # integers take cumsums of short blocks (ops/aggregate._blocked_prefix);
+    # a float one costs this compiler two minutes
+    assert not any("cumsum" in line and "f64[" in line
+                   for line in text.splitlines())
 
 
 def test_mesh_aggregate_stage_on_four_chips(topo, tpu_branches):
@@ -204,3 +208,51 @@ def test_mesh_aggregate_stage_on_four_chips(topo, tpu_branches):
         (0, 1), (2, 3, 2), (AggOp.SUM, AggOp.SUM, AggOp.COUNT), 2048, 2048,
     )
     assert "all-to-all" in _compile(prog, cols, nulls, arg("bool"))
+
+
+def test_group_by_segment_programs_at_the_h2o_cell_shape(S, tpu_branches):
+    """The two programs of the sort path's segment reduction as one 2M-row
+    batch of ``h2o-g1-1e7-mem.groupby``'s g1q3 reaches them (an int32 key, an
+    int32 and a float64 sum, a count; 131,072 slots): integer prefixes by
+    blocks and float totals in two levels, each a few seconds to compile
+    where the stock cumsums and the one-column einsum cost this compiler 124 s
+    (PERF.md, PR 29)."""
+    from ballista_tpu.ops import aggregate as A
+    from ballista_tpu.ops.aggregate import AggOp
+
+    n, cap = 2 * N, 1 << 17
+    ops = (AggOp.SUM, AggOp.SUM, AggOp.COUNT)
+    dtypes = ("int32", "float64", "float64")
+    layouts = A._seg_layouts(dtypes, (False,) * 3, ops)
+
+    def part1(valid, key, vals, perm):
+        return A._seg_part1(valid, [key], [None], list(vals), [None] * 3,
+                            perm, ops, cap, False, *layouts)
+
+    args = (S((n,), "bool"), S((n,), "int32"),
+            tuple(S((n,), d) for d in dtypes), S((n,), "int32"))
+    text = _compile(part1, *args)
+    assert not any("cumsum" in line and "f64[" in line
+                   for line in text.splitlines())
+    n_groups, _, _, _, ps, cnt_cs, sum_cs, mm_vals = jax.eval_shape(part1, *args)
+    floats = [cs for cs in sum_cs if isinstance(cs, tuple)]
+    assert len(floats) == 1 and [a.shape for a in floats[0]] == [
+        (n, 1), (n // 512 + 1, 1), (n // 512 + 1, 1)]
+
+    def part2(n_groups, ps, cnt_cs, sum_cs, key):
+        res = A._seg_part2(n_groups, ps, cnt_cs, list(sum_cs), [], [key],
+                           [None], ops, cap, *layouts)
+        return res.keys, res.values, res.valid
+
+    def place(x):
+        return jax.tree.map(lambda a: S(a.shape, a.dtype), x)
+
+    _compile(part2, place(n_groups), place(ps), place(cnt_cs),
+             place(tuple(sum_cs)), S((n,), "int32"))
+
+
+@pytest.mark.parametrize("dtype", ["int32", "int64"])
+def test_integer_prefix_by_blocks(S, tpu_branches, dtype):
+    from ballista_tpu.ops import aggregate as A
+
+    _compile(A._prefix_sum_2d, S((1 << 19, 2), dtype))
