@@ -28,6 +28,12 @@ telemetry uses) and keeps process-global counters:
   tasks run again after a ``CapacityError``, device passes dispatched by
   kind, rows the final aggregates emitted. Declared at 0, so that a
   reader tells "none" from "a program without the counter".
+- ``poll.rpcs`` / ``poll.wakes_by_status`` (executor) and ``poll.holds`` /
+  ``poll.holds_granted`` / ``poll.holds_timed_out`` (scheduler) — the pull
+  loop's hand-off (docs/observability.md): ``PollWork`` calls sent, waits
+  between polls that a finished task ended, idle executors' polls the
+  scheduler held, and those that ended in a grant or at the bound.
+  Declared at 0 likewise.
 
 Counters surface per executor through the heartbeat -> scheduler REST
 path (docs/compile_cache.md) and per query through bench.py's tracked
@@ -43,7 +49,13 @@ AGG_COUNTERS = (
     "agg.capacity_retries", "agg.sort_passes", "agg.dense_passes",
     "agg.groups_out",
 )
-_COUNTERS: dict[str, float] = dict.fromkeys(AGG_COUNTERS, 0)
+# the pull loop's hand-off (docs/serving.md): polls sent and waits ended by
+# a finished task (executor); polls held, and how a hold ended (scheduler)
+POLL_COUNTERS = (
+    "poll.rpcs", "poll.wakes_by_status", "poll.holds", "poll.holds_granted",
+    "poll.holds_timed_out",
+)
+_COUNTERS: dict[str, float] = dict.fromkeys(AGG_COUNTERS + POLL_COUNTERS, 0)
 _INSTALLED = False
 
 # jax monitoring event -> (counter incremented per event, duration-sum

@@ -8,6 +8,7 @@ report status on next poll).
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import logging
 import queue
@@ -35,8 +36,11 @@ from ballista_tpu.serde import BallistaCodec
 
 log = logging.getLogger(__name__)
 
-POLL_INTERVAL = 0.1  # ref execution_loop.rs:110-112 (100ms idle sleep)
-
+# ref execution_loop.rs:110-112 (100ms idle sleep). Here it is only the BOUND
+# of the pull loop's wait between two polls: a finished task ends the wait at
+# once, and an idle executor's poll is held by the scheduler until a task is
+# runnable (docs/serving.md), so neither a status nor a grant waits it out
+POLL_INTERVAL = 0.1
 
 
 class Executor:
@@ -611,9 +615,16 @@ class PollLoop:
         task_slots = effective_task_slots(task_slots)
         self.task_slots = task_slots
         self._available = threading.Semaphore(task_slots)
+        # (perf_counter at put, TaskStatus): the stamp feeds the phase
+        # executor.status_wait when a poll drains the entry
         self._statuses: queue.Queue = queue.Queue()
         self._stop = threading.Event()
+        # set by a task thread once its status is queued (and by stop):
+        # ends the loop's wait between two polls
+        self._wake = threading.Event()
         self._thread: threading.Thread | None = None
+        # the poll channel, for stop() to close under a held PollWork
+        self._channel = None
         # AOT kernel prewarm (docs/compile_cache.md); mode resolution and
         # the start sequence are shared with ExecutorServer
         from ballista_tpu.compilecache import prewarm as prewarm_mod
@@ -635,6 +646,12 @@ class PollLoop:
 
     def stop(self) -> None:
         self._stop.set()
+        self._wake.set()
+        channel = self._channel
+        if channel is not None:
+            # a poll the scheduler is holding ends now (CANCELLED), not at
+            # the hold's bound; run() closes the channel again, harmlessly
+            channel.close()
         if self._prewarm is not None:
             # zero-thread-leak shutdown: cancel queued prewarm compiles
             # and join the pool (tests/test_shutdown_hygiene.py)
@@ -668,6 +685,7 @@ class PollLoop:
         tok = reswitness.acquire(
             "grpc-channel", f"poll-loop->{self.scheduler_addr}"
         )
+        self._channel = channel
         stub = scheduler_stub(channel)
         try:
             self._poll(stub)
@@ -678,9 +696,11 @@ class PollLoop:
             reswitness.release(tok)
 
     def _poll(self, stub) -> None:
-        while not self._stop.is_set():
-            from ballista_tpu.testing import faults
+        from ballista_tpu.compilecache import metrics as compile_metrics
+        from ballista_tpu.obs import hist as obs_hist
+        from ballista_tpu.testing import faults
 
+        while not self._stop.is_set():
             inj = faults.active()
             if inj is not None and inj.heartbeat_suppressed(
                 self.executor.executor_id
@@ -691,83 +711,111 @@ class PollLoop:
                 # status drain: statuses are drained exactly once, so
                 # draining first and then skipping the poll would lose
                 # them permanently across a bounded blackout
-                time.sleep(POLL_INTERVAL)
+                self._stop.wait(POLL_INTERVAL)
                 continue
-            # drain completed statuses (ref :219-239)
-            statuses = []
-            while True:
-                try:
-                    statuses.append(self._statuses.get_nowait())
-                except queue.Empty:
-                    break
+            # cleared BEFORE the drain: a status queued after this line
+            # sets the flag again and ends the wait at the round's end
+            self._wake.clear()
             # free-slot count for batched grants (docs/serving.md):
             # drain the semaphore non-blocking, count, release. This
             # thread is the only grant consumer, so the count only ever
             # UNDER-advertises (a task finishing mid-count frees a slot
             # we don't report) — the scheduler never grants more tasks
             # than the _run_task acquires below can absorb unblocked.
+            # Counted BEFORE the statuses are drained, and a task queues
+            # its status before it frees its slot: a poll that reports
+            # every slot free carries every status there is, so the
+            # scheduler may hold it and no status waits behind the hold
             free_slots = 0
             while self._available.acquire(blocking=False):
                 free_slots += 1
             for _ in range(free_slots):
                 self._available.release()
             can_accept = free_slots > 0
-            from ballista_tpu.compilecache import metrics as compile_metrics
-            from ballista_tpu.obs import hist as obs_hist
-
+            # drain completed statuses (ref :219-239)
+            statuses = []
+            waited = 0.0
+            now = time.perf_counter()
+            while True:
+                try:
+                    queued_at, st = self._statuses.get_nowait()
+                except queue.Empty:
+                    break
+                statuses.append(st)
+                waited += now - queued_at
+            if statuses:
+                obs_trace.account(
+                    "executor.status_wait", waited, len(statuses)
+                )
             spans = obs_trace.drain_outbox()
             hist_deltas = obs_hist.REGISTRY.drain_deltas()
-            try:
-                result = stub.PollWork(
-                    pb.PollWorkParams(
-                        metadata=self._metadata(),
-                        can_accept_task=can_accept,
-                        task_status=statuses,
-                        # compile-latency observability: pull-mode liveness
-                        # IS the poll, so the counter snapshot rides it
-                        metrics=[
-                            pb.KeyValuePair(key=k, value=str(v))
-                            for k, v in compile_metrics.snapshot().items()
-                        ],
-                        # drained trace spans + latency-histogram deltas
-                        # ride the same liveness RPC
-                        # (docs/observability.md)
-                        spans=[obs_trace.span_to_proto(s) for s in spans],
-                        hists=obs_hist.deltas_to_proto(hist_deltas),
-                        free_slots=free_slots,
-                    )
-                )
-            except grpc.RpcError as e:
-                log.warning("poll_work failed: %s", e)
-                # re-enqueue the drained statuses (and spans, and
-                # histogram deltas) for the next successful poll —
-                # dropping them left tasks RUNNING forever on the
-                # scheduler (statuses are reported exactly once; spans
-                # and histogram deltas ship exactly once too)
-                for st in statuses:
-                    self._statuses.put(st)
-                obs_trace.requeue_outbox(spans)
-                obs_hist.REGISTRY.requeue_deltas(hist_deltas)
-                time.sleep(1.0)
-                continue
-            # batched grants (docs/serving.md): a batching scheduler
-            # fills `tasks` (first grant mirrored into `task`); a
-            # pre-batching scheduler sets only `task`
-            tasks = list(result.tasks)
-            if not tasks and result.HasField("task"):
-                tasks = [result.task]
-            if tasks:
-                for td in tasks:
-                    self._run_task(td)
-            elif free_slots == self.task_slots:
-                # asleep with NO task running: idle the scheduler (or the
-                # client) has to fill. With a task running the same sleep
-                # is no phase: on a trace it would cover, and so take the
-                # label of, every gap the running tasks can explain
-                with obs_trace.phase("executor.poll_sleep"):
-                    time.sleep(POLL_INTERVAL)
-            else:
-                time.sleep(POLL_INTERVAL)
+            request = pb.PollWorkParams(
+                metadata=self._metadata(),
+                can_accept_task=can_accept,
+                task_status=statuses,
+                # compile-latency observability: pull-mode liveness
+                # IS the poll, so the counter snapshot rides it
+                metrics=[
+                    pb.KeyValuePair(key=k, value=str(v))
+                    for k, v in compile_metrics.snapshot().items()
+                ],
+                # drained trace spans + latency-histogram deltas
+                # ride the same liveness RPC
+                # (docs/observability.md)
+                spans=[obs_trace.span_to_proto(s) for s in spans],
+                hists=obs_hist.deltas_to_proto(hist_deltas),
+                free_slots=free_slots,
+            )
+            # blocked with NO task running: idle the scheduler (or the
+            # client) has to fill, whether the scheduler holds the poll
+            # or the loop waits out the rest of POLL_INTERVAL. With a
+            # task running the same wait is no phase: on a trace it
+            # would cover, and so take the label of, every gap the
+            # running tasks can explain
+            idle = (
+                obs_trace.phase("executor.poll_sleep")
+                if free_slots == self.task_slots
+                else contextlib.nullcontext()
+            )
+            with idle:
+                sent = time.perf_counter()
+                compile_metrics.add("poll.rpcs")
+                try:
+                    result = stub.PollWork(request)
+                except grpc.RpcError as e:
+                    if not self._stop.is_set():
+                        log.warning("poll_work failed: %s", e)
+                    # re-enqueue the drained statuses (and spans, and
+                    # histogram deltas) for the next successful poll —
+                    # dropping them left tasks RUNNING forever on the
+                    # scheduler (statuses are reported exactly once; spans
+                    # and histogram deltas ship exactly once too)
+                    now = time.perf_counter()
+                    for st in statuses:
+                        self._statuses.put((now, st))
+                    obs_trace.requeue_outbox(spans)
+                    obs_hist.REGISTRY.requeue_deltas(hist_deltas)
+                    self._stop.wait(1.0)
+                    continue
+                # batched grants (docs/serving.md): a batching scheduler
+                # fills `tasks` (first grant mirrored into `task`); a
+                # pre-batching scheduler sets only `task`
+                tasks = list(result.tasks)
+                if not tasks and result.HasField("task"):
+                    tasks = [result.task]
+                if not tasks:
+                    # nothing granted: what is left of POLL_INTERVAL since
+                    # the poll was sent (nothing, if the scheduler held
+                    # it that long), or until a task's status is queued
+                    left = POLL_INTERVAL - (time.perf_counter() - sent)
+                    if (
+                        left > 0
+                        and self._wake.wait(left)
+                        and not self._stop.is_set()
+                    ):
+                        compile_metrics.add("poll.wakes_by_status")
+            for td in tasks:
+                self._run_task(td)
 
     def _run_task(self, task: pb.TaskDefinition) -> None:
         """ref run_received_tasks :129-217 (panic-catching thread spawn)."""
@@ -779,24 +827,30 @@ class PollLoop:
             cost = None
             t0, c0 = time.perf_counter(), time.thread_time()
             try:
-                result = self.executor.execute_shuffle_write(task)
-            except BaseException as e:  # noqa: BLE001 (catch_unwind parity)
-                error = f"{type(e).__name__}: {e}\n{traceback.format_exc()}"
-                log.error("task %s failed: %s", task.task_id, error)
-                # the failed attempt still consumed resources — charge it
-                # (docs/observability.md cost accounting)
-                cost = failed_attempt_cost(
-                    task, time.perf_counter() - t0, time.thread_time() - c0
-                )
-            finally:
-                self._available.release()
-            with obs_trace.phase("task.report"):
-                self._statuses.put(
-                    as_task_status(
+                try:
+                    result = self.executor.execute_shuffle_write(task)
+                except BaseException as e:  # noqa: BLE001 (catch_unwind parity)
+                    error = (
+                        f"{type(e).__name__}: {e}\n{traceback.format_exc()}"
+                    )
+                    log.error("task %s failed: %s", task.task_id, error)
+                    # the failed attempt still consumed resources — charge
+                    # it (docs/observability.md cost accounting)
+                    cost = failed_attempt_cost(
+                        task, time.perf_counter() - t0,
+                        time.thread_time() - c0,
+                    )
+                with obs_trace.phase("task.report"):
+                    status = as_task_status(
                         task.task_id, self.executor.executor_id, result,
                         error, cost=cost,
                     )
-                )
+                    self._statuses.put((time.perf_counter(), status))
+            finally:
+                # the slot is freed AFTER the status is queued (see the
+                # free-slot count in _poll), then the loop is woken
+                self._available.release()
+                self._wake.set()
 
         # fire-and-forget by design: concurrency is bounded by the task
         # slot semaphore and completion is observed through the status

@@ -251,7 +251,7 @@ class ExecutorServer:
                 idle = self._running == 0
             try:
                 # every slot asleep with nothing to run (the pull loop's
-                # POLL_INTERVAL sleep is the same phase); summed over slots
+                # held poll is the same phase); summed over slots
                 with (
                     obs_trace.phase("executor.poll_sleep")
                     if idle else contextlib.nullcontext()

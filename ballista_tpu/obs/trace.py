@@ -338,7 +338,9 @@ PHASES = (
     "scheduler.plan",
     "scheduler.grant",
     "scheduler.status",
+    "scheduler.grant_wait",
     "executor.poll_sleep",
+    "executor.status_wait",
     "task.decode",
     "task.scan_host",
     "task.h2d",
@@ -434,6 +436,16 @@ class phase:
                 live.attrs[attr] = round(live.attrs.get(attr, 0.0) + dt, 6)
                 break
         return False
+
+
+def account(name: str, seconds: float, count: int = 1) -> None:
+    """Add to ``phase.<name>.seconds|count`` a wait that no thread spent
+    inside a ``with`` block: it began on one thread (a status queued, a
+    stage made runnable) and ended on another (the poll that drained or
+    granted it). Counters only: there is no stretch of one thread to
+    annotate, and nothing for a profiler to see."""
+    keys = _phase_names(name, "")[2]
+    metrics.add_many(((keys[0], seconds), (keys[1], count)))
 
 
 # ---------------------------------------------------------------------------

@@ -83,6 +83,23 @@ class ExecutorManager:
                 0, min(d.total_task_slots, d.available_task_slots + delta)
             )
 
+    def free_slot_since(self, executor_id: str, free: bool) -> float:
+        """Pull mode: note whether the executor is left with a free slot,
+        and return since when it has had one through every poll (now, if
+        this poll brought the news). A task that was grantable before
+        then waited for a slot, not for the hand-off: the phase
+        ``scheduler.grant_wait`` counts from the later of the two."""
+        now = time.time()
+        with self._lock:
+            d = self._data.get(executor_id)
+            if d is None:
+                return now
+            if not free:
+                d.free_since_s = 0.0
+            elif not d.free_since_s:
+                d.free_since_s = now
+            return d.free_since_s or now
+
     def get_executor_data(self, executor_id: str) -> ExecutorData | None:
         with self._lock:
             return self._data.get(executor_id)

@@ -7,7 +7,8 @@ event loop (scheduler_server/query_stage_scheduler.rs:40-473):
   ExecuteQuery -> plan (SQL -> logical -> optimized -> physical)
               -> JobSubmitted event -> DistributedPlanner stage split
               -> stage DAG submit (running if deps resolved, else pending)
-  PollWork    -> heartbeat + apply statuses + hand out <=1 task (pull mode)
+  PollWork    -> heartbeat + apply statuses + hand out tasks (pull mode);
+                 an idle executor's poll is held until one is runnable
   StageFinished -> resolve dependent stages (patch shuffle locations)
   JobFinished -> assemble CompletedJob partition locations
 """
@@ -20,6 +21,7 @@ import logging
 import random
 import string
 import threading
+import time as _time
 
 from ballista_tpu.analysis.witness import make_lock
 from ballista_tpu.config import BallistaConfig, TaskSchedulingPolicy
@@ -63,6 +65,19 @@ from ballista_tpu.sql.parser import parse_sql
 from ballista_tpu.sql.planner import SqlPlanner
 
 log = logging.getLogger(__name__)
+
+# Held polls (docs/serving.md): PollWork holds the poll of an executor that
+# reports no running task until a task is grantable for it, or this long.
+# The bound keeps the poll what it also is: the pull mode's heartbeat and
+# the ride home of the executor's counters and spans (/api/state), several
+# times a second.
+POLL_HOLD_S = 0.25
+# workers of the scheduler's gRPC pool (start_scheduler_grpc). A held poll
+# occupies one until it ends, so at most half of them hold; a poll beyond
+# that is answered at once, as before, and the executor waits its own
+# POLL_INTERVAL
+GRPC_WORKERS = 16
+MAX_HELD_POLLS = GRPC_WORKERS // 2
 
 
 def generate_job_id() -> str:
@@ -264,6 +279,9 @@ class QueryStageScheduler(EventAction):
         # query_stage_scheduler.rs:403-408)
         if s.policy == TaskSchedulingPolicy.PUSH_STAGED:
             return ReviveOffers()
+        # pull mode: the event has been HANDLED (stages submitted or
+        # promoted, a bypass task queued): held polls look again
+        s.notify_grantable()
         return None
 
 
@@ -418,10 +436,19 @@ class SchedulerServer:
         from ballista_tpu.scheduler.result_cache import ResultCache
 
         self.result_cache = ResultCache(self.config.result_cache_mb() << 20)
+        # (job_id, unix seconds queued): the stamp starts the bypass
+        # task's scheduler.grant_wait
         self._bypass_pending: collections.deque = collections.deque()
         self._bypass_running: dict[str, str] = {}  # job_id -> executor_id
         self._bypass_attempts: dict[str, int] = {}
         self.obs_bypass_total = 0
+        # held polls (next_tasks_held): waiters sleep on _work_cv; whatever
+        # makes a task grantable bumps _work_gen under it and wakes them
+        # all. Its lock is a leaf: nothing else is taken while it is held
+        self._work_cv = threading.Condition()
+        self._work_gen = 0
+        self._held_polls = 0
+        self._stopping = False
         self.state = None
         if state_backend is not None:
             from ballista_tpu.scheduler.persistent_state import (
@@ -490,7 +517,7 @@ class SchedulerServer:
             )
             for jid in lost_bypass:
                 del self._bypass_running[jid]
-                self._bypass_pending.append(jid)
+                self._bypass_pending.append((jid, _time.time()))
         reset = self.stage_manager.reset_tasks_of_executors(expired)
         log.warning(
             "executors %s expired; reset %d running tasks", expired, len(reset)
@@ -522,6 +549,9 @@ class SchedulerServer:
             self.policy == TaskSchedulingPolicy.PUSH_STAGED
         ):
             self.event_loop.post(ReviveOffers())
+        # tasks went back to PENDING: another executor's held poll may
+        # take them now
+        self.notify_grantable()
         return sorted(expired)
 
     # -- locked accessors (racelint unguarded-field discipline) --------------
@@ -2231,7 +2261,7 @@ class SchedulerServer:
         return tasks[0] if tasks else None
 
     def next_tasks(
-        self, executor_id: str, max_n: int
+        self, executor_id: str, max_n: int, free_since: float = 0.0
     ) -> list[pb.TaskDefinition]:
         """Batched pull-mode handout (docs/serving.md): up to ``max_n``
         task definitions for one PollWork round-trip. Bypass grants go
@@ -2240,11 +2270,18 @@ class SchedulerServer:
         (assign_next_tasks — the pick/mark race stays closed per batch),
         and only when nothing else was runnable, a single eager-shuffle
         task (eager consumers soak otherwise-idle slots; granting them a
-        whole batch would starve runnable work arriving mid-poll)."""
+        whole batch would starve runnable work arriving mid-poll).
+
+        ``free_since``: since when the polling executor is known to have
+        had a free slot (``ExecutorManager.free_slot_since``). The phase
+        ``scheduler.grant_wait`` counts each first-granted task from when
+        it became grantable or from then, whichever is later: the wait
+        for the hand-off, without the wait for a slot."""
         max_n = max(1, max_n)
         out: list[pb.TaskDefinition] = []
+        grantable: list[float] = []  # when each such task became so
         while len(out) < max_n:
-            td = self._next_bypass_task(executor_id)
+            td = self._next_bypass_task(executor_id, grantable)
             if td is None:
                 break
             out.append(td)
@@ -2252,9 +2289,21 @@ class SchedulerServer:
             for picked in self.stage_manager.assign_next_tasks(
                 executor_id, max_n - len(out)
             ):
+                since = self.stage_manager.first_grant_since(*picked[:3])
                 td = self._task_def_from_pick(picked, eager_pick=False)
                 if td is not None:
                     out.append(td)
+                    if since is not None:
+                        grantable.append(since)
+        if grantable:
+            from ballista_tpu.obs import trace as obs_trace
+
+            now = _time.time()
+            obs_trace.account(
+                "scheduler.grant_wait",
+                sum(max(0.0, now - max(g, free_since)) for g in grantable),
+                len(grantable),
+            )
         if not out:
             picked = self._pick_eager_task(executor_id)
             if picked is not None:
@@ -2262,6 +2311,71 @@ class SchedulerServer:
                 if td is not None:
                     out.append(td)
         return out
+
+    def notify_grantable(self) -> None:
+        """Something may have become grantable: every held poll looks
+        again (all of them: ``next_tasks`` decides who gets what, as it
+        does between concurrent polls). Called after the event loop has
+        handled an event, after statuses were applied (a retry went back
+        to PENDING, a map output made an eager pick possible) and after an
+        expiry sweep reset tasks."""
+        with self._work_cv:
+            self._work_gen += 1
+            self._work_cv.notify_all()
+
+    def next_tasks_held(
+        self, executor_id: str, max_n: int, hold: bool, still_wanted,
+        free_since: float = 0.0,
+    ) -> list[pb.TaskDefinition]:
+        """:meth:`next_tasks`, and when it finds nothing and ``hold`` says
+        the poll may wait (the executor reports no running task, so no
+        status can queue up behind it): wait until something becomes
+        grantable and look again, until the first grant, ``POLL_HOLD_S``,
+        shutdown, or ``still_wanted()`` turning false (the RPC was
+        cancelled: a grant into it would strand its tasks RUNNING)."""
+        from ballista_tpu.compilecache import metrics
+        from ballista_tpu.obs import trace as obs_trace
+
+        def grant() -> list[pb.TaskDefinition]:
+            with obs_trace.phase("scheduler.grant"):
+                return self.next_tasks(executor_id, max_n, free_since)
+
+        # read BEFORE looking: a notify between the look and the wait
+        # has moved the generation, and the wait does not begin
+        with self._work_cv:
+            gen = self._work_gen
+        tasks = grant()
+        if tasks or not hold:
+            return tasks
+        with self._work_cv:
+            if self._stopping or self._held_polls >= MAX_HELD_POLLS:
+                return []
+            self._held_polls += 1
+        metrics.add("poll.holds")
+        deadline = _time.monotonic() + POLL_HOLD_S
+        try:
+            while True:
+                with self._work_cv:
+                    while (
+                        self._work_gen == gen
+                        and not self._stopping
+                        and still_wanted()
+                    ):
+                        left = deadline - _time.monotonic()
+                        if left <= 0:
+                            metrics.add("poll.holds_timed_out")
+                            return []
+                        self._work_cv.wait(left)
+                    if self._stopping or not still_wanted():
+                        return []
+                    gen = self._work_gen
+                tasks = grant()
+                if tasks:
+                    metrics.add("poll.holds_granted")
+                    return tasks
+        finally:
+            with self._work_cv:
+                self._held_polls -= 1
 
     def _task_def_from_pick(
         self, picked, eager_pick: bool
@@ -2440,19 +2554,21 @@ class SchedulerServer:
         with self._lock:
             job.resolved_plan_bytes[stage.stage_id] = plan_bytes
             self.obs_bypass_total += 1
-            self._bypass_pending.append(job_id)
+            self._bypass_pending.append((job_id, _time.time()))
 
     def _next_bypass_task(
-        self, executor_id: str
+        self, executor_id: str, grantable: list[float]
     ) -> pb.TaskDefinition | None:
-        """Pop one queued bypass grant. The pending queue only ever holds
-        job ids; torn-down/failed jobs are skipped here rather than
-        scrubbed at teardown (the queue is short-lived and bounded by
-        submission rate)."""
+        """Pop one queued bypass grant (a first grant adds the time it was
+        queued to ``grantable``, for ``next_tasks`` to account). The
+        pending queue only ever holds job ids and that stamp;
+        torn-down/failed jobs are skipped here rather than scrubbed at
+        teardown (the queue is short-lived and bounded by submission
+        rate)."""
         job = None
         with self._lock:
             while self._bypass_pending:
-                job_id = self._bypass_pending.popleft()
+                job_id, queued_s = self._bypass_pending.popleft()
                 j = self.jobs.get(job_id)
                 if j is None or j.status != "running":
                     continue
@@ -2464,6 +2580,8 @@ class SchedulerServer:
                 break
         if job is None:
             return None
+        if attempt == 0:
+            grantable.append(queued_s)
         self._meter_first_assign(job)
         props = self._task_props(job, stage_id, attempt)
         return pb.TaskDefinition(
@@ -2531,7 +2649,7 @@ class SchedulerServer:
                 retry = error_is_retryable(error) and n < job.max_attempts
                 if retry:
                     job.total_retries += 1
-                    self._bypass_pending.append(job.job_id)
+                    self._bypass_pending.append((job.job_id, _time.time()))
             if not retry:
                 self._on_job_failed(
                     job.job_id,
@@ -2925,6 +3043,11 @@ class SchedulerServer:
                 events = []
             for e in events:
                 self.event_loop.post(e)
+        if statuses:
+            # applied on this thread, no event needed: a failed task back
+            # to PENDING, a bypass retry queued, a first map output that
+            # makes an eager pick possible
+            self.notify_grantable()
 
     def shuffle_locations_proto(
         self, job_id: str, stage_id: int, partition: int
@@ -2996,6 +3119,11 @@ class SchedulerServer:
         event loop) — abandoning daemon threads leaks them across repeated
         start/stop cycles in one process (tests assert a zero
         ``threading.enumerate()`` delta)."""
+        with self._work_cv:
+            # held polls return empty at once (their gRPC workers must not
+            # outlive the server's stop)
+            self._stopping = True
+            self._work_cv.notify_all()
         self._expiry_stop.set()
         self._expiry_thread.join(timeout=5)
         self.event_loop.stop()
@@ -3058,6 +3186,10 @@ class SchedulerGrpcServicer:
         self.s.ingest_hists(list(request.hists))
         self._apply_statuses(request.task_status)
         result = pb.PollWorkResult()
+        slots = self.s.executor_manager
+        free_since = slots.free_slot_since(
+            meta.id, request.can_accept_task and request.free_slots > 0
+        )
         if request.can_accept_task:
             # batched grants (docs/serving.md): an executor advertising
             # free_slots gets up to min(free_slots, task_grant_batch)
@@ -3070,15 +3202,29 @@ class SchedulerGrpcServicer:
                     int(request.free_slots),
                     self.s.config.task_grant_batch(),
                 )
-            from ballista_tpu.obs import trace as obs_trace
-
-            with obs_trace.phase("scheduler.grant"):
-                tasks = self.s.next_tasks(meta.id, max_n)
-                if tasks:
-                    result.tasks.extend(tasks)
-                    # mirror the first grant into the singular field so a
-                    # pre-batching executor still makes progress
-                    result.task.CopyFrom(tasks[0])
+            # an executor with every slot free has no status to bring, so
+            # its poll may be held until a task is grantable (heartbeat,
+            # metrics, spans and statuses are all saved by now); with a
+            # task running the poll returns at once, and the status it
+            # will carry never waits behind a hold. free_slots == 0 is a
+            # pre-batching executor: never held. The RPC ending under the
+            # hold (executor stopped, channel gone) wakes and ends it
+            hold = 0 < request.free_slots == em.specification.task_slots
+            if hold and context is not None:
+                context.add_callback(self.s.notify_grantable)
+            tasks = self.s.next_tasks_held(
+                meta.id, max_n, hold,
+                context.is_active if context is not None else lambda: True,
+                free_since,
+            )
+            if len(tasks) >= request.free_slots:
+                # no slot is left free: the next free one will be news
+                slots.free_slot_since(meta.id, False)
+            if tasks:
+                result.tasks.extend(tasks)
+                # mirror the first grant into the singular field so a
+                # pre-batching executor still makes progress
+                result.task.CopyFrom(tasks[0])
         return result
 
     def _apply_statuses(self, statuses) -> None:
@@ -3258,7 +3404,7 @@ def start_scheduler_grpc(
 
     gs = _grpc.server(
         __import__("concurrent.futures", fromlist=["ThreadPoolExecutor"])
-        .ThreadPoolExecutor(max_workers=16)
+        .ThreadPoolExecutor(max_workers=GRPC_WORKERS)
     )
     add_service(gs, SCHEDULER_SERVICE, SCHEDULER_METHODS, SchedulerGrpcServicer(server))
     # KEDA external scaler rides the same port (ref main.rs:136-166

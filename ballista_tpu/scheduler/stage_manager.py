@@ -12,6 +12,7 @@ from __future__ import annotations
 import dataclasses
 import enum
 import random
+import time
 
 from ballista_tpu.analysis.statemachine import TASK_TRANSITIONS
 from ballista_tpu.analysis.witness import make_lock
@@ -80,6 +81,9 @@ class Stage:
     # times this stage's completed output was invalidated and re-run
     # (lost-shuffle recovery); bounded by max_attempts
     recomputes: int = 0
+    # when the stage last entered the running set (its tasks became
+    # grantable): the start of the phase scheduler.grant_wait
+    runnable_s: float = 0.0
 
     def __post_init__(self):
         if not self.tasks:
@@ -196,7 +200,8 @@ class StageManager:
         with self._lock:
             key = (job_id, stage_id)
             self._stages[key] = Stage(
-                job_id, stage_id, n_tasks, max_attempts=max(1, max_attempts)
+                job_id, stage_id, n_tasks, max_attempts=max(1, max_attempts),
+                runnable_s=time.time(),
             )
             self._running.add(key)
             self._pending.discard(key)
@@ -262,6 +267,24 @@ class StageManager:
             if stage is None or not (0 <= partition < stage.n_tasks):
                 return 0
             return stage.tasks[partition].attempts
+
+    def first_grant_since(
+        self, job_id: str, stage_id: int, partition: int
+    ) -> float | None:
+        """When the task became grantable, if it has never failed or been
+        lost: when its stage last entered the running set, the start of
+        the phase ``scheduler.grant_wait``. None for a retried or
+        recovered task (its wait began at a failure, not with the stage)
+        and for an eager pick out of a stage that is still pending."""
+        with self._lock:
+            key = (job_id, stage_id)
+            stage = self._stages.get(key)
+            if stage is None or key not in self._running:
+                return None
+            info = stage.tasks[partition]
+            if info.blamed or info.attempts:
+                return None
+            return stage.runnable_s
 
     def assign_next_task(
         self, executor_id: str = ""
@@ -546,6 +569,7 @@ class StageManager:
             self._pending.discard(key)
             self._running.add(key)
             stage = self._stages[key]
+            stage.runnable_s = time.time()
             if not stage.is_completed:
                 return []
             self._running.discard(key)

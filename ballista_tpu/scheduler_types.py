@@ -77,6 +77,10 @@ class ExecutorData:
     executor_id: str
     total_task_slots: int
     available_task_slots: int
+    # pull mode: since when (unix seconds) every poll of this executor has
+    # left a slot free; 0 when the last one reported or left none
+    # (ExecutorManager.free_slot_since)
+    free_since_s: float = 0.0
 
 
 @dataclasses.dataclass(frozen=True)

@@ -37,7 +37,7 @@ def _delta(before: dict, after: dict) -> dict:
 
 
 def test_phases_are_a_closed_list():
-    assert len(set(obs_trace.PHASES)) == len(obs_trace.PHASES) == 15
+    assert len(set(obs_trace.PHASES)) == len(obs_trace.PHASES) == 17
     with pytest.raises(ValueError, match="not in obs.trace.PHASES"):
         obs_trace.phase("task.something_new")
 
