@@ -5,8 +5,7 @@ A hash shuffle slices every device batch ``fan_out`` ways, so the batches
 reaching the wire/disk are ``batch_bytes / fan_out`` — tiny at real fan-
 outs — and each one pays fixed costs end-to-end: IPC framing, a Flight
 chunk round-trip, a queue handoff in the overlapped reader, a device-
-upload dispatch. BENCH_SHUFFLE showed that per-batch CPU is what made
-overlapped fetch LOSE to sequential on raw loopback. Both ends of the
+upload dispatch: costs paid per batch, not per byte. Both ends of the
 shuffle coalesce with the SAME helper (``ballista.tpu.
 shuffle_target_batch_mb``): writers concatenate sub-target batches
 before write/stream (executor/shuffle.py), and result assembly

@@ -2,7 +2,8 @@
 
 Cold-start on a JAX/XLA engine is compile latency: every distinct
 ``(kernel, capacity-bucket, dtype-tuple)`` signature pays tracing + XLA
-compilation once per process (BENCH_r04: q18 42.1s cold vs 1.65s warm).
+compilation once per process (PERF.md §2: `first_setup_s` against
+`setup_s` of every cell).
 This package attacks it end to end:
 
 - :mod:`registry` — the CLOSED kernel vocabulary, its AOT signature
@@ -14,7 +15,7 @@ This package attacks it end to end:
   canonical plan signature (fresh per-task plan instances stop
   re-tracing identical programs).
 - :mod:`metrics` — trace/compile/persistent-cache counters surfaced via
-  executor heartbeats, the scheduler REST state, and bench.py.
+  executor heartbeats and the scheduler REST state.
 - :mod:`hints` — persisted plan-shape hints (learned join strategies,
   shrink/state capacities, the grown aggregate capacity) next to the XLA
   cache, so a fresh process skips the adaptive-learning half of

@@ -36,8 +36,7 @@ telemetry uses) and keeps process-global counters:
   Declared at 0 likewise.
 
 Counters surface per executor through the heartbeat -> scheduler REST
-path (docs/compile_cache.md) and per query through bench.py's tracked
-``n_signatures`` / ``compile_seconds`` fields.
+path (docs/compile_cache.md).
 """
 
 from __future__ import annotations

@@ -5,8 +5,8 @@ query needs it.
 ``BALLISTA_TPU_PREWARM`` env the server loops read at start):
 
 - ``on`` — compile every enumerated signature synchronously before
-  returning; startup blocks until warm (bench cold/warm mode, serving
-  tiers that must never show a cold first query).
+  returning; startup blocks until warm (serving tiers that must never
+  show a cold first query).
 - ``background`` — compile on a small daemon thread pool while the
   process serves; queries that arrive mid-warm pay at most the kernels
   not yet done. The pool is JOINED by ``ExecutorServer.stop`` /

@@ -426,8 +426,8 @@ def check_plan(plan) -> list[str]:
 
 
 def plan_kernels(plan) -> set[str]:
-    """The vocabulary slice a plan may dispatch (observability: bench and
-    the REST surface report it as the plan's compile surface)."""
+    """The vocabulary slice a plan may dispatch (the analysis gate counts
+    it as the plan's compile surface)."""
     out: set[str] = set()
 
     def walk(p) -> None:

@@ -37,7 +37,6 @@ BALLISTA_DEVICE = "ballista.tpu.device"  # "tpu" | "cpu" | "auto"
 BALLISTA_AGG_CAPACITY = "ballista.tpu.agg_capacity"  # max distinct groups per kernel
 BALLISTA_TPU_BATCH_ROWS = "ballista.tpu.batch_rows"  # device-batch row budget
 BALLISTA_PROFILE_DIR = "ballista.tpu.profile_dir"  # XLA profiler trace output
-BALLISTA_JOIN_EXPANSION = "ballista.tpu.join_expansion"  # probe-output expansion factor
 BALLISTA_BUILD_CACHE_MB = "ballista.tpu.build_cache_mb"  # join build-table HBM cache
 BALLISTA_COLLECTIVE_SHUFFLE = "ballista.tpu.collective_shuffle"  # on-pod all_to_all
 BALLISTA_SCAN_STREAM_MB = "ballista.tpu.scan_stream_mb"  # parquet streaming threshold
@@ -55,9 +54,6 @@ BALLISTA_SHUFFLE_FETCH_CONCURRENCY = (
 )
 BALLISTA_SHUFFLE_COMPRESSION = (
     "ballista.tpu.shuffle_compression"  # IPC codec: none|lz4|zstd
-)
-BALLISTA_SHUFFLE_LOCAL_FASTPATH = (
-    "ballista.tpu.shuffle_local_fastpath"  # direct file reads when colocated
 )
 BALLISTA_EAGER_SHUFFLE = "ballista.tpu.eager_shuffle"  # pre-barrier consumption
 BALLISTA_PUSH_SHUFFLE = "ballista.tpu.push_shuffle"  # in-memory DoExchange fast path
@@ -469,8 +465,8 @@ def _entries() -> dict[str, ConfigEntry]:
             BALLISTA_BUILD_CACHE_MB,
             "HBM budget (MB) for caching join build tables across queries "
             "on the same registered data. A warm TPC-H suite re-collects "
-            "and re-sorts each dimension/build side every run otherwise "
-            "(~170ms per 1.5M-row build on a v5e). 0 disables.",
+            "and re-sorts each dimension/build side every run otherwise. "
+            "0 disables.",
             "2048",
             int,
         ),
@@ -479,15 +475,9 @@ def _entries() -> dict[str, ConfigEntry]:
             "Rows per DeviceBatch cut from a scan (the device-side analogue "
             "of ballista.batch.size; larger batches amortize per-dispatch "
             "and per-batch aggregate costs, smaller ones bound HBM use). "
-            "2M measured best on v5e at TPC-H SF=1: every headline query "
-            "improved or held vs 1M (~65ms fixed cost per batch per op)",
+            "The default, 2M, is what every cell of BENCHMARK.json runs "
+            "(PERF.md section 4); no other value is on the ledger",
             str(1 << 21),
-            int,
-        ),
-        ConfigEntry(
-            BALLISTA_JOIN_EXPANSION,
-            "Max probe-output rows per input row for non-unique joins",
-            "4",
             int,
         ),
         ConfigEntry(
@@ -620,27 +610,14 @@ def _entries() -> dict[str, ConfigEntry]:
             "mixed codecs within one consumed partition (rolling "
             "upgrades) are fine. 'auto' (default) negotiates per "
             "(producer, consumer) link: 'none' when the pair is "
-            "colocated (same host, shared filesystem, or one ICI mesh — "
-            "BENCH_SHUFFLE measured lz4 COSTING 40%% throughput on raw "
-            "loopback) and 'lz4' when shuffle bytes genuinely cross a "
+            "colocated (same host, shared filesystem, or one ICI mesh: "
+            "there is no wire to save, only codec CPU to pay) and 'lz4' "
+            "when shuffle bytes genuinely cross a "
             "NIC; files are written uncompressed under auto since the "
             "wire codec is re-negotiated per fetch anyway. Explicit lz4/"
             "zstd force that codec everywhere; none disables it.",
             "auto",
             _parse_shuffle_compression,
-        ),
-        ConfigEntry(
-            BALLISTA_SHUFFLE_LOCAL_FASTPATH,
-            "Read a shuffle partition straight off the filesystem "
-            "(zero-copy mmap) whenever its path exists locally — the "
-            "colocated/standalone-cluster fast path. Off forces every "
-            "fetch through the serving executor's Flight endpoint: the "
-            "separate-hosts data path, and the right setting when a "
-            "shared volume (NFS) makes 'local' paths secretly remote. "
-            "bench.py's shuffle A/B turns it off to measure the wire "
-            "pipeline on one box.",
-            "true",
-            _parse_bool,
         ),
         ConfigEntry(
             BALLISTA_EAGER_SHUFFLE,
@@ -691,8 +668,8 @@ def _entries() -> dict[str, ConfigEntry]:
             "hitting the wire/disk: post-partition slices of a hash "
             "shuffle are tiny (batch bytes / fan-out), and per-batch "
             "fixed costs (IPC framing, Flight chunk round-trips, queue "
-            "handoffs, device-upload dispatch) dominated the data plane "
-            "on fast links (BENCH_SHUFFLE). Writers concatenate "
+            "handoffs, device-upload dispatch) are paid per batch, not "
+            "per byte. Writers concatenate "
             "sub-target batches before write/stream; readers concatenate "
             "sub-target batches before device upload. 0 disables "
             "coalescing (every partition slice ships as-is).",
@@ -1025,9 +1002,6 @@ class BallistaConfig:
     def profile_dir(self) -> str:
         return self._get(BALLISTA_PROFILE_DIR)
 
-    def join_expansion(self) -> int:
-        return self._get(BALLISTA_JOIN_EXPANSION)
-
     def build_cache_mb(self) -> int:
         return self._get(BALLISTA_BUILD_CACHE_MB)
 
@@ -1069,9 +1043,6 @@ class BallistaConfig:
 
     def shuffle_compression(self) -> str:
         return self._get(BALLISTA_SHUFFLE_COMPRESSION)
-
-    def shuffle_local_fastpath(self) -> bool:
-        return self._get(BALLISTA_SHUFFLE_LOCAL_FASTPATH)
 
     def eager_shuffle(self) -> bool:
         return self._get(BALLISTA_EAGER_SHUFFLE)

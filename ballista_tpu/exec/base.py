@@ -506,8 +506,8 @@ class Metrics:
 def plan_counters(plan, names) -> dict[str, int]:
     """Sum the named metric counters over a whole plan tree — the most
     recent run's values (collect resets per-operator metrics per query).
-    The out-of-core/prefetch reporting surface of bench.py and the
-    out-of-core tests, via DataFrame.collect_with_plan."""
+    The out-of-core/prefetch reporting surface of the out-of-core
+    tests, via DataFrame.collect_with_plan."""
     out = {n: 0 for n in names}
 
     def walk(p) -> None:

@@ -909,8 +909,8 @@ class DataFrame:
         read per-operator metrics of THIS run (spill bytes/passes,
         prefetch hits) after it completes — re-calling
         create_physical_plan would hand back a fresh tree with reset
-        metrics. bench.py and the out-of-core tests consume this; plain
-        collect() is the (table-only) user surface."""
+        metrics. The out-of-core tests consume this; plain collect() is
+        the (table-only) user surface."""
         if self._const is not None:
             return self._const, None
         phys = self.ctx.create_physical_plan(self.logical, sql=self._sql)

@@ -19,10 +19,8 @@ Hardening/perf on top of the reference shape (docs/shuffle.md):
 - **Zero-copy serving**: files are served batch-at-a-time off a memory
   map — uncompressed batches alias the page cache straight into the
   Flight serializer, no per-request heap copy of the partition (the
-  buffered pa.OSFile read this replaces was the dominant per-batch CPU
-  cost BENCH_SHUFFLE measured on fast links; the map is closed
-  deterministically, so RSS exposure is bounded by the in-flight
-  stream, not by request history).
+  map is closed deterministically, so RSS exposure is bounded by the
+  in-flight stream, not by request history).
 - **DoExchange push streams**: a FetchPartition action in the descriptor
   command (with ``push``/``map_partition``) serves the in-memory push
   registry when the stream is live, transparently falling back to the

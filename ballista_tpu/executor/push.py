@@ -33,12 +33,11 @@ keeps the batches, because in-task capacity/speculation retries
 re-fetch its inputs mid-attempt. Consumed streams live in a grace pool
 capped at window/4 and are DROPPED (not spilled) beyond it, oldest
 first — writing fall-back files for data whose consumer already
-finished burned the disk savings push exists for, while keeping them
-indefinitely let dead streams' residency outweigh the spills it
-replaced (both measured, BENCH_SF100); the rare post-drop re-fetch
-recovers through lineage recompute. Memory is further reclaimed by the
-TTL sweep (executor/cleanup.py) and :func:`drop_owner` at executor
-stop.
+finished would spend the disk writes push exists to skip, while keeping
+them indefinitely lets dead streams hold the window that live ones
+need; the rare post-drop re-fetch recovers through lineage recompute.
+Memory is further reclaimed by the TTL sweep (executor/cleanup.py) and
+:func:`drop_owner` at executor stop.
 
 Spill files appear ATOMICALLY (written to ``<path>.spill.tmp``, then
 os.replace): a consumer can never open a half-written fall-back file.
@@ -210,9 +209,9 @@ class PushRegistry:
         consumer already streamed them, and the only re-reader is a
         rare retry (in-task capacity growth, a consumer task failing
         after its fetch), which recovers through the normal
-        gone->lineage-recompute path; spilling them wrote gigabytes of
-        fall-back files per SF1 query that nothing ever read back
-        (BENCH_SF100), erasing the disk-skipping win push exists for.
+        gone->lineage-recompute path; spilling them writes fall-back
+        files that nothing reads back: the disk writes push exists to
+        skip.
         UNCONSUMED sealed streams (genuinely lagging consumers) spill
         to their fall-back path, least-recently-touched first. Returns
         ``([(victim, batches-or-None), ...], convert_self)`` — batches
@@ -223,11 +222,9 @@ class PushRegistry:
                 return victims, True
             # consumed streams get only a FRACTION of the window (a grace
             # pool for in-task retry re-fetches): without the sub-budget,
-            # a window sized generously for in-flight data let gigabytes
-            # of already-consumed streams linger on the heap with nothing
-            # ever reclaiming them (no pressure -> no drop), and that
-            # residency cost more than the spills it replaced
-            # (BENCH_SF100 round 3)
+            # a window sized generously for in-flight data lets
+            # already-consumed streams linger on the heap with nothing
+            # ever reclaiming them (no pressure -> no drop)
             consumed_budget = window_bytes // 4
             consumed = sorted(
                 (

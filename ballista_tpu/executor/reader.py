@@ -121,8 +121,9 @@ def resolve_link_codec(codec: str, loc: PartitionLocation) -> str:
     over ICI inside shard_map — parallel/collective.py — so by the time
     bytes hit the Flight data plane, 'same host' is exactly the
     colocation the mesh left us.) Anything crossing a real NIC gets lz4:
-    BENCH_SHUFFLE's codec_wire_ratio shows ~2x fewer wire bytes for
-    single-digit-% CPU. Explicit codecs pass through unchanged."""
+    fewer wire bytes for little codec CPU (~2x fewer for single-digit-%
+    CPU: CPU loopback, round 3-4; not measured on the chip). Explicit
+    codecs pass through unchanged."""
     if codec != "auto":
         return codec
     if os.path.exists(loc.path) or loc.host in _local_hostnames():
@@ -185,7 +186,6 @@ def fetch_partition_batches(
     backoff_ms: int | None = None,
     timeout_s: float | None = None,
     compression: str = "",
-    local_fastpath: bool = True,
     trace_ctx: tuple[str, str] | None = None,
     on_push_fallback=None,
 ) -> Iterator[pa.RecordBatch]:
@@ -214,26 +214,22 @@ def fetch_partition_batches(
     push_fallbacks counter reads."""
     compression = resolve_link_codec(compression, loc)
     if loc.push:
-        if local_fastpath:
-            # the in-process registry shortcut is the push analogue of
-            # the mmap local fast path: same colocation concept, same
-            # knob (off forces every byte through the Flight wire path —
-            # the separate-hosts shape, and what bench.py paces), and
-            # the same fetch-attempt fault plumbing — fetch_error/
-            # fetch_slow rules must fire here exactly like on the file
-            # fast path, or chaos/fault tests silently stop covering
-            # push-mode runs
-            from ballista_tpu.executor.push import REGISTRY, stream_key
+        # the in-process registry shortcut is the push analogue of the
+        # mmap local fast path: same colocation concept, and the same
+        # fetch-attempt fault plumbing — fetch_error/fetch_slow rules
+        # must fire here exactly like on the file fast path, or
+        # chaos/fault tests silently stop covering push-mode runs
+        from ballista_tpu.executor.push import REGISTRY, stream_key
 
-            _inject_local_fetch_faults(loc, retries, backoff_ms)
-            batches = REGISTRY.take_batches(
-                stream_key(loc.job_id, loc.stage_id, loc.map_partition,
-                           loc.partition)
-            )
-            if batches is not None:
-                yield from _local_push_batches(loc, batches)
-                return
-        if not (local_fastpath and os.path.exists(loc.path)):
+        _inject_local_fetch_faults(loc, retries, backoff_ms)
+        batches = REGISTRY.take_batches(
+            stream_key(loc.job_id, loc.stage_id, loc.map_partition,
+                       loc.partition)
+        )
+        if batches is not None:
+            yield from _local_push_batches(loc, batches)
+            return
+        if not os.path.exists(loc.path):
             from ballista_tpu.client.flight import fetch_push_batches
 
             yield from fetch_push_batches(
@@ -245,7 +241,7 @@ def fetch_partition_batches(
         # pull fast path below serves the very file the stream spilled to
         if on_push_fallback is not None:
             on_push_fallback()
-    if local_fastpath and os.path.exists(loc.path):
+    if os.path.exists(loc.path):
         from ballista_tpu.testing import faults
 
         _inject_local_fetch_faults(loc, retries, backoff_ms)
@@ -790,7 +786,6 @@ class ShuffleReaderExec(ExecutionPlan):
         backoff_ms = ctx.config.fetch_backoff_ms()
         timeout_s = ctx.config.fetch_timeout_s()
         compression = ctx.config.shuffle_compression()
-        local_fastpath = ctx.config.shuffle_local_fastpath()
         # tracing (docs/observability.md): execute() runs on the task
         # thread, where the ambient context is the task-attempt span (when
         # the session traces) — captured HERE and passed explicitly, since
@@ -807,7 +802,7 @@ class ShuffleReaderExec(ExecutionPlan):
         def fetch_one(loc: PartitionLocation) -> Iterator[pa.RecordBatch]:
             it = fetch_partition_batches(
                 loc, retries, backoff_ms, timeout_s, compression,
-                local_fastpath, trace_ctx=trace_parent,
+                trace_ctx=trace_parent,
                 on_push_fallback=on_push_fallback,
             )
             if trace_parent is None:

@@ -43,7 +43,7 @@ from ballista_tpu.datatypes import DataType, Field, Schema
 log = logging.getLogger(__name__)
 
 # the closed cost-vector key set — every surface (proto, JSON records,
-# Prometheus rollup, system-table columns, bench fields) uses exactly
+# Prometheus rollup, system-table columns) uses exactly
 # these names, so a new resource dimension is a one-list change
 COST_KEYS = (
     "wall_seconds",

@@ -262,8 +262,7 @@ def scheduler_families(server) -> list[tuple]:
          [({}, server.history.job_count())])
     )
     # serving fast path (docs/serving.md): result-cache effectiveness and
-    # the orchestration-bypass count — the two fleet signals the
-    # BENCH_SERVE artifact reports straight from this scrape
+    # the orchestration-bypass count
     cache = server.result_cache.stats()
     families.append(
         ("ballista_result_cache_events_total", "counter",

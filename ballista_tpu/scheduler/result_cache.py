@@ -100,7 +100,7 @@ class ResultCache:
     def get(self, key: tuple | None) -> tuple[bytes, dict] | None:
         """``(payload, meta)`` for ``key``, counting the hit/miss.
         ``None`` keys (uncacheable submissions) count as misses so the
-        hit ratio the bench reports stays honest about them."""
+        hit ratio /api/metrics reports stays honest about them."""
         if not self.enabled:
             return None
         with self._lock:
@@ -144,7 +144,7 @@ class ResultCache:
             self._bytes = 0
 
     def stats(self) -> dict:
-        """Snapshot for /api/metrics and the BENCH_SERVE artifact."""
+        """Snapshot for /api/metrics."""
         with self._lock:
             return {
                 "hits": self.hits,

@@ -7,9 +7,13 @@ generated from the registries and pinned here; the runtime
 """
 
 import logging
+import re
+
+import pytest
 
 from ballista_tpu import config as cfg
 from ballista_tpu.analysis import configlint
+from ballista_tpu.errors import ConfigError
 
 
 def _rules(src: str):
@@ -23,8 +27,6 @@ def test_tree_is_closed_over_the_registries():
     diags, summary = configlint.lint_tree()
     assert diags == [], "\n".join(str(d) for d in diags)
     # the scan saw real traffic (not vacuously green)
-    import re
-
     m = re.match(r"(\d+) config-key literals \+ (\d+) env read", summary)
     assert m and int(m.group(1)) > 0 and int(m.group(2)) > 0, summary
 
@@ -48,6 +50,30 @@ def test_generated_docs_cover_both_registries():
         assert f"`{name}`" in text, name
     for e in cfg.ENV_REGISTRY:
         assert f"`{e.name}`" in text, e.name
+
+
+def test_docs_config_md_lists_exactly_the_registry_keys():
+    listed = re.findall(
+        r"^\| `(ballista\.[a-z0-9_.]+)` \|",
+        configlint.docs_path().read_text(),
+        flags=re.M,
+    )
+    assert sorted(listed) == sorted(cfg._entries())
+    assert len(listed) == len(set(listed))
+
+
+@pytest.mark.parametrize(
+    "key",
+    [
+        "ballista.tpu.shuffle_local_fastpath",  # removed, PR 31
+        "ballista.tpu.join_expansion",  # removed, PR 31
+        "ballista.tpu.shuffle_compresion",  # a misspelt key
+    ],
+)
+def test_removed_and_misspelt_keys_are_unknown(key):
+    with pytest.raises(ConfigError, match="unknown configuration key"):
+        cfg.BallistaConfig().with_setting(key, "true")
+    assert _rules(f'k = "{key}"\n') == ["unknown-config-key"]
 
 
 # ----------------------------------------------------------- mutations --

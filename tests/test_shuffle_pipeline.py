@@ -140,6 +140,57 @@ def test_mixed_codecs_in_one_partition(tmp_path):
         assert t.column("k").to_pylist() == list(range(i * 10, i * 10 + 10))
 
 
+@pytest.mark.parametrize("push", [False, True], ids=["pull", "push"])
+@pytest.mark.parametrize("on_disk", [True, False], ids=["file", "gone"])
+def test_colocated_file_is_read_locally_else_flight(
+    tmp_path, monkeypatch, push, on_disk
+):
+    """The colocated path has no switch: a location whose file exists on
+    this filesystem is read from the file (no Flight call at all), one
+    whose file is gone goes to the serving executor over Flight. A push
+    location with no live stream in this process does the same through
+    its spilled file or DoExchange, and meters the fall-back."""
+    import ballista_tpu.client.flight as flight
+    from ballista_tpu.executor.reader import fetch_partition_batches
+
+    p = str(tmp_path / "data-0.arrow")
+    if on_disk:
+        _write_file(p, 0, 6, n_batches=2)
+    loc = dataclasses.replace(_loc(p, port=1), push=push)
+    wire = pa.record_batch(
+        [pa.array([-1], pa.int64()), pa.array([-1.0])], schema=ARROW2
+    )
+    calls = []
+
+    def over_flight(name):
+        def fetch(loc_, *args, **kwargs):
+            calls.append((name, loc_.path))
+            yield wire
+        return fetch
+
+    monkeypatch.setattr(
+        flight, "fetch_partition_batches", over_flight("do_get")
+    )
+    monkeypatch.setattr(
+        flight, "fetch_push_batches", over_flight("do_exchange")
+    )
+    fallbacks = []
+    got = list(
+        fetch_partition_batches(
+            loc, retries=1, on_push_fallback=lambda: fallbacks.append(1)
+        )
+    )
+    if on_disk:
+        assert calls == []
+        assert [rb.column(0).to_pylist() for rb in got] == [
+            list(range(6)), list(range(6, 12))
+        ]
+        assert fallbacks == ([1] if push else [])
+    else:
+        assert calls == [("do_exchange" if push else "do_get", p)]
+        assert got == [wire] and fallbacks == []
+
+
 def test_zero_row_upstream_output(tmp_path):
     """A zero-row upstream file and an empty location list both read as
     an empty (but well-formed) stream."""
