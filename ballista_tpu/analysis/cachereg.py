@@ -152,7 +152,7 @@ CACHES: tuple[CacheEntry, ...] = (
             "evict_plan_cache bounds it oldest-first at commit",
         ),
         seam=("__init__", "_job_snapshot"),
-        ok_calls=("save_if_changed", "load_once", "evict_plan_cache"),
+        ok_calls=("mark", "load_once", "evict_plan_cache"),
     ),
     CacheEntry(
         name="executor-job-snapshots",
@@ -194,14 +194,16 @@ CACHES: tuple[CacheEntry, ...] = (
             "ballista_tpu/executor/executor.py::Executor._hints",
             "ballista_tpu/scheduler/aqe.py::StrategyStore._persist",
         ),
-        keyed_by="plan-shape fact key, persisted across processes "
-        "(compilecache/hints.py)",
+        keyed_by="plan-shape fact key without a job id, persisted across "
+        "processes (compilecache/hints.py)",
         scope="process",
         coherence="speculative-validated",
         invalidation=(
             "stale persisted guesses are invalidated at use by the "
-            "defer_speculation seam, then overwritten by save_if_changed",
+            "defer_speculation seam, then overwritten by the store's "
+            "writer (mark -> save_if_changed; flush at close)",
             "4096-entry bound at save",
+            "entries keyed by a job id are never written: process-local",
         ),
     ),
     CacheEntry(

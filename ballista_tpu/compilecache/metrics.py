@@ -34,6 +34,13 @@ telemetry uses) and keeps process-global counters:
   between polls that a finished task ended, idle executors' polls the
   scheduler held, and those that ended in a grant or at the bound.
   Declared at 0 likewise.
+- ``hints.marks`` / ``hints.writes_skipped_unchanged`` /
+  ``hints.entries_job_scoped_skipped`` / ``hints_saved`` / ``hints_loaded``
+  — the plan-hint store (compilecache/hints.py): tasks and collects that
+  marked it, writer passes that found the fingerprint where it was,
+  entries a pass left out because their key carries a job id (summed over
+  passes), files written, entries merged at load. The first three and the
+  writer's phase seconds are declared at 0 likewise.
 
 Counters surface per executor through the heartbeat -> scheduler REST
 path (docs/compile_cache.md).
@@ -54,7 +61,15 @@ POLL_COUNTERS = (
     "poll.rpcs", "poll.wakes_by_status", "poll.holds", "poll.holds_granted",
     "poll.holds_timed_out",
 )
-_COUNTERS: dict[str, float] = dict.fromkeys(AGG_COUNTERS + POLL_COUNTERS, 0)
+# the plan-hint store's hand-off to its writer (compilecache/hints.py), with
+# the writer's phase: 0 and not absent in a process whose writer never woke
+HINT_COUNTERS = (
+    "hints.marks", "hints.writes_skipped_unchanged",
+    "hints.entries_job_scoped_skipped", "phase.executor.hints_write.seconds",
+)
+_COUNTERS: dict[str, float] = dict.fromkeys(
+    AGG_COUNTERS + POLL_COUNTERS + HINT_COUNTERS, 0
+)
 _INSTALLED = False
 
 # jax monitoring event -> (counter incremented per event, duration-sum

@@ -681,7 +681,7 @@ class TpuContext(Catalog, TableProvider):
                     os.environ["BALLISTA_TPU_NO_FUSE"] = prev_no_fuse
         elapsed = _time.perf_counter() - t0
         with obs_trace.phase("task.hints_save"):
-            self._hints.save_if_changed(self._capacity_hint, self._plan_cache)
+            self._hints.mark(self._capacity_hint, self._plan_cache)
         from ballista_tpu.scheduler.aqe import narrate as aqe_narrate
 
         rows = [
@@ -967,7 +967,7 @@ class DataFrame:
                 plan_cache=self.ctx._plan_cache
             )
         with obs_trace.phase("task.hints_save"):
-            self.ctx._hints.save_if_changed(
+            self.ctx._hints.mark(
                 self.ctx._capacity_hint, self.ctx._plan_cache
             )
         if not record_batches:

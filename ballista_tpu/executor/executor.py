@@ -199,6 +199,13 @@ class Executor:
             except Exception:  # noqa: BLE001
                 pass
 
+    def close(self) -> None:
+        """What either task loop's stop() ends with: the eager-poll channel
+        closed, and the hint store flushed and its writer joined, so a
+        clean stop persists everything learned (docs/compile_cache.md)."""
+        self.close_locations_client()
+        self._hints.close()
+
     def _job_snapshot(self, job_id: str) -> dict:
         """The frozen strategy view every task of one job seeds from —
         the q15 warm-pass drift fix (docs/serving.md).
@@ -405,8 +412,10 @@ class Executor:
         from ballista_tpu.exec.base import evict_plan_cache
 
         evict_plan_cache(self._plan_cache)
+        # persisting is the store's writer's work (compilecache/hints.py):
+        # the task only says that there may be something to persist
         with obs_trace.phase("task.hints_save"):
-            self._hints.save_if_changed(self._capacity_hint, self._plan_cache)
+            self._hints.mark(self._capacity_hint, self._plan_cache)
         from ballista_tpu.analysis import replay
 
         if replay.enabled():
@@ -659,7 +668,7 @@ class PollLoop:
             self._prewarm = None
         if self._thread is not None:
             self._thread.join(timeout=5)
-        self.executor.close_locations_client()
+        self.executor.close()
         # push-shuffle streams die with their producer by design
         # (docs/shuffle.md): drop this executor's registry entries so
         # consumers fall back / recompute and the memory (and resource-

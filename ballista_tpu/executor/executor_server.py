@@ -326,8 +326,9 @@ class ExecutorServer:
         # AFTER the runner join: a runner mid-eager-task must not see the
         # poll channel closed and re-dial one nobody would ever close
         # (close_locations_client also latches against exactly that race
-        # for stragglers that outlived the join timeout)
-        self.executor.close_locations_client()
+        # for stragglers that outlived the join timeout); the hint store's
+        # flush likewise comes after the last task's mark
+        self.executor.close()
         # push-shuffle streams die with their producer (docs/shuffle.md)
         from ballista_tpu.executor.push import REGISTRY
 

@@ -341,6 +341,7 @@ PHASES = (
     "scheduler.grant_wait",
     "executor.poll_sleep",
     "executor.status_wait",
+    "executor.hints_write",
     "task.decode",
     "task.scan_host",
     "task.h2d",

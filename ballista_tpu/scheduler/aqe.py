@@ -260,7 +260,9 @@ class StrategyStore:
         # CHANGED (learn/unlearn/deny call it on change only, and
         # save_if_changed fingerprint-debounces besides) — a class
         # learns a handful of times and then settles, so steady state
-        # does zero IO here.
+        # does zero IO here. Synchronous, where the executor only marks
+        # its store: this store is a process-wide singleton that no
+        # stop() owns, so nothing would flush or join a writer of its own.
         with self._lock:
             hint, cache = self._hint, self._cache
         self._persist.save_if_changed(hint, cache)

@@ -473,6 +473,8 @@ def test_hint_persistence_seeds_a_fresh_context(tmp_path, monkeypatch):
     # the settled (run-2+) result is the reference: learned decimal-sum
     # scaling makes money sums exact, and a hinted cold run starts there
     settled = ctx1.sql(sql).collect()
+    # a collect only marks the store; its writer, or a flush, persists
+    ctx1._hints.flush()
     assert (tmp_path / "plan_hints.json").exists()
     learned = dict(ctx1._plan_cache)
     assert learned, "expected the query to learn plan-shape facts"
@@ -488,6 +490,289 @@ def test_hint_persistence_seeds_a_fresh_context(tmp_path, monkeypatch):
         if k != "__build_cache_bytes__":
             assert k in ctx2._plan_cache, k
     assert settled.to_pydict() == again.to_pydict()
+
+
+def _writer_threads():
+    import threading
+
+    from ballista_tpu.compilecache.hints import WRITER_THREAD_NAME
+
+    return [
+        t for t in threading.enumerate()
+        if t.name == WRITER_THREAD_NAME and t.is_alive()
+    ]
+
+
+def _wait_for(cond, timeout=10.0):
+    import time
+
+    deadline = time.time() + timeout
+    while time.time() < deadline:
+        if cond():
+            return True
+        time.sleep(0.01)
+    return cond()
+
+
+@pytest.fixture
+def writers():
+    """The live writer threads of this test's own stores. An earlier
+    test's context may still have one: what it has pending is written now,
+    not into this test's directory later, and its thread is not counted."""
+    from ballista_tpu.compilecache import hints
+
+    hints._flush_marked()
+    before = set(_writer_threads())
+    return lambda: [t for t in _writer_threads() if t not in before]
+
+
+def test_hint_mark_defers_and_coalesces(tmp_path, monkeypatch, writers):
+    """A mark touches no file; the store's writer persists once for the
+    many marks of one debounce interval, and ends when no mark has come
+    for a while."""
+    from ballista_tpu.compilecache import hints, metrics
+
+    monkeypatch.setenv("BALLISTA_TPU_HINT_CACHE", str(tmp_path))
+    monkeypatch.setattr(hints, "WRITE_DEBOUNCE_S", 0.4)
+    monkeypatch.setattr(hints, "_WRITER_IDLE_S", 0.2)
+    path = tmp_path / "plan_hints.json"
+    hint, cache = {}, {}
+    s = hints.HintStore()
+    s.load_once(hint, cache)
+    with metrics.delta() as d:
+        for i in range(50):
+            cache[("shrink", f"site {i}", 0, 1024)] = 64
+            s.mark(hint, cache)
+        assert not path.exists()  # inside the debounce: nothing written
+        assert len(writers()) == 1
+        assert _wait_for(lambda: not writers())
+    assert path.exists()
+    assert d.value["hints.marks"] == 50
+    assert d.value["hints_saved"] == 1
+    # (an earlier test's idling writer may add a pass of its own)
+    assert d.value["phase.executor.hints_write.count"] >= 1
+    c2 = {}
+    hints.HintStore().load_once({}, c2)
+    assert len(c2) == 50
+    # a mark over an unchanged state wakes the writer, which writes nothing
+    mtime = path.stat().st_mtime_ns
+    with metrics.delta() as d:
+        s.mark(hint, cache)
+        assert _wait_for(lambda: not writers())
+    assert d.value.get("hints_saved", 0) == 0
+    assert d.value["hints.writes_skipped_unchanged"] == 1
+    assert path.stat().st_mtime_ns == mtime
+
+
+def test_hint_flush_is_synchronous_and_close_joins(
+    tmp_path, monkeypatch, writers
+):
+    from ballista_tpu.compilecache import hints, metrics
+
+    monkeypatch.setenv("BALLISTA_TPU_HINT_CACHE", str(tmp_path))
+    monkeypatch.setattr(hints, "WRITE_DEBOUNCE_S", 60.0)
+    path = tmp_path / "plan_hints.json"
+    hint, cache = {"agg_capacity": 4096}, {("shrink", "a", 0, 8): 4}
+    s = hints.HintStore()
+    s.load_once(hint, cache)
+    s.mark(hint, cache)
+    assert not path.exists()
+    assert s.flush()  # written when flush returns, a minute early
+    assert path.exists()
+    assert not s.flush()  # nothing pending
+    assert len(writers()) == 1  # still inside its debounce
+    cache[("shrink", "b", 0, 8)] = 2
+    s.mark(hint, cache)
+    s.close()  # ends the debounce, flushes, joins
+    assert not writers()
+    c2 = {}
+    hints.HintStore().load_once({}, c2)
+    assert c2 == cache
+    # after close a mark counts, starts no writer and writes nothing
+    cache[("shrink", "c", 0, 8)] = 1
+    with metrics.delta() as d:
+        s.mark(hint, cache)
+    assert d.value["hints.marks"] == 1
+    assert not writers()
+
+
+_JOB_KEY = {
+    "join_flags": lambda job: ("join_flags", job, "plan display", (2,), None),
+    "dec_sum": lambda job: ("dec_sum", job, "site", 1),
+    "dec_sum_last": lambda job: ("dec_sum_last", job, "site", 1),
+    "agg_sorted": lambda job: ("agg_sorted", job, "site", False, 0),
+    "agg_state_cap": lambda job: ("agg_state_cap", job, "site", 0),
+    "agg_state_prefix": lambda job: ("agg_state_prefix", job, "site", 0),
+    "join_lut": lambda job: ("join_lut", _JOB_KEY["join_flags"](job)),
+    "expand_cap": lambda job: (
+        "expand_cap", _JOB_KEY["join_flags"](job), "INNER", 0
+    ),
+}
+
+
+def test_hint_job_scoped_families_are_all_named():
+    from ballista_tpu.compilecache.hints import _JOB_SCOPED_FAMILIES
+
+    assert _JOB_SCOPED_FAMILIES | {"join_lut", "expand_cap"} == set(_JOB_KEY)
+
+
+@pytest.mark.parametrize("family", sorted(_JOB_KEY))
+def test_hint_entries_keyed_by_a_job_id_stay_in_memory(
+    family, tmp_path, monkeypatch, writers
+):
+    """What no later process can read is not written: an entry whose key
+    carries a served job's id, directly or through a nested strategy
+    key, neither enters the file nor moves the fingerprint; the same
+    family under the empty job id (the local context) and the unscoped
+    families round-trip as before."""
+    from ballista_tpu.compilecache import hints, metrics
+
+    monkeypatch.setenv("BALLISTA_TPU_HINT_CACHE", str(tmp_path))
+    path = tmp_path / "plan_hints.json"
+    key = _JOB_KEY[family]
+    hint = {"agg_capacity": 1 << 20}
+    cache = {
+        key(""): 7,
+        ("shrink", "HashJoinExec: ...", 0, 1 << 21): 4096,
+        ("aqe", "class-a"): (("flip", 2, 0),),
+    }
+    s = hints.HintStore()
+    s.load_once(hint, cache)
+    assert s.save_if_changed(hint, cache)
+    written = path.read_text(encoding="utf-8")
+    with metrics.delta() as d:
+        cache[key("job-one")] = 9
+        cache[key("job-two")] = 11
+        assert not s.save_if_changed(hint, cache)  # fingerprint held
+    assert d.value["hints.writes_skipped_unchanged"] == 1
+    assert d.value["hints.entries_job_scoped_skipped"] == 2
+    assert path.read_text(encoding="utf-8") == written
+    # and when something persistable does change, they still stay out
+    cache[("shrink", "other site", 0, 64)] = 8
+    assert s.save_if_changed(hint, cache)
+    assert "job-one" not in path.read_text(encoding="utf-8")
+    h2, c2 = {}, {}
+    hints.HintStore().load_once(h2, c2)
+    assert h2 == hint
+    assert c2 == {
+        k: v for k, v in cache.items()
+        if "job-one" not in repr(k) and "job-two" not in repr(k)
+    }
+    assert len(c2) == 4 and c2[key("")] == 7
+
+
+def test_restarted_executor_loads_what_the_stopped_one_flushed(
+    tmp_path, monkeypatch, writers
+):
+    """A clean stop persists everything learned: close() flushes a mark
+    the writer has not reached yet, and the next executor on the same
+    hint directory starts from it."""
+    from ballista_tpu.compilecache import hints
+    from ballista_tpu.executor.executor import Executor
+
+    monkeypatch.setenv("BALLISTA_TPU_HINT_CACHE", str(tmp_path / "hints"))
+    monkeypatch.setattr(hints, "WRITE_DEBOUNCE_S", 60.0)
+    ex = Executor("e-1", str(tmp_path / "work"))
+    learned = {
+        ("shrink", "FilterExec: ...", 0, 1 << 20): 2048,
+        ("dec_sum", "", "site", 0): 3,
+    }
+    ex._plan_cache.update(learned)
+    ex._plan_cache[("dec_sum", "job-1", "site", 0)] = 3
+    ex._capacity_hint["agg_capacity"] = 1 << 18
+    ex._hints.mark(ex._capacity_hint, ex._plan_cache)
+    assert not (tmp_path / "hints" / "plan_hints.json").exists()
+    ex.close()
+    assert not writers()
+    ex2 = Executor("e-2", str(tmp_path / "work"))
+    try:
+        assert ex2._plan_cache == learned
+        assert ex2._capacity_hint == {"agg_capacity": 1 << 18}
+    finally:
+        ex2.close()
+
+
+def test_hint_write_failure_disables_the_writer_once(
+    tmp_path, monkeypatch, caplog, writers
+):
+    import logging
+
+    from ballista_tpu.compilecache import hints, metrics
+
+    blocker = tmp_path / "a_file"
+    blocker.write_text("not a directory", encoding="utf-8")
+    monkeypatch.setenv("BALLISTA_TPU_HINT_CACHE", str(blocker / "hints"))
+    monkeypatch.setattr(hints, "WRITE_DEBOUNCE_S", 0.05)
+    monkeypatch.setattr(hints, "_WRITER_IDLE_S", 0.05)
+    hint, cache = {}, {("shrink", "a", 0, 8): 4}
+    s = hints.HintStore()
+    s.load_once(hint, cache)
+    with caplog.at_level(logging.WARNING, logger=hints.log.name):
+        with metrics.delta() as d:
+            s.mark(hint, cache)
+            assert _wait_for(lambda: not writers())
+            assert s._write_failed
+            for i in range(5):  # disabled: no writer, no second warning
+                cache[("shrink", "b", i, 8)] = 4
+                s.mark(hint, cache)
+                assert not writers()
+            assert not s.flush()
+            s.close()
+    warnings = [r for r in caplog.records if "not writable" in r.getMessage()]
+    assert len(warnings) == 1
+    assert d.value["hints.marks"] == 6
+    assert d.value.get("hints_saved", 0) == 0
+
+
+def test_concurrent_marks_during_plan_cache_resizes(
+    tmp_path, monkeypatch, writers
+):
+    """Four task threads commit into the owner's plan cache (it resizes as
+    it grows) and mark while the writer snapshots it: nothing raises on
+    either side (hints._snapshot_items), and after close() the file
+    holds the last state."""
+    import sys
+    import threading
+
+    from ballista_tpu.compilecache import hints
+
+    monkeypatch.setenv("BALLISTA_TPU_HINT_CACHE", str(tmp_path))
+    monkeypatch.setattr(hints, "WRITE_DEBOUNCE_S", 0.005)
+    raised = []
+    monkeypatch.setattr(threading, "excepthook", raised.append)
+    hint, cache = {}, {}
+    s = hints.HintStore()
+    s.load_once(hint, cache)
+    n_threads, n_keys = 4, 1000
+
+    def task_thread(t):
+        for i in range(n_keys):
+            cache[("shrink", f"site {t}", i, 1024)] = i
+            cache[("dec_sum", f"job-{t}", "site", i)] = i
+            s.mark(hint, cache)
+
+    threads = [
+        threading.Thread(target=task_thread, args=(t,))
+        for t in range(n_threads)
+    ]
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    try:
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=120)
+    finally:
+        sys.setswitchinterval(interval)
+    assert not any(t.is_alive() for t in threads)
+    s.close()
+    assert not writers()
+    assert not raised, raised
+    c2 = {}
+    hints.HintStore().load_once({}, c2)
+    # at most _MAX_ENTRIES of them, newest first: here all fit
+    assert c2 == {k: v for k, v in cache.items() if k[0] == "shrink"}
+    assert len(c2) == n_threads * n_keys
 
 
 # ------------------------------------------------------ metrics ------------

@@ -37,7 +37,7 @@ def _delta(before: dict, after: dict) -> dict:
 
 
 def test_phases_are_a_closed_list():
-    assert len(set(obs_trace.PHASES)) == len(obs_trace.PHASES) == 17
+    assert len(set(obs_trace.PHASES)) == len(obs_trace.PHASES) == 18
     with pytest.raises(ValueError, match="not in obs.trace.PHASES"):
         obs_trace.phase("task.something_new")
 
@@ -264,10 +264,13 @@ print("RESULT " + json.dumps({
 
 
 @pytest.fixture(scope="module")
-def served():
+def served(tmp_path_factory):
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     env = {k: v for k, v in os.environ.items() if k != "XLA_FLAGS"}
-    env.update(PYTHONPATH=root, JAX_PLATFORMS="cpu")
+    # hint persistence on (the tests' default is off), or no mark would
+    # start the store's writer and its phase would never be reached
+    env.update(PYTHONPATH=root, JAX_PLATFORMS="cpu",
+               BALLISTA_TPU_HINT_CACHE=str(tmp_path_factory.mktemp("hints")))
     proc = subprocess.run(
         [sys.executable, "-c", SERVED], env=env, cwd=root,
         capture_output=True, text=True, timeout=900,

@@ -379,3 +379,69 @@ def test_q15_every_warm_pass_returns_its_row():
         replay.enable(False)
         replay.reset()
         ctx.close()
+
+
+# ---------------------------------------------------------------------------
+# plan hints leave the task (docs/compile_cache.md)
+# ---------------------------------------------------------------------------
+
+
+def test_plan_hints_are_marked_by_tasks_and_written_by_the_store(
+    tmp_path, monkeypatch
+):
+    """A finished task only marks the executor's hint store. What the
+    store's writer persists holds no entry keyed by a job id, so a second
+    run of the same query, which learns nothing a later process could
+    read, writes nothing; and stop() leaves no writer thread."""
+    import threading
+
+    import numpy as np
+
+    from ballista_tpu.compilecache import hints, metrics
+
+    monkeypatch.setenv("BALLISTA_TPU_HINT_CACHE", str(tmp_path))
+    monkeypatch.setattr(hints, "WRITE_DEBOUNCE_S", 0.05)
+    rng = np.random.default_rng(32)
+    n = 6000
+    data = {
+        "t": pa.table({"k": rng.integers(0, 40, n),
+                       "v": rng.uniform(0, 100, n).round(2)}),
+        "d": pa.table({"id": np.arange(40, dtype=np.int64),
+                       "grp": np.arange(40, dtype=np.int64) % 5}),
+    }
+    sql = (
+        "SELECT grp, SUM(v) AS sv FROM t JOIN d ON k = id "
+        "GROUP BY grp ORDER BY grp"
+    )
+    ctx = _standalone(data, **{"ballista.shuffle.partitions": "2"})
+    cluster = ctx._standalone_cluster
+    executor = cluster.executors[0].executor
+    path = tmp_path / "plan_hints.json"
+    try:
+        with metrics.delta() as first:
+            ctx.sql(sql).collect()
+            executor._hints.flush()
+        assert first.value["hints_saved"] >= 1
+        with metrics.delta() as second:
+            again = ctx.sql(sql).collect()
+            executor._hints.flush()
+        assert again.num_rows == 5
+        jobs = sorted(cluster.scheduler.jobs)
+        assert len(jobs) == 2
+        # in memory the jobs' entries are there for their later tasks ...
+        assert any(jobs[0] in repr(k) for k in executor._plan_cache)
+        assert any(jobs[1] in repr(k) for k in executor._plan_cache)
+        # ... and the file has neither's
+        written = path.read_text(encoding="utf-8")
+        assert jobs[0] not in written and jobs[1] not in written
+        assert second.value.get("hints_saved", 0) == 0
+        assert second.value["hints.writes_skipped_unchanged"] >= 1
+        # the phase is still every task's, and now the mark
+        tasks = second.value["phase.task.hints_save.count"]
+        assert tasks >= 3 and second.value["hints.marks"] == tasks
+    finally:
+        ctx.close()
+    assert not [
+        t for t in threading.enumerate()
+        if t.name == hints.WRITER_THREAD_NAME and t.is_alive()
+    ]

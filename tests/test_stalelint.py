@@ -246,7 +246,7 @@ def test_rule3_rejects_plain_live_read_allows_commit_write():
         "    def run_task(self, cache):\n"
         "        flag = self._plan_cache.get(('join', 'q3'))\n"  # escape
         "        self._plan_cache.update(cache)\n"  # commit: legal
-        "        self._hints.save_if_changed({}, self._plan_cache)\n"
+        "        self._hints.mark({}, self._plan_cache)\n"
     )
     diags = [
         d for d in stalelint.lint_source(
