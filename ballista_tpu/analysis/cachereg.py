@@ -319,6 +319,9 @@ CACHES: tuple[CacheEntry, ...] = (
             "ballista_tpu/ops/perm.py::_argsort_program",
             "ballista_tpu/ops/perm.py::_take_program",
             "ballista_tpu/ops/perm.py::_take_batch_program",
+            "ballista_tpu/ops/perm.py::_f64_keys_program",
+            "ballista_tpu/ops/perm.py::_holistic_pass_program",
+            "ballista_tpu/ops/perm.py::_holistic_take_program",
         ),
         keyed_by="full program signature (shapes, dtypes, capacities, "
         "static flags) — pure function of the key",

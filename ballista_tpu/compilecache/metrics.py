@@ -28,6 +28,10 @@ telemetry uses) and keeps process-global counters:
   tasks run again after a ``CapacityError``, device passes dispatched by
   kind, rows the final aggregates emitted. Declared at 0, so that a
   reader tells "none" from "a program without the counter".
+- ``holistic.tasks`` / ``holistic.rows_sorted`` / ``holistic.sort_passes``
+  — the window and percentile operators (docs/observability.md): tasks
+  that ran one, the live rows of every sort they dispatched, the argsort
+  passes of those sorts. Declared at 0 likewise.
 - ``poll.rpcs`` / ``poll.wakes_by_status`` (executor) and ``poll.holds`` /
   ``poll.holds_granted`` / ``poll.holds_timed_out`` (scheduler) — the pull
   loop's hand-off (docs/observability.md): ``PollWork`` calls sent, waits
@@ -55,6 +59,11 @@ AGG_COUNTERS = (
     "agg.capacity_retries", "agg.sort_passes", "agg.dense_passes",
     "agg.groups_out",
 )
+# the operators that need every row of a group in one place (exec/window.py,
+# exec/percentile.py), summed from their metrics as a task ends
+HOLISTIC_COUNTERS = (
+    "holistic.tasks", "holistic.rows_sorted", "holistic.sort_passes",
+)
 # the pull loop's hand-off (docs/serving.md): polls sent and waits ended by
 # a finished task (executor); polls held, and how a hold ended (scheduler)
 POLL_COUNTERS = (
@@ -68,7 +77,7 @@ HINT_COUNTERS = (
     "hints.entries_job_scoped_skipped", "phase.executor.hints_write.seconds",
 )
 _COUNTERS: dict[str, float] = dict.fromkeys(
-    AGG_COUNTERS + POLL_COUNTERS + HINT_COUNTERS, 0
+    AGG_COUNTERS + HOLISTIC_COUNTERS + POLL_COUNTERS + HINT_COUNTERS, 0
 )
 _INSTALLED = False
 

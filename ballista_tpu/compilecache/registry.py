@@ -60,6 +60,17 @@ VOCABULARY: dict[str, KernelSpec] = {
     "ops.perm.perm_take_batch": KernelSpec(
         None, "stacked gather of a column set (dtypes + null layout)"
     ),
+    "ops.perm.sort_f64_keys": KernelSpec(
+        None, "a float64 sort key as two int32 keys (TPU), per capacity"
+    ),
+    "ops.perm.holistic_sort_pass": KernelSpec(
+        None, "a window/percentile sort pass (gather, argsort, gather) "
+        "per (dtype, capacity): reached at the capacity of a whole input"
+    ),
+    "ops.perm.holistic_take": KernelSpec(
+        None, "a window/percentile operator's stacked gather of its keys "
+        "(dtypes + null layout)"
+    ),
     "ops.compact.compact_invalid": KernelSpec(
         "execute", "invalid flags for the compaction sort, per capacity"
     ),
@@ -189,8 +200,10 @@ VOCABULARY: dict[str, KernelSpec] = {
 # surface) or a mapping naming an unknown kernel (mappings cannot rot).
 _PERM = (
     "ops.perm.sort_argsort", "ops.perm.perm_take", "ops.perm.perm_take_batch",
+    "ops.perm.sort_f64_keys",
     "ops.compact.compact_invalid", "ops.compact.compact_front_valid",
 )
+_HOLISTIC = ("ops.perm.holistic_sort_pass", "ops.perm.holistic_take")
 _FETCH = (
     "ops.fetch.fetch_flat", "ops.fetch.fetch_concat",
     "ops.fetch.fetch_concat_f64",
@@ -245,8 +258,10 @@ OPERATOR_KERNELS: dict[str, tuple[str, ...]] = {
     "CrossJoinExec": _JOIN,
     "WindowExec": (
         "exec.window.window_rank", "exec.window.window_frame",
-    ) + _PERM,
-    "PercentileExec": ("exec.percentile.percentile_interp",) + _PERM,
+    ) + _HOLISTIC + _PERM,
+    "PercentileExec": (
+        ("exec.percentile.percentile_interp",) + _HOLISTIC + _PERM
+    ),
     # exchange boundary
     "HashRepartitionExec": _REPARTITION + _PERM,
     "ShuffleWriterExec": _REPARTITION + _PERM + _FETCH + _CONCAT,
@@ -259,7 +274,7 @@ OPERATOR_KERNELS: dict[str, tuple[str, ...]] = {
     "MeshSortExec": ("exec.sort.limit_mask",) + _PERM,
     "MeshWindowExec": (
         "exec.window.window_rank", "exec.window.window_frame",
-    ) + _PERM,
+    ) + _HOLISTIC + _PERM,
 }
 
 
@@ -296,8 +311,7 @@ def _warm_argsort(dtype: str, cap: int, descending: bool) -> None:
 
     from ballista_tpu.ops.perm import _argsort_program
 
-    is_float = dtype.startswith("float")
-    fn = _argsort_program(dtype, cap, descending, is_float)
+    fn = _argsort_program(dtype, cap, descending)
     fn.lower(jax.ShapeDtypeStruct((cap,), jnp.dtype(dtype))).compile()
 
 

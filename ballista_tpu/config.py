@@ -429,7 +429,8 @@ def _entries() -> dict[str, ConfigEntry]:
         ),
         ConfigEntry(
             BALLISTA_REPARTITION_WINDOWS,
-            "Repartition inputs of window functions",
+            "Repartition inputs of window functions and percentiles by "
+            "their keys",
             "true",
             _parse_bool,
         ),

@@ -6,9 +6,11 @@ the EXACT answer cheaper: sort all rows by (group keys, value), find the
 per-group segment [ps, pe] over non-null live values, and gather the two
 bracketing order statistics at ``t = q * (cnt - 1)`` for linear
 interpolation — no data-dependent loops, one sort + a handful of n-sized
-vector ops. Gathers all input partitions (like SortExec/WindowExec); the
-optimizer only plans this node below a join that re-distributes by group
-key, so the funnel carries one row per group outward.
+vector ops. Works a partition of its input at a time: the planner puts
+every row of a group into one partition (``PhysicalPlanner._whole_groups``:
+the hash exchange on the group keys, or the gather into one), and the
+optimizer only plans this node below a join on the group keys, so one row
+per group goes outward.
 """
 
 from __future__ import annotations
@@ -28,8 +30,10 @@ from ballista_tpu.exec.base import (
     UnknownPartitioning,
 )
 from ballista_tpu.expr import logical as L
+from ballista_tpu.ops.aggregate import running_count
 from ballista_tpu.ops.concat import concat_batches
-from ballista_tpu.ops.sort import SortKey, gather_batch, sort_perm
+from ballista_tpu.ops.perm import holistic_perm, holistic_take
+from ballista_tpu.ops.sort import SortKey, argsort_count, sort_passes
 
 
 @functools.lru_cache(maxsize=None)
@@ -66,8 +70,9 @@ def _pct_program(
             valid_sorted & ~val_nmask
         )
         # live rows of a group are its prefix (value-nulls sort last), so
-        # the live count per row's group is a cumsum difference
-        cnt_cs = jnp.cumsum(live.astype(jnp.int64))
+        # the live count per row's group is a difference of running
+        # counts: below 2^31, as the capacity is
+        cnt_cs = running_count(live, jnp.int32)
         nxt = jnp.flip(
             jax.lax.cummin(jnp.flip(jnp.where(changed, cap_i, cap)))
         )
@@ -143,7 +148,7 @@ class PercentileExec(ExecutionPlan):
         return [self.input]
 
     def output_partitioning(self):
-        return UnknownPartitioning(1)
+        return UnknownPartitioning(self.input.output_partitioning().n)
 
     def describe(self) -> str:
         g = ", ".join(e.name() for e in self.group_exprs)
@@ -157,10 +162,12 @@ class PercentileExec(ExecutionPlan):
     ) -> Iterator[DeviceBatch]:
         from ballista_tpu.exec.shrink import maybe_shrink
 
-        batches = []
-        part = self.input.output_partitioning()
-        for p in range(part.n):
-            batches.extend(self.input.execute(p, ctx))
+        # the order of the rows is nothing to a sort: by capacity, so that
+        # batches a shuffle read delivered in another order (two map outputs
+        # fetched at once) concatenate through the program of the last time
+        batches = sorted(
+            self.input.execute(partition, ctx), key=lambda b: -b.capacity
+        )
         if not batches:
             return
         b = concat_batches(batches) if len(batches) > 1 else batches[0]
@@ -170,16 +177,25 @@ class PercentileExec(ExecutionPlan):
         keys.append(
             SortKey(col=self._vi, ascending=True, nulls_first=False)
         )
+        # for the executor's ``holistic.*`` counters; the live rows stay a
+        # device scalar until the task's metrics are read
+        self.metrics.add("rows_sorted", jnp.sum(b.valid, dtype=jnp.int64))
+        self.metrics.add(
+            "sort_passes", argsort_count(b.columns, b.nulls, keys)
+        )
         with self.metrics.time("sort_time"):
-            perm = sort_perm(b, keys)
+            perm = holistic_perm(
+                sort_passes(b.columns, b.nulls, b.valid, keys)
+            )
             # one stacked-by-dtype random-access pass for every column +
             # mask + validity (the optimizer projects the input down to
             # exactly keys + value, so whole-batch gather is minimal)
-            sb = gather_batch(b, perm)
+            cols, nmasks, valid_sorted = holistic_take(
+                list(b.columns), list(b.nulls), b.valid, perm
+            )
 
-        key_pairs = [(sb.columns[i], sb.nulls[i]) for i in self._gk]
-        val, val_null = sb.columns[self._vi], sb.nulls[self._vi]
-        valid_sorted = sb.valid
+        key_pairs = [(cols[i], nmasks[i]) for i in self._gk]
+        val, val_null = cols[self._vi], nmasks[self._vi]
         prog = _pct_program(
             tuple(b.nulls[i] is not None for i in self._gk),
             b.nulls[self._vi] is not None,

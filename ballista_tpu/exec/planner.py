@@ -94,6 +94,32 @@ class PhysicalPlanner:
             and (self.config is None or self.config.repartition_joins())
         )
 
+    def _repartition_windows(self) -> bool:
+        return (
+            self.distributed
+            and self.partitions > 1
+            and (self.config is None or self.config.repartition_windows())
+        )
+
+    def _whole_groups(
+        self, child: ExecutionPlan, keys: list[L.Expr]
+    ) -> ExecutionPlan:
+        """What a window or a percentile stands on: an input in which every
+        group lies whole inside one partition, since the operators work a
+        partition at a time. On the distributed tier that is the hash
+        exchange on the group's keys (``ballista.repartition.windows``): the
+        stage splitter cuts there and each bucket is a task, as the
+        reference plans ``WindowAggExec`` over ``RepartitionExec(Hash)``.
+        Without keys, or in one process, where an exchange is K masked views
+        of every batch, it is the gather into one partition (docs/sql.md)."""
+        if keys and self._repartition_windows():
+            from ballista_tpu.exec.repartition import HashRepartitionExec
+
+            return HashRepartitionExec(child, keys, self.partitions)
+        if child.output_partitioning().n > 1:
+            return CoalescePartitionsExec(child)
+        return child
+
     def plan(self, logical: P.LogicalPlan) -> ExecutionPlan:
         return self._plan(logical)
 
@@ -138,7 +164,9 @@ class PhysicalPlanner:
             from ballista_tpu.exec.percentile import PercentileExec
 
             return PercentileExec(
-                self._plan(node.input),
+                self._whole_groups(
+                    self._plan(node.input), list(node.group_exprs)
+                ),
                 node.group_exprs,
                 node.group_names,
                 node.requests,
@@ -162,10 +190,17 @@ class PhysicalPlanner:
                     )
                 except PlanError:
                     pass
-            # WindowExec gathers all input partitions itself (a ranking
-            # window needs every row of a partition in one place)
+            # the columns every expression partitions by route the
+            # exchange: a partition of any of them then lies in one bucket
+            shared = [
+                e for e in node.window_exprs[0].partition_by
+                if all(
+                    any(e.name() == o.name() for o in w.partition_by)
+                    for w in node.window_exprs[1:]
+                )
+            ] if node.window_exprs else []
             return WindowExec(
-                child,
+                self._whole_groups(child, shared),
                 list(node.window_exprs),
                 list(node.names),
             )

@@ -36,9 +36,10 @@ from ballista_tpu.exec.base import (
     UnknownPartitioning,
 )
 from ballista_tpu.expr import logical as L
+from ballista_tpu.ops.aggregate import running_count
 from ballista_tpu.ops.concat import concat_batches
-from ballista_tpu.ops.perm import take
-from ballista_tpu.ops.sort import SortKey, sort_perm
+from ballista_tpu.ops.perm import holistic_perm, holistic_take
+from ballista_tpu.ops.sort import SortKey, argsort_count, sort_passes
 
 
 @functools.lru_cache(maxsize=None)
@@ -51,28 +52,17 @@ def _rank_program(
     the permutation; output is the rank column at ORIGINAL row positions.
     Gathers/cumsums plus one unique-index permutation scatter."""
 
-    def changed_of(cols, nulls):
-        changed = jnp.zeros(cap, dtype=bool).at[0].set(True)
-        for col, nm in zip(cols, nulls):
-            zc = col if nm is None else jnp.where(nm, jnp.zeros_like(col), col)
-            changed = changed | jnp.concatenate(
-                [jnp.ones(1, dtype=bool), zc[1:] != zc[:-1]]
-            )
-            if nm is not None:
-                changed = changed | jnp.concatenate(
-                    [jnp.ones(1, dtype=bool), nm[1:] != nm[:-1]]
-                )
-        return changed
-
     def window_rank(part_cols, part_nmasks, order_cols, order_nmasks, perm):
-        idx = jnp.arange(cap, dtype=jnp.int64)
+        # positions and ranks are below 2^31 (capacities are): counted in
+        # int32, which the TPU carries whole, and widened once at the end
+        idx = jnp.arange(cap, dtype=jnp.int32)
         part_changed = (
-            changed_of(part_cols, part_nmasks)
+            _changed_of(part_cols, part_nmasks, cap)
             if part_cols
             else jnp.zeros(cap, dtype=bool).at[0].set(True)
         )
         order_changed = (
-            changed_of(order_cols, order_nmasks)
+            _changed_of(order_cols, order_nmasks, cap)
             if order_cols
             else jnp.zeros(cap, dtype=bool)
         )
@@ -85,7 +75,7 @@ def _rank_program(
             )
             vals = peer_start - start + 1
         else:  # dense_rank
-            dr = jnp.cumsum((part_changed | order_changed).astype(jnp.int64))
+            dr = running_count(part_changed | order_changed, jnp.int32)
             dr_at_start = jax.lax.cummax(jnp.where(part_changed, dr, 0))
             vals = dr - dr_at_start + 1
         # back to original row order: out[perm[i]] = vals[i] (perm is a
@@ -93,7 +83,7 @@ def _rank_program(
         return (
             jnp.zeros(cap, dtype=jnp.int64)
             .at[perm]
-            .set(vals, unique_indices=True)
+            .set(vals.astype(jnp.int64), unique_indices=True)
         )
 
     return jax.jit(window_rank)
@@ -236,7 +226,7 @@ def _agg_window_program(
             from ballista_tpu.ops.aggregate import _prefix_sum_2d
 
             cs = _prefix_sum_2d(contrib[:, None])[:, 0]
-            cnt_cs = jnp.cumsum(live.astype(jnp.int64))
+            cnt_cs = running_count(live)
 
             hi_c = jnp.clip(hi, 0, cap - 1)
             lo_c = jnp.clip(lo, 0, cap - 1)
@@ -270,7 +260,7 @@ def _agg_window_program(
             run = _seg_running_minmax(masked, ps, fname == "min")
             hi_c = jnp.clip(hi, 0, cap - 1)
             vals = run[hi_c]
-            cnt_cs = jnp.cumsum(live.astype(jnp.int64))
+            cnt_cs = running_count(live)
             pre = jnp.where(
                 ps > 0, cnt_cs[jnp.clip(ps - 1, 0, cap - 1)], 0
             )
@@ -297,9 +287,11 @@ def _agg_window_program(
 
 
 class WindowExec(ExecutionPlan):
-    """Appends one INT64 rank column per window expression. Gathers ALL
-    input partitions (a ranking window needs every row of a partition in
-    one place), so output partitioning is 1."""
+    """Appends one column per window expression, a partition of its input
+    at a time: a window needs every row of a ``PARTITION BY`` group in one
+    place, and the planner puts them there (``PhysicalPlanner._whole_groups``:
+    the hash exchange on the shared keys, or the gather into one
+    partition). The output keeps the input's partitioning."""
 
     def __init__(self, input: ExecutionPlan, window_exprs, names) -> None:
         super().__init__()
@@ -391,7 +383,7 @@ class WindowExec(ExecutionPlan):
         return [self.input]
 
     def output_partitioning(self):
-        return UnknownPartitioning(1)
+        return UnknownPartitioning(self.input.output_partitioning().n)
 
     def describe(self) -> str:
         return "WindowExec: " + ", ".join(
@@ -402,13 +394,16 @@ class WindowExec(ExecutionPlan):
     def execute(
         self, partition: int, ctx: TaskContext
     ) -> Iterator[DeviceBatch]:
-        batches = []
-        part = self.input.output_partitioning()
-        for p in range(part.n):
-            batches.extend(self.input.execute(p, ctx))
+        # the order of the rows is nothing to a sort: by capacity, so that
+        # batches a shuffle read delivered in another order (two map outputs
+        # fetched at once) concatenate through the program of the last time
+        batches = sorted(
+            self.input.execute(partition, ctx), key=lambda b: -b.capacity
+        )
         if not batches:
             return
         b = concat_batches(batches) if len(batches) > 1 else batches[0]
+        self._count_sorts(b)
         out_cols, out_nulls = self.append_window_columns(b)
         yield DeviceBatch(
             schema=self._schema,
@@ -417,6 +412,25 @@ class WindowExec(ExecutionPlan):
             nulls=tuple(out_nulls),
             dictionaries=dict(b.dictionaries),
         )
+
+    @staticmethod
+    def _sort_keys(pk, ok) -> tuple:
+        return tuple(SortKey(col=i, ascending=True) for i in pk) + ok
+
+    def _count_sorts(self, b: DeviceBatch) -> None:
+        """The sorts ``append_window_columns`` dispatches for ``b``, into
+        the operator's metrics (the executor sums them into the
+        ``holistic.*`` counters; docs/observability.md). The live rows stay
+        a device scalar until the task's metrics are read."""
+        distinct = dict.fromkeys(
+            self._sort_keys(pk, ok) for pk, ok in self._keys
+        )
+        live = jnp.sum(b.valid, dtype=jnp.int64)
+        for sk in distinct:
+            self.metrics.add("rows_sorted", live)
+            self.metrics.add(
+                "sort_passes", argsort_count(b.columns, b.nulls, sk)
+            )
 
     def append_window_columns(self, b: DeviceBatch):
         """Input batch -> (columns + appended window columns, null masks).
@@ -430,23 +444,27 @@ class WindowExec(ExecutionPlan):
             self.window_exprs, self._keys, self._args, self._arg_lits,
             self._schema.fields[len(b.schema):],
         ):
-            sk = tuple(SortKey(col=i, ascending=True) for i in pk) + ok
+            sk = self._sort_keys(pk, ok)
             perm = perm_cache.get(sk)
             if perm is None:
                 with self.metrics.time("sort_time"):
-                    perm = sort_perm(b, list(sk))
+                    perm = holistic_perm(
+                        sort_passes(b.columns, b.nulls, b.valid, list(sk))
+                    )
                 perm_cache[sk] = perm
 
-            def gathered(i):
-                return (
-                    take(b.columns[i], perm),
-                    None
-                    if b.nulls[i] is None
-                    else take(b.nulls[i], perm),
-                )
-
-            part_pairs = [gathered(i) for i in pk]
-            order_pairs = [gathered(k.col) for k in ok]
+            # keys, argument and validity in sorted order: one dispatch
+            ranking = w.fname in ("row_number", "rank", "dense_rank")
+            want = list(pk) + [k.col for k in ok]
+            if not ranking and argi not in (None, -1):
+                want.append(argi)
+            got, got_nulls, valid_sorted = holistic_take(
+                [b.columns[i] for i in want], [b.nulls[i] for i in want],
+                b.valid, perm,
+            )
+            pairs = list(zip(got, got_nulls))
+            part_pairs = pairs[: len(pk)]
+            order_pairs = pairs[len(pk): len(pk) + len(ok)]
             if w.fname in ("row_number", "rank", "dense_rank"):
                 prog = _rank_program(
                     tuple(b.nulls[i] is not None for i in pk),
@@ -476,8 +494,7 @@ class WindowExec(ExecutionPlan):
                 )
                 arg_null = None
             else:
-                arg_col, arg_null = gathered(argi)
-            valid_sorted = take(b.valid, perm)
+                arg_col, arg_null = pairs[-1]
             frame_key = (
                 None
                 if w.frame is None

@@ -458,6 +458,23 @@ class Executor:
             from ballista_tpu.compilecache import metrics as compile_metrics
 
             compile_metrics.add("agg.groups_out", groups_out)
+        # likewise the sorts of the task's window and percentile operators
+        holistic = [
+            r["counters"] for r in op_metrics or ()
+            if r["operator"] in ("WindowExec", "PercentileExec")
+            and "sort_passes" in r["counters"]
+        ]
+        if holistic:
+            from ballista_tpu.compilecache import metrics as compile_metrics
+
+            compile_metrics.add_many(
+                [("holistic.tasks", 1)]
+                + [
+                    (f"holistic.{name}", c[name])
+                    for c in holistic
+                    for name in ("rows_sorted", "sort_passes")
+                ]
+            )
         # cost accounting (docs/observability.md): this attempt's
         # resource vector — wall/CPU around the run, the plan's
         # data-plane counters (shuffle read, spill, push), the committed

@@ -449,6 +449,13 @@ def _prefix_sum_2d(x2: jnp.ndarray) -> jnp.ndarray:
     return _blocked_prefix(x2, _INT_PREFIX_BLOCK)
 
 
+def running_count(flags: jnp.ndarray, dtype=jnp.int64) -> jnp.ndarray:
+    """Inclusive count of the set ``flags`` (n,) up to each row: the integer
+    prefix of the window and percentile operators, through
+    ``_prefix_sum_2d``."""
+    return _prefix_sum_2d(flags.astype(dtype)[:, None])[:, 0]
+
+
 def _float_prefix_parts(x2: jnp.ndarray, block: int = _PREFIX_BLOCK):
     """(n, M) floats -> the inclusive prefix along axis 0 in two levels,
     for ``_float_seg_totals``: ``inner`` (nb * block, M), the prefix within

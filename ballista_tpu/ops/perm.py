@@ -31,18 +31,72 @@ def argsort_i32(c: jnp.ndarray) -> jnp.ndarray:
     return jax.lax.sort_key_val(c, iota, is_stable=True)[1]
 
 
+def _reversed(c: jnp.ndarray) -> jnp.ndarray:
+    """A key whose ascending order is ``c``'s descending one."""
+    if jnp.issubdtype(c.dtype, jnp.floating):
+        return -c
+    return ~c  # ~x = -x-1: total order reversal incl. INT_MIN
+
+
 @functools.lru_cache(maxsize=None)
-def _argsort_program(dtype: str, cap: int, descending: bool, is_float: bool):
+def _argsort_program(dtype: str, cap: int, descending: bool):
     def sort_argsort(col):
-        c = col
-        if descending:
-            if is_float:
-                c = -c
-            else:
-                c = ~c  # ~x = -x-1: total order reversal incl. INT_MIN
-        return argsort_i32(c)
+        return argsort_i32(_reversed(col) if descending else col)
 
     return jax.jit(sort_argsort)
+
+
+def _f32_order(x: jnp.ndarray) -> jnp.ndarray:
+    """The int32 that orders as the float32 ``x`` does: what ``lax.sort``'s
+    own comparator makes of a float at every comparison (zeros and NaNs
+    standardised, then the bits, negatives reversed; NaNs last), made once."""
+    x = jnp.where(x == 0, jnp.zeros_like(x), x)
+    x = jnp.where(jnp.isnan(x), jnp.full_like(x, jnp.nan), x)
+    bits = jax.lax.bitcast_convert_type(x, jnp.int32)
+    return jnp.where(bits < 0, jnp.iinfo(jnp.int32).max - bits, bits)
+
+
+@functools.lru_cache(maxsize=None)
+def _f64_keys_program(cap: int, descending: bool):
+    def sort_f64_keys(col):
+        c = -col if descending else col
+        hi = c.astype(jnp.float32)
+        lo = (c - hi.astype(jnp.float64)).astype(jnp.float32)
+        return _f32_order(hi), _f32_order(lo)
+
+    return jax.jit(sort_f64_keys)
+
+
+def argsorts_of(col: jnp.ndarray) -> int:
+    """Argsort passes a sort by ``col`` dispatches: ``narrow_passes``' rule."""
+    wide = col.dtype == jnp.float64 and jax.default_backend() != "cpu"
+    return 2 if wide else 1
+
+
+def narrow_passes(
+    passes: list[tuple[jnp.ndarray, bool]],
+) -> list[tuple[jnp.ndarray, bool]]:
+    """``passes`` (most significant first) with every float64 key as two
+    int32 keys, on the TPU.
+
+    The chip has no float64: the compiler carries one as a pair of float32
+    (a value read back differs from what went in by up to 2e-15 of itself),
+    compares pairs inside the sort, and takes 225-259 s over one
+    float64[8M] argsort program, ascending and descending apart (PERF.md,
+    PR 33; compiled for a described v5e; int64 84 s, int32 32 s). The pair
+    sorts as two stable passes over its halves' integer order, which ride
+    the int32 program of their capacity with the flags: the order is the
+    same, and a float64 key compiles no sort program of its own. The CPU has
+    float64 whole and compiles its sort in a second: it keeps the one pass.
+    """
+    out = []
+    for col, desc in passes:
+        if argsorts_of(col) == 2:
+            hi, lo = _f64_keys_program(col.shape[0], desc)(col)
+            out += [(hi, False), (lo, False)]
+        else:
+            out.append((col, desc))
+    return out
 
 
 def stable_argsort(col: jnp.ndarray, descending: bool = False) -> jnp.ndarray:
@@ -52,12 +106,7 @@ def stable_argsort(col: jnp.ndarray, descending: bool = False) -> jnp.ndarray:
         # (dtype, capacity) is a sort program of its own, and one sort
         # program costs the TPU compiler 10-55 s (PERF.md, PR 21)
         col = col.astype(jnp.int32)
-    return _argsort_program(
-        str(col.dtype),
-        col.shape[0],
-        descending,
-        bool(jnp.issubdtype(col.dtype, jnp.floating)),
-    )(col)
+    return _argsort_program(str(col.dtype), col.shape[0], descending)(col)
 
 
 @functools.lru_cache(maxsize=None)
@@ -120,6 +169,14 @@ def take_many_split(
     return gathered[: len(cols)], out_opt
 
 
+def _take_stacked(cols, nulls, valid, perm):
+    """Columns, null masks and validity by ``perm``, stacked by dtype."""
+    gathered, out_nulls = take_many_split(
+        [valid] + list(cols), list(nulls), perm
+    )
+    return gathered[1:], out_nulls, gathered[0]
+
+
 @functools.lru_cache(maxsize=None)
 def _take_batch_program(sig: tuple, nulls_sig: tuple):
     """One jitted program gathering a whole column set (+ null masks +
@@ -128,10 +185,7 @@ def _take_batch_program(sig: tuple, nulls_sig: tuple):
     per shape on its own, so capacity is deliberately NOT in the key.)"""
 
     def perm_take_batch(cols, nulls, valid, perm):
-        gathered, out_nulls = take_many_split(
-            [valid] + list(cols), list(nulls), perm
-        )
-        return gathered[1:], out_nulls, gathered[0]
+        return _take_stacked(cols, nulls, valid, perm)
 
     return jax.jit(perm_take_batch)
 
@@ -161,6 +215,55 @@ def multi_key_perm(
     Each pass is (column, descending). Executes least-significant first."""
     cap = passes[0][0].shape[0]
     perm = jnp.arange(cap, dtype=jnp.int32)
-    for col, desc in reversed(passes):
+    for col, desc in reversed(narrow_passes(passes)):
         perm = refine_perm(perm, col, desc)
     return perm
+
+
+# -- the window and percentile operators' own programs ----------------------
+# A sort over a whole table is these operators' work and nobody else's, so
+# its programs carry a name of their own (``holistic_*``): the trace's
+# ``XLA Modules`` line and a reader can then tell them from the group-by's
+# and the join's (docs/observability.md). A pass is ONE program here: the
+# key's gather, the argsort and the permutation's gather fused.
+
+
+@functools.lru_cache(maxsize=None)
+def _holistic_pass_program(dtype: str, cap: int, descending: bool):
+    def holistic_sort_pass(col, perm):
+        c = col[perm]
+        return perm[argsort_i32(_reversed(c) if descending else c)]
+
+    return jax.jit(holistic_sort_pass)
+
+
+def holistic_perm(passes: list[tuple[jnp.ndarray, bool]]) -> jnp.ndarray:
+    """``multi_key_perm`` for the window and percentile operators: the same
+    passes, the same order among equal keys, one named program a pass."""
+    perm = jnp.arange(passes[0][0].shape[0], dtype=jnp.int32)
+    for col, desc in reversed(narrow_passes(passes)):
+        if col.dtype == jnp.bool_:
+            col = col.astype(jnp.int32)  # as stable_argsort: one program less
+        perm = _holistic_pass_program(str(col.dtype), col.shape[0], desc)(
+            col, perm
+        )
+    return perm
+
+
+@functools.lru_cache(maxsize=None)
+def _holistic_take_program(sig: tuple, nulls_sig: tuple):
+    def holistic_take(cols, nulls, valid, perm):
+        return _take_stacked(cols, nulls, valid, perm)
+
+    return jax.jit(holistic_take)
+
+
+def holistic_take(cols: list, nulls: list, valid, perm):
+    """``take_batch`` under the operators' own name: columns, null masks
+    and validity by ``perm`` in one dispatch, stacked by dtype."""
+    prog = _holistic_take_program(
+        tuple(str(c.dtype) for c in cols),
+        tuple(m is not None for m in nulls),
+    )
+    return prog(tuple(cols), tuple(nulls), valid, perm)
+

@@ -21,7 +21,7 @@ import jax
 import jax.numpy as jnp
 
 from ballista_tpu.columnar.batch import DeviceBatch
-from ballista_tpu.ops.perm import multi_key_perm, take_batch
+from ballista_tpu.ops.perm import argsorts_of, multi_key_perm, take_batch
 
 
 @dataclasses.dataclass(frozen=True)
@@ -71,6 +71,15 @@ def sort_passes(cols, nulls, valid, keys: list["SortKey"]):
             passes.append((nm != k.nulls_first, False))
         passes.append((cols[k.col], not k.ascending))
     return passes
+
+
+def argsort_count(cols, nulls, keys) -> int:
+    """Argsort passes a sort by ``keys`` dispatches: ``sort_passes``'
+    validity pass, each key's null pass and the key's own (two for a key
+    that ``ops/perm.py narrow_passes`` splits), without making them."""
+    return 1 + sum(
+        (nulls[k.col] is not None) + argsorts_of(cols[k.col]) for k in keys
+    )
 
 
 def sort_perm(batch: DeviceBatch, keys: list[SortKey]) -> jnp.ndarray:

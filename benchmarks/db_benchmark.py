@@ -13,7 +13,10 @@ produced no tracked number. The tracked measurement of the group-by
 questions is the benchmark's cell ``h2o-g1-1e7-mem.groupby`` (BENCHMARK.json,
 ``perf/queries/g1q{3,5,2,7}.sql``: questions 3, 5, 2 and 7 of the SQL below
 on the served path at 1e7 rows, each answer held to a plain reference;
-PERF.md §4).
+PERF.md §4), and of the advanced questions 6 and 8 the cell
+``h2o-g1-1e7-adv-mem.advanced`` (``perf/queries/g1q{6,8}.sql``: the exact
+median and the sample deviation by two keys, the two largest per key with
+their row numbers).
 
 Usage: python benchmarks/db_benchmark.py [--n 1e6] [--k 100] [--iterations 2]
 """

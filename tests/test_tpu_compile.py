@@ -115,7 +115,7 @@ def test_searchsorted_sort_method(S):
 def test_argsort_pass_program(S, dtype):
     from ballista_tpu.ops import perm
 
-    prog = perm._argsort_program(dtype, 8192, True, dtype == "float64")
+    prog = perm._argsort_program(dtype, 8192, True)
     assert " sort(" in _compile(prog, S((8192,), dtype))
 
 
@@ -249,6 +249,43 @@ def test_group_by_segment_programs_at_the_h2o_cell_shape(S, tpu_branches):
 
     _compile(part2, place(n_groups), place(ps), place(cnt_cs),
              place(tuple(sum_cs)), S((n,), "int32"))
+
+
+# what one bucket of the exchange holds of h2o-g1-1e7-adv-mem's 1e7 rows
+# (5e6) rounds up to on the capacity ladder
+HOLISTIC_CAP = 1 << 23
+
+
+@pytest.mark.parametrize("program", ["window_rank", "percentile_interp"])
+def test_holistic_programs_at_the_h2o_adv_cell_shape(S, tpu_branches, program):
+    """g1q8's ranking (``row_number`` over an int64 partition key, ordered by
+    a float64) and g1q6's interpolation (two int64 keys with their null
+    flags, a float64) as a task of ``h2o-g1-1e7-adv-mem.advanced`` reaches
+    them: they compile in seconds, and the running count goes by 2048-row
+    blocks, no stock ``cumsum`` over the whole length left (``row_number``
+    counts nothing: its case holds the compile). The running maxima stay
+    stock: the compiler blocks a one-column ``cummax`` itself (PERF.md,
+    PR 33)."""
+    n = HOLISTIC_CAP
+    if program == "window_rank":
+        from ballista_tpu.exec.window import _rank_program
+
+        prog = _rank_program((False,), (False,), "row_number", n)
+        args = ([S((n,), "int64")], [None], [S((n,), "float64")], [None],
+                S((n,), "int32"))
+    else:
+        from ballista_tpu.exec.percentile import _pct_program
+
+        prog = _pct_program((False,) * 4, False, (0.5,), n)
+        keys = [S((n,), "int64"), S((n,), "bool")] * 2
+        args = (keys, [None] * 4, S((n,), "float64"), None, S((n,), "bool"))
+    _compile(prog, *args)
+    lowered = prog.lower(*args).as_text()
+    sums = [line for line in lowered.splitlines() if "call @cumsum" in line]
+    assert bool(sums) == (program == "percentile_interp")
+    whole = [line for line in sums
+             if f"tensor<{n}xi64>" in line or f"tensor<{n}xi32>" in line]
+    assert not whole, whole[0]
 
 
 @pytest.mark.parametrize("dtype", ["int32", "int64"])
