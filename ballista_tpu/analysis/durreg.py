@@ -309,6 +309,17 @@ STATE: tuple[StateEntry, ...] = (
         "clients resubmit (same contract as stage-state)",
     ),
     StateEntry(
+        name="jobs-ending",
+        anchors=(
+            "ballista_tpu/scheduler/server.py::SchedulerServer._ending",
+        ),
+        durability="ephemeral",
+        contents="ids of the jobs whose end is being recorded right now "
+        "(_job_ending): held GetJobStatus calls wait for them",
+        recovery="empty at start: a restarted scheduler holds no call, "
+        "and a job recovered terminal is answered from its status",
+    ),
+    StateEntry(
         name="job-run-counters",
         anchors=(
             "ballista_tpu/scheduler/server.py::JobInfo.max_attempts",

@@ -6,8 +6,11 @@ boots an in-proc scheduler + executor (:137-207); table registration is
 kept CLIENT-side and travels with each query's serialized logical plan
 (:258-308); sql() intercepts SHOW and CREATE EXTERNAL TABLE (:311-435);
 collect() drives the DistributedQueryExec flow (core/src/execution_plans/
-distributed_query.rs:160-326): submit, poll GetJobStatus every 100ms, then
-Flight-fetch the completed partition locations.
+distributed_query.rs:160-326): submit, ask GetJobStatus until the job has
+ended, then Flight-fetch the completed partition locations. Every ask says
+how long the client will wait (``STATUS_WAIT_MS``), and the scheduler holds
+it until the job ends (docs/serving.md); the reference's 100 ms between two
+asks is what is left for a scheduler that does not.
 """
 
 from __future__ import annotations
@@ -28,7 +31,13 @@ from ballista_tpu.sql import ast
 from ballista_tpu.sql.parser import parse_sql
 from ballista_tpu.sql.planner import SqlPlanner
 
-POLL_INTERVAL = 0.1  # ref distributed_query.rs:268
+# the least time between two GetJobStatus asks (ref distributed_query.rs:268):
+# all of it against a scheduler that answers at once, none of it after a
+# held call that took as long
+POLL_INTERVAL = 0.1
+# what every ask offers to wait; the scheduler holds it no longer than its
+# own bound (scheduler/server.py POLL_HOLD_S)
+STATUS_WAIT_MS = 1000
 
 
 class BallistaContext(TpuContext):
@@ -191,12 +200,14 @@ class BallistaContext(TpuContext):
             )
         job_id = result.job_id
         deadline = time.time() + 600
-        # the poll sleep below is deliberately NO phase: the client always
-        # sleeps while the executor works, and on a trace its span would
-        # take every idle gap's label (docs/observability.md)
+        # the wait below (the held call, and the sleep after one that was
+        # not held) is deliberately NO phase: the client always waits while
+        # the executor works, and on a trace its span would take every idle
+        # gap's label (docs/observability.md)
         while True:
+            asked = time.monotonic()
             status = self._stub.GetJobStatus(
-                pb.GetJobStatusParams(job_id=job_id)
+                pb.GetJobStatusParams(job_id=job_id, wait_ms=STATUS_WAIT_MS)
             ).status
             kind = status.WhichOneof("status")
             if kind == "completed":
@@ -210,7 +221,13 @@ class BallistaContext(TpuContext):
                 )
             if time.time() > deadline:
                 raise GrpcError(f"job {job_id} timed out")
-            time.sleep(POLL_INTERVAL)
+            # the scheduler holds the ask until the job ends or its bound
+            # runs out, and then the next one goes at once; one that did
+            # not (over its budget, or from before it held) is asked every
+            # POLL_INTERVAL, as the reference's is
+            left = POLL_INTERVAL - (time.monotonic() - asked)
+            if left > 0:
+                time.sleep(left)
 
     def _fetch_results(
         self, completed: pb.CompletedJob, logical: LogicalPlan

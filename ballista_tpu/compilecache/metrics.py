@@ -38,6 +38,12 @@ telemetry uses) and keeps process-global counters:
   between polls that a finished task ended, idle executors' polls the
   scheduler held, and those that ended in a grant or at the bound.
   Declared at 0 likewise.
+- ``status.rpcs`` / ``status.holds`` / ``status.holds_ended_by_status`` /
+  ``status.holds_timed_out`` / ``status.holds_over_budget`` (scheduler) —
+  the client's hand-off (docs/observability.md): ``GetJobStatus`` calls
+  served, those held for a job that had not ended, and the holds its end
+  released, that ran out at the bound, or that the budget of held calls
+  had no room for. Declared at 0 likewise.
 - ``hints.marks`` / ``hints.writes_skipped_unchanged`` /
   ``hints.entries_job_scoped_skipped`` / ``hints_saved`` / ``hints_loaded``
   — the plan-hint store (compilecache/hints.py): tasks and collects that
@@ -70,6 +76,12 @@ POLL_COUNTERS = (
     "poll.rpcs", "poll.wakes_by_status", "poll.holds", "poll.holds_granted",
     "poll.holds_timed_out",
 )
+# the client's hand-off (docs/serving.md): status calls served and held,
+# and how a hold ended (scheduler)
+STATUS_COUNTERS = (
+    "status.rpcs", "status.holds", "status.holds_ended_by_status",
+    "status.holds_timed_out", "status.holds_over_budget",
+)
 # the plan-hint store's hand-off to its writer (compilecache/hints.py), with
 # the writer's phase: 0 and not absent in a process whose writer never woke
 HINT_COUNTERS = (
@@ -77,7 +89,8 @@ HINT_COUNTERS = (
     "hints.entries_job_scoped_skipped", "phase.executor.hints_write.seconds",
 )
 _COUNTERS: dict[str, float] = dict.fromkeys(
-    AGG_COUNTERS + HOLISTIC_COUNTERS + POLL_COUNTERS + HINT_COUNTERS, 0
+    AGG_COUNTERS + HOLISTIC_COUNTERS + POLL_COUNTERS + STATUS_COUNTERS
+    + HINT_COUNTERS, 0
 )
 _INSTALLED = False
 

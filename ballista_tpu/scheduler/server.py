@@ -16,7 +16,9 @@ event loop (scheduler_server/query_stage_scheduler.rs:40-473):
 from __future__ import annotations
 
 import collections
+import contextlib
 import dataclasses
+import functools
 import logging
 import random
 import string
@@ -72,12 +74,29 @@ log = logging.getLogger(__name__)
 # the ride home of the executor's counters and spans (/api/state), several
 # times a second.
 POLL_HOLD_S = 0.25
-# workers of the scheduler's gRPC pool (start_scheduler_grpc). A held poll
-# occupies one until it ends, so at most half of them hold; a poll beyond
-# that is answered at once, as before, and the executor waits its own
-# POLL_INTERVAL
+# workers of the scheduler's gRPC pool (start_scheduler_grpc). A held call
+# (an idle executor's PollWork, a client's GetJobStatus with a wait) occupies
+# one until it ends, so both kinds draw on one budget that leaves a quarter
+# of the pool to the calls that end them (ExecuteQuery, UpdateTaskStatus, a
+# PollWork that brings a status); a call beyond it is answered at once, as
+# before, and its caller waits out its own POLL_INTERVAL
 GRPC_WORKERS = 16
-MAX_HELD_POLLS = GRPC_WORKERS // 2
+MAX_HELD_CALLS = GRPC_WORKERS * 3 // 4
+# the statuses a job does not leave: what a held GetJobStatus waits for
+JOB_ENDED = ("completed", "failed")
+
+
+def _ends_job(method):
+    """A ``SchedulerServer`` method that sets a job's terminal status and
+    records its end (first argument: the job or its id) runs inside
+    :meth:`SchedulerServer._job_ending`."""
+
+    @functools.wraps(method)
+    def ending(self, job, *args, **kwargs):
+        with self._job_ending(getattr(job, "job_id", job)):
+            return method(self, job, *args, **kwargs)
+
+    return ending
 
 
 def generate_job_id() -> str:
@@ -444,10 +463,19 @@ class SchedulerServer:
         self.obs_bypass_total = 0
         # held polls (next_tasks_held): waiters sleep on _work_cv; whatever
         # makes a task grantable bumps _work_gen under it and wakes them
-        # all. Its lock is a leaf: nothing else is taken while it is held
-        self._work_cv = threading.Condition()
+        # all. Held status calls (job_status_held) sleep on _status_cv, and
+        # a job's end wakes them all: a condition each, so that a job's end
+        # wakes no poll and a grantable task no client. One lock under both
+        # guards the budget they share (MAX_HELD_CALLS). It is a leaf:
+        # nothing else is taken while it is held
+        hold_lock = threading.Lock()
+        self._work_cv = threading.Condition(hold_lock)
         self._work_gen = 0
         self._held_polls = 0
+        self._status_cv = threading.Condition(hold_lock)
+        self._held_status = 0
+        # jobs whose end is being recorded (_job_ending), under hold_lock
+        self._ending: set[str] = set()
         self._stopping = False
         self.state = None
         if state_backend is not None:
@@ -607,8 +635,10 @@ class SchedulerServer:
                     # tasks in flight died with the old scheduler; fail
                     # loudly rather than dangle (running StageManager state
                     # is not persisted, matching the reference)
-                    job.status = "failed"
-                    job.error = "scheduler restarted while job was in flight"
+                    self._set_job_status(
+                        job, "failed",
+                        "scheduler restarted while job was in flight",
+                    )
                     self.state.save_job(job)
                     # the history log must agree with the job record: the
                     # predecessor wrote "submitted" but never a terminal
@@ -1526,7 +1556,7 @@ class SchedulerServer:
         job.dependencies = deps
         self.stage_manager.add_final_stage(job_id, job.final_stage_id)
         self.stage_manager.add_stages_dependency(job_id, deps)
-        job.status = "running"
+        self._set_job_status(job, "running")
         if self.state is not None:
             # write-through: stage plans + job record (ref
             # persistent_state.rs save_stage_plan :183-324)
@@ -2132,6 +2162,22 @@ class SchedulerServer:
                 if old.trace_id:
                     self._traces.pop(old.trace_id, None)
 
+    @contextlib.contextmanager
+    def _job_ending(self, job_id: str):
+        """While a job's end is recorded (status, history record, trace,
+        state write-through), a held status call keeps waiting: it is told
+        of the end when what a client may read next has it, and woken
+        here. An unheld call is answered from the status, as ever."""
+        with self._status_cv:
+            self._ending.add(job_id)
+        try:
+            yield
+        finally:
+            with self._status_cv:
+                self._ending.discard(job_id)
+                self._status_cv.notify_all()
+
+    @_ends_job
     def _on_job_finished(self, job_id: str) -> None:
         """Assemble CompletedJob locations (ref :370-388, :416-473)."""
         job = self._get_job(job_id)
@@ -2145,7 +2191,7 @@ class SchedulerServer:
         for part in locs:
             flat.extend(part)
         job.completed_locations = flat
-        job.status = "completed"
+        self._set_job_status(job, "completed")
         # the final stage has no StageFinished event (JobFinished fires
         # instead) — run its skew check here so the last stage's
         # partitions are monitored like every other stage's
@@ -2190,12 +2236,12 @@ class SchedulerServer:
         self.stage_manager.remove_job_stages(job_id)
         log.info("job %s completed (%d partitions)", job_id, len(flat))
 
+    @_ends_job
     def _on_job_failed(self, job_id: str, error: str) -> None:
         job = self._get_job(job_id)
         if job is None:
             return
-        job.status = "failed"
-        job.error = error
+        self._set_job_status(job, "failed", error)
         job.stage_stats = self.stage_manager.job_stage_detail(job_id)
         self._close_job_trace(job, "error")
         self._retain_job_obs(job)
@@ -2348,7 +2394,7 @@ class SchedulerServer:
         if tasks or not hold:
             return tasks
         with self._work_cv:
-            if self._stopping or self._held_polls >= MAX_HELD_POLLS:
+            if not self._may_hold():
                 return []
             self._held_polls += 1
         metrics.add("poll.holds")
@@ -2542,7 +2588,7 @@ class SchedulerServer:
         job.stages[stage.stage_id] = stage
         job.final_stage_id = stage.stage_id
         job.bypass = True
-        job.status = "running"
+        self._set_job_status(job, "running")
         plan_bytes = self.codec.physical_to_proto(
             stage.plan
         ).SerializeToString()
@@ -2657,6 +2703,7 @@ class SchedulerServer:
                     f"{tid.partition_id} failed: {error}",
                 )
 
+    @_ends_job
     def _finish_bypass_job(
         self, job: JobInfo, executor_id: str,
         metas: list[ShuffleWritePartitionMeta],
@@ -2682,7 +2729,7 @@ class SchedulerServer:
             for m in metas
         ]
         job.completed_locations = flat
-        job.status = "completed"
+        self._set_job_status(job, "completed")
         if job.submitted_s:
             import time as _time
 
@@ -3092,6 +3139,90 @@ class SchedulerServer:
             )
         return res
 
+    def _may_hold(self) -> bool:
+        """Whether one more call may be held (under the holds' lock)."""
+        return not self._stopping and (
+            self._held_polls + self._held_status < MAX_HELD_CALLS
+        )
+
+    def _set_job_status(
+        self, job: JobInfo, status: str, error: str = ""
+    ) -> None:
+        """The one place a job's status changes. An end (``completed``,
+        ``failed``) wakes every held status call, so what its answer is
+        made of (``completed_locations``, set by the caller; the error,
+        set here) is in place BEFORE the status is; ``running`` wakes
+        nobody. Where the end is still being recorded (``_job_ending``)
+        the woken calls wait on, for that to finish."""
+        if error:
+            job.error = error
+        job.status = status
+        if status in JOB_ENDED:
+            self.release_status_holds()
+
+    def release_status_holds(self) -> None:
+        """Every held status call looks again: a job ended, or an RPC
+        ended under its hold (gRPC's callback for it)."""
+        with self._status_cv:
+            self._status_cv.notify_all()
+
+    def job_status_held(
+        self, job_id: str, wait_s: float, still_wanted
+    ) -> pb.JobStatus:
+        """:meth:`job_status_proto`, and for a caller prepared to wait
+        ``wait_s`` seconds whose job has not ended: the answer once it
+        has, or the status as it is after ``wait_s`` or ``POLL_HOLD_S``
+        (the shorter), at shutdown, or when ``still_wanted()`` turns false
+        (the RPC was cancelled). A call the budget has no room for is
+        answered at once, and so is an unknown job. A job has ended, for a
+        held call, once its end is recorded (``_job_ending``): the history
+        a client reads next has its row."""
+        from ballista_tpu.compilecache import metrics
+
+        metrics.add("status.rpcs")
+        job = self._get_job(job_id)
+        if wait_s <= 0 or job is None:
+            return self.job_status_proto(job_id)
+
+        def ended() -> bool:
+            return job.status in JOB_ENDED and job_id not in self._ending
+
+        deadline = _time.monotonic() + min(wait_s, POLL_HOLD_S)
+        ended_by = None
+        # looked at under the lock that the setter and _job_ending notify
+        # under: an end between the look and the wait cannot be missed
+        with self._status_cv:
+            if ended() or self._stopping:
+                pass
+            elif not self._may_hold():
+                ended_by = "status.holds_over_budget"
+            else:
+                self._held_status += 1
+                metrics.add("status.holds")
+                try:
+                    while (
+                        not ended()
+                        and not self._stopping
+                        and still_wanted()
+                    ):
+                        left = deadline - _time.monotonic()
+                        if left <= 0:
+                            ended_by = "status.holds_timed_out"
+                            break
+                        self._status_cv.wait(left)
+                finally:
+                    self._held_status -= 1
+                if ended():
+                    ended_by = "status.holds_ended_by_status"
+            told = ended()
+        if ended_by is not None:
+            metrics.add(ended_by)
+        if told or job.status not in JOB_ENDED:
+            return self.job_status_proto(job_id)
+        # the hold ran out (or there was none) while the end was being
+        # recorded: not ended yet, for this caller; it asks again
+        return pb.JobStatus(running=pb.RunningJob())
+
     def job_status_proto(self, job_id: str) -> pb.JobStatus:
         job = self._get_job(job_id)
         if job is None:
@@ -3120,10 +3251,12 @@ class SchedulerServer:
         start/stop cycles in one process (tests assert a zero
         ``threading.enumerate()`` delta)."""
         with self._work_cv:
-            # held polls return empty at once (their gRPC workers must not
-            # outlive the server's stop)
+            # held polls return empty and held status calls the status as
+            # it is, at once (their gRPC workers must not outlive the
+            # server's stop)
             self._stopping = True
             self._work_cv.notify_all()
+            self._status_cv.notify_all()
         self._expiry_stop.set()
         self._expiry_thread.join(timeout=5)
         self.event_loop.stop()
@@ -3361,8 +3494,16 @@ class SchedulerGrpcServicer:
         return pb.ExecuteQueryResult(job_id=job_id, session_id=session_id)
 
     def GetJobStatus(self, request, context):
+        # a caller that says how long it will wait (wait_ms) is answered
+        # when its job ends, within POLL_HOLD_S; the RPC ending under the
+        # hold (the client gone) wakes and ends it
+        if request.wait_ms and context is not None:
+            context.add_callback(self.s.release_status_holds)
         return pb.GetJobStatusResult(
-            status=self.s.job_status_proto(request.job_id)
+            status=self.s.job_status_held(
+                request.job_id, request.wait_ms / 1e3,
+                context.is_active if context is not None else lambda: True,
+            )
         )
 
     def GetShuffleLocations(self, request, context):

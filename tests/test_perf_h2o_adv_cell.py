@@ -151,8 +151,8 @@ def test_a_traced_run_of_the_cell_reports_every_metric_it_is_held_to(tmp_path):
     held_to = {m["name"] for m in bench["per_layer"]
                if CELL in m.get("workloads", [CELL])
                and m["source"] != "device_trace" and m["layer"] != "device"}
-    assert {"holistic_tasks_per_query",
-            "holistic_rows_sorted_per_query"} <= held_to
+    assert {"holistic_tasks_per_query", "holistic_rows_sorted_per_query",
+            "status_polls_per_query"} <= held_to
     done = subprocess.run(
         [sys.executable, str(PERF / "run.py"), "--workload", CELL,
          "--seed", "3300000034", "--seconds", "1", "--trace", "1",
@@ -164,6 +164,7 @@ def test_a_traced_run_of_the_cell_reports_every_metric_it_is_held_to(tmp_path):
     line = json.loads(done.stdout.strip().splitlines()[-1])
     assert held_to - set(line["metrics"]) == set()
     assert line["metrics"]["holistic_tasks_per_query"]["value"] == 2
+    assert 1 <= line["metrics"]["status_polls_per_query"]["value"] < 10
 
 
 # -- planted faults -----------------------------------------------------------------

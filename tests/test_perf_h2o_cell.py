@@ -154,6 +154,33 @@ def test_the_cell_in_rehearsal_is_what_the_comparison_said(capsys, monkeypatch):
     assert sum(line.startswith("perf: stages: ") for line in err) == 1
 
 
+def test_a_traced_run_of_the_cell_reports_every_metric_it_is_held_to(tmp_path):
+    """As ``tests/test_perf_h2o_adv_cell.py`` does for its cell: the
+    ``--trace 1`` line of a rehearsal has every counter and host-clock
+    metric the cell is held to, ``status_polls_per_query`` (PR 34) and
+    ``dict_merge_ms_per_query`` (the string keys) among them."""
+    import os
+    import subprocess
+
+    bench = json.loads((ROOT / "BENCHMARK.json").read_text())
+    held_to = {m["name"] for m in bench["per_layer"]
+               if CELL in m.get("workloads", [CELL])
+               and m["source"] != "device_trace" and m["layer"] != "device"}
+    assert {"status_polls_per_query", "dict_merge_ms_per_query"} <= held_to
+    done = subprocess.run(
+        [sys.executable, str(PERF / "run.py"), "--workload", CELL,
+         "--seed", "3400000034", "--seconds", "1", "--trace", "1",
+         "--rehearse-sf", "0.01"],
+        cwd=ROOT, capture_output=True, text=True, timeout=280,
+        env={**os.environ, "JAX_PLATFORMS": "cpu",
+             "BALLISTA_TPU_HINT_CACHE": "off", "TMPDIR": str(tmp_path)})
+    assert done.returncode == 0, done.stderr[-2000:]
+    line = json.loads(done.stdout.strip().splitlines()[-1])
+    assert held_to - set(line["metrics"]) == set()
+    # every query is asked about at least once, and a held ask is one
+    assert 1 <= line["metrics"]["status_polls_per_query"]["value"] < 10
+
+
 # -- planted faults -----------------------------------------------------------------
 
 

@@ -469,11 +469,12 @@ def test_two_held_executors_each_get_their_grant(bounds):
 
 def test_held_polls_are_bounded_below_the_grpc_pool(bounds, monkeypatch):
     """A held poll occupies a worker of the scheduler's gRPC pool: past
-    the bound a poll is answered at once, as before the change."""
+    the bound (the one budget of held calls, test_status_hold.py) a poll
+    is answered at once, as before the change."""
     from ballista_tpu.scheduler import server as server_mod
 
-    assert 0 < server_mod.MAX_HELD_POLLS < server_mod.GRPC_WORKERS
-    monkeypatch.setattr(server_mod, "MAX_HELD_POLLS", 1)
+    assert 0 < server_mod.MAX_HELD_CALLS < server_mod.GRPC_WORKERS
+    monkeypatch.setattr(server_mod, "MAX_HELD_CALLS", 1)
     _, sched = _scheduler()
     try:
         first = Poll(sched, _request(executor_id="e1"))
