@@ -130,7 +130,7 @@ def _column_to_np(
         values = tuple(sorted_uniq.to_pylist())
         codes_arr = pc.index_in(col, sorted_uniq)
         codes = np.asarray(codes_arr.fill_null(0)).astype(np.int32)
-        return codes, null_mask, Dictionary(values)
+        return codes, null_mask, Dictionary(values, arrow=sorted_uniq)
 
     if pa.types.is_decimal(col.type) or pa.types.is_floating(col.type):
         arr = np.asarray(col.cast(pa.float64() if dtype == DataType.FLOAT64 else pa.float32()).fill_null(0))

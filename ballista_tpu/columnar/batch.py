@@ -19,6 +19,7 @@ with a representation XLA can tile onto the MXU/VPU.
 
 from __future__ import annotations
 
+import bisect
 import dataclasses
 from typing import Mapping, Sequence
 
@@ -160,20 +161,70 @@ def round_capacity(n: int) -> int:
     return _LADDER.round(n)
 
 
-@dataclasses.dataclass(frozen=True)
 class Dictionary:
-    """Host-side dictionary for a STRING column: code i <-> values[i]."""
+    """Host-side dictionary for a STRING column: code i <-> values[i].
+    Immutable, and sorted (``columnar/dict_util.py``: codes compare as
+    their strings do).
 
-    values: tuple[str, ...]
+    A dictionary is static aux data of every ``DeviceBatch`` that carries
+    it, so each jit dispatch hashes it and compares it with the one the
+    program was traced for. Both are O(1) for the same object: the hash is
+    computed once (O(n)) and kept; two dictionaries are equal exactly when
+    their values are, and only a comparison of two distinct objects with
+    equal hashes walks them.
+
+    What is computed from the entries alone lives and dies with the
+    dictionary: its Arrow array and the tables of the string predicates
+    evaluated over it (``dict_util.predicate_table``)."""
+
+    __slots__ = ("values", "_hash", "_arrow", "_tables")
+
+    def __init__(self, values: tuple[str, ...], arrow=None) -> None:
+        """``arrow``: the same entries as an Arrow string array, where the
+        caller made the values from one."""
+        self.values = values
+        self._hash: int | None = None
+        self._arrow = arrow
+        # predicate key -> what it gives for every entry; unbounded by
+        # design: its keys are the literals of the queries this dictionary
+        # met, and it goes with the dictionary
+        self._tables: dict = {}
 
     def index_of(self, s: str) -> int:
-        try:
-            return self.values.index(s)
-        except ValueError:
-            return -1
+        """The code of ``s``, or -1: a bisection, the entries being sorted."""
+        i = bisect.bisect_left(self.values, s)
+        if i < len(self.values) and self.values[i] == s:
+            return i
+        return -1
+
+    def arrow(self):
+        """The entries as an Arrow string array, made once."""
+        if self._arrow is None:
+            import pyarrow as pa
+
+            self._arrow = pa.array(self.values, type=pa.string())
+        return self._arrow
 
     def __len__(self) -> int:
         return len(self.values)
+
+    def __hash__(self) -> int:
+        if self._hash is None:
+            self._hash = hash(self.values)
+        return self._hash
+
+    def __eq__(self, other) -> bool:
+        if self is other:
+            return True
+        if not isinstance(other, Dictionary):
+            return NotImplemented
+        return hash(self) == hash(other) and self.values == other.values
+
+    def __repr__(self) -> str:
+        return f"Dictionary({len(self.values)} entries)"
+
+    def __reduce__(self):
+        return (Dictionary, (self.values,))
 
 
 @jax.tree_util.register_pytree_node_class

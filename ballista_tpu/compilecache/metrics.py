@@ -32,6 +32,16 @@ telemetry uses) and keeps process-global counters:
   — the window and percentile operators (docs/observability.md): tasks
   that ran one, the live rows of every sort they dispatched, the argsort
   passes of those sorts. Declared at 0 likewise.
+- ``dict_predicate.entries`` / ``dict_predicate.reused`` — string predicates
+  over dictionaries (``columnar/dict_util.py predicate_table``): entries
+  walked by the evaluations of ``LIKE``, ``substr`` and string ``IN``
+  tables, and tables taken from the dictionary's memo instead. Declared at
+  0 likewise.
+- ``join.noninner.tasks`` / ``join.noninner.probe_rows`` /
+  ``join.noninner.unmatched_rows`` — ``LEFT``, ``SEMI`` and ``ANTI`` joins
+  (``exec/joins.py``): tasks that ran one, the live rows of their preserved
+  side, and those an outer or anti join emitted without a match. Declared
+  at 0 likewise.
 - ``poll.rpcs`` / ``poll.wakes_by_status`` (executor) and ``poll.holds`` /
   ``poll.holds_granted`` / ``poll.holds_timed_out`` (scheduler) — the pull
   loop's hand-off (docs/observability.md): ``PollWork`` calls sent, waits
@@ -70,6 +80,15 @@ AGG_COUNTERS = (
 HOLISTIC_COUNTERS = (
     "holistic.tasks", "holistic.rows_sorted", "holistic.sort_passes",
 )
+# string predicates over dictionaries (columnar/dict_util.py): entries
+# evaluated, and tables reused from a dictionary's memo
+DICT_PREDICATE_COUNTERS = ("dict_predicate.entries", "dict_predicate.reused")
+# the joins that preserve a side (exec/joins.py LEFT, SEMI, ANTI), summed
+# from their metrics as a task ends
+NONINNER_JOIN_COUNTERS = (
+    "join.noninner.tasks", "join.noninner.probe_rows",
+    "join.noninner.unmatched_rows",
+)
 # the pull loop's hand-off (docs/serving.md): polls sent and waits ended by
 # a finished task (executor); polls held, and how a hold ended (scheduler)
 POLL_COUNTERS = (
@@ -89,7 +108,8 @@ HINT_COUNTERS = (
     "hints.entries_job_scoped_skipped", "phase.executor.hints_write.seconds",
 )
 _COUNTERS: dict[str, float] = dict.fromkeys(
-    AGG_COUNTERS + HOLISTIC_COUNTERS + POLL_COUNTERS + STATUS_COUNTERS
+    AGG_COUNTERS + HOLISTIC_COUNTERS + DICT_PREDICATE_COUNTERS
+    + NONINNER_JOIN_COUNTERS + POLL_COUNTERS + STATUS_COUNTERS
     + HINT_COUNTERS, 0
 )
 _INSTALLED = False

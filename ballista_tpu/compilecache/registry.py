@@ -63,6 +63,9 @@ VOCABULARY: dict[str, KernelSpec] = {
     "ops.perm.sort_f64_keys": KernelSpec(
         None, "a float64 sort key as two int32 keys (TPU), per capacity"
     ),
+    "ops.perm.sort_i64_keys": KernelSpec(
+        None, "an int64 sort key as two int32 keys (TPU), per capacity"
+    ),
     "ops.perm.holistic_sort_pass": KernelSpec(
         None, "a window/percentile sort pass (gather, argsort, gather) "
         "per (dtype, capacity): reached at the capacity of a whole input"
@@ -175,6 +178,9 @@ VOCABULARY: dict[str, KernelSpec] = {
     "exec.joins.join_semi_mask": KernelSpec(
         None, "semi/anti mask from match counts (keys, kind)"
     ),
+    "exec.joins.join_noninner_counts": KernelSpec(
+        None, "probe and unmatched rows of a LEFT/SEMI/ANTI probe batch"
+    ),
     "exec.joins.join_expand": KernelSpec(
         None, "expansion-join body (filter expr, kind, output capacity)"
     ),
@@ -200,7 +206,7 @@ VOCABULARY: dict[str, KernelSpec] = {
 # surface) or a mapping naming an unknown kernel (mappings cannot rot).
 _PERM = (
     "ops.perm.sort_argsort", "ops.perm.perm_take", "ops.perm.perm_take_batch",
-    "ops.perm.sort_f64_keys",
+    "ops.perm.sort_f64_keys", "ops.perm.sort_i64_keys",
     "ops.compact.compact_invalid", "ops.compact.compact_front_valid",
 )
 _HOLISTIC = ("ops.perm.holistic_sort_pass", "ops.perm.holistic_take")
@@ -228,6 +234,7 @@ _AGG = (
 _JOIN = (
     "exec.joins.join_probe", "exec.joins.join_probe_counts",
     "exec.joins.join_expand_total", "exec.joins.join_semi_mask",
+    "exec.joins.join_noninner_counts",
     "exec.joins.join_expand", "exec.joins.join_probe_filter",
     "ops.join._build_finish", "ops.join.join_build_prep",
     "ops.join.join_exact2_range", "ops.join.join_lut",

@@ -98,6 +98,29 @@ def _jit_expand_total(preserve_probe: bool):
     return jax.jit(join_expand_total)
 
 
+@functools.lru_cache(maxsize=None)
+def _jit_noninner_counts(kind: JoinSide, build_col: int | None):
+    """(live probe rows, preserved rows emitted without a match) of one
+    probe batch of a LEFT, SEMI or ANTI join: two device scalars for the
+    operator's metrics (``join.noninner.*``, docs/observability.md).
+    ``build_col`` is a build-side key column of a LEFT join's output, which
+    is NULL exactly in the rows that found no match."""
+
+    def join_noninner_counts(pb, out):
+        probe_rows = jnp.sum(pb.valid, dtype=jnp.int64)
+        if kind == JoinSide.ANTI:
+            unmatched = jnp.sum(out.valid, dtype=jnp.int64)
+        elif kind == JoinSide.LEFT and out.nulls[build_col] is not None:
+            unmatched = jnp.sum(
+                out.valid & out.nulls[build_col], dtype=jnp.int64
+            )
+        else:
+            unmatched = jnp.zeros((), jnp.int64)
+        return probe_rows, unmatched
+
+    return jax.jit(join_noninner_counts)
+
+
 class HashJoinExec(ExecutionPlan):
     def __init__(
         self,
@@ -482,11 +505,13 @@ class HashJoinExec(ExecutionPlan):
                 if kind in (JoinSide.INNER, JoinSide.SEMI):
                     continue
                 for pb in probe_batches():
-                    yield (
+                    out = (
                         pb
                         if kind == JoinSide.ANTI
                         else self._null_extend(pb)
                     )
+                    self._count_noninner(kind, pb, out, right_keys)
+                    yield out
                 continue
             with self.metrics.time("build_time"):
                 bb_parts: list[DeviceBatch] = []
@@ -513,9 +538,28 @@ class HashJoinExec(ExecutionPlan):
                 )
                 if kind in (JoinSide.INNER, JoinSide.LEFT):
                     out = self._restore_column_order(out, pb2, bt.batch, True)
+                self._count_noninner(kind, pb2, out, right_keys)
                 self.metrics.add("output_batches")
                 yield maybe_shrink(out, ctx, site, partition)
         pset.close()
+
+    def _count_noninner(
+        self, kind: JoinSide, pb: DeviceBatch, out: DeviceBatch,
+        right_keys: list[int],
+    ) -> None:
+        """One probe batch of a join that preserves its left side, into the
+        operator's metrics: the executor sums them into the
+        ``join.noninner.*`` counters as the task ends. The rows stay device
+        scalars until the task's metrics are read."""
+        if kind == JoinSide.INNER:
+            return
+        build_col = (
+            len(self.left.schema()) + right_keys[0]
+            if kind == JoinSide.LEFT else None
+        )
+        probe_rows, unmatched = _jit_noninner_counts(kind, build_col)(pb, out)
+        self.metrics.add("noninner_probe_rows", probe_rows)
+        self.metrics.add("noninner_unmatched_rows", unmatched)
 
     def _null_extend(self, pb: DeviceBatch) -> DeviceBatch:
         """LEFT-join rows for an empty build range: probe columns pass
@@ -572,6 +616,7 @@ class HashJoinExec(ExecutionPlan):
             if kind in (JoinSide.INNER, JoinSide.LEFT):
                 # probe++build == left++right; relabel to the plan schema
                 out = self._restore_column_order(out, pb, bt.batch, True)
+            self._count_noninner(kind, pb, out, right_keys)
             self.metrics.add("output_batches")
             # selective joins (q18's SEMI against a tiny HAVING set) leave
             # a near-empty batch at full probe capacity — re-bucket so the

@@ -446,6 +446,8 @@ class Executor:
             task.task_id.job_id, task.task_id.stage_id,
             task.task_id.partition_id, plan,
         )
+        from ballista_tpu.compilecache import metrics as compile_metrics
+
         # rows the task's final aggregates emitted, from the counters the
         # collector has just resolved: no device read of its own
         groups_out = sum(
@@ -455,8 +457,6 @@ class Executor:
             and "mode=final" in r["describe"]
         )
         if groups_out:
-            from ballista_tpu.compilecache import metrics as compile_metrics
-
             compile_metrics.add("agg.groups_out", groups_out)
         # likewise the sorts of the task's window and percentile operators
         holistic = [
@@ -465,14 +465,26 @@ class Executor:
             and "sort_passes" in r["counters"]
         ]
         if holistic:
-            from ballista_tpu.compilecache import metrics as compile_metrics
-
             compile_metrics.add_many(
                 [("holistic.tasks", 1)]
                 + [
                     (f"holistic.{name}", c[name])
                     for c in holistic
                     for name in ("rows_sorted", "sort_passes")
+                ]
+            )
+        # and the joins that preserved a side (LEFT, SEMI, ANTI)
+        noninner = [
+            r["counters"] for r in op_metrics or ()
+            if "noninner_probe_rows" in r["counters"]
+        ]
+        if noninner:
+            compile_metrics.add_many(
+                [("join.noninner.tasks", 1)]
+                + [
+                    (f"join.noninner.{name}", c[f"noninner_{name}"])
+                    for c in noninner
+                    for name in ("probe_rows", "unmatched_rows")
                 ]
             )
         # cost accounting (docs/observability.md): this attempt's

@@ -20,7 +20,6 @@ arithmetic propagate null as the OR of operand nulls.
 from __future__ import annotations
 
 import dataclasses
-import re
 
 import jax.numpy as jnp
 import numpy as np
@@ -551,12 +550,11 @@ def _compile_in_list(expr: L.InList, schema: Schema):
         if et == DataType.STRING:
             if v.dictionary is None:
                 raise PlanError("string IN without dictionary")
-            codes = [v.dictionary.index_of(s) for s in lits]
-            codes = [c for c in codes if c >= 0]
-            if not codes:
+            codes = dict_util.in_codes(v.dictionary, tuple(lits))
+            if not len(codes):
                 hit = jnp.zeros(v.values.shape, dtype=bool)
             else:
-                hit = jnp.isin(v.values, jnp.asarray(codes, dtype=jnp.int32))
+                hit = jnp.isin(v.values, jnp.asarray(codes))
         else:
             arr = np.asarray(lits, dtype=et.to_np())
             hit = jnp.isin(v.values, jnp.asarray(arr))
@@ -567,32 +565,16 @@ def _compile_in_list(expr: L.InList, schema: Schema):
     return fn
 
 
-def like_to_regex(pattern: str) -> "re.Pattern[str]":
-    """SQL LIKE pattern -> anchored regex (% = .*, _ = .)."""
-    out = []
-    for ch in pattern:
-        if ch == "%":
-            out.append(".*")
-        elif ch == "_":
-            out.append(".")
-        else:
-            out.append(re.escape(ch))
-    return re.compile("^" + "".join(out) + "$", re.DOTALL)
-
-
 def _compile_like(expr: L.Like, schema: Schema):
     if expr.expr.data_type(schema) != DataType.STRING:
         raise PlanError("LIKE on non-string column")
     f = _compile(expr.expr, schema)
-    rx = like_to_regex(expr.pattern)
 
     def fn(batch: DeviceBatch) -> ColumnValue:
         v = f(batch)
         if v.dictionary is None:
             raise PlanError("LIKE on string column without dictionary")
-        table = np.asarray(
-            [rx.match(s) is not None for s in v.dictionary.values], dtype=bool
-        )
+        table = dict_util.like_table(v.dictionary, expr.pattern)
         if expr.negated:
             table = ~table
         if len(table) == 0:
@@ -658,15 +640,9 @@ def _compile_scalar_fn(expr: L.ScalarFunction, schema: Schema):
             v = args[0](batch)
             if v.dictionary is None:
                 raise PlanError("substr on string column without dictionary")
-            cut = [
-                s[start - 1 :] if length is None else s[start - 1 : start - 1 + length]
-                for s in v.dictionary.values
-            ]
-            uniq = tuple(sorted(set(cut)))
-            pos = {s: i for i, s in enumerate(uniq)}
-            table = np.asarray([pos[s] for s in cut], dtype=np.int32)
+            table, uniq = dict_util.substr_table(v.dictionary, start, length)
             codes = dict_util.remap_codes(v.values, table)
-            return ColumnValue(codes, v.nulls, DataType.STRING, Dictionary(uniq))
+            return ColumnValue(codes, v.nulls, DataType.STRING, uniq)
 
         return fn
 

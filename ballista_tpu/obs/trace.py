@@ -349,6 +349,7 @@ PHASES = (
     "task.shuffle_write",
     "task.shuffle_fetch",
     "task.dict_merge",
+    "task.dict_predicate",
     "task.hints_save",
     "task.report",
 )

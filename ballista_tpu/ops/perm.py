@@ -208,14 +208,62 @@ def refine_perm(
     return take(perm, idx)
 
 
+@functools.lru_cache(maxsize=None)
+def _i64_keys_program(cap: int, descending: bool):
+    def sort_i64_keys(col):
+        c = ~col if descending else col
+        hi = (c >> 32).astype(jnp.int32)
+        # the low half orders as an unsigned number: its top bit flipped,
+        # the same bits order as an int32
+        lo = jax.lax.bitcast_convert_type(
+            c.astype(jnp.uint32) ^ jnp.uint32(0x80000000), jnp.int32
+        )
+        return hi, lo
+
+    return jax.jit(sort_i64_keys)
+
+
+def split_wide_ints(
+    passes: list[tuple[jnp.ndarray, bool]],
+) -> list[tuple[jnp.ndarray, bool]]:
+    """``passes`` (most significant first) with every int64 key as two int32
+    keys, on the TPU.
+
+    The chip has no int64 either: an int64 argsort program costs its
+    compiler 17-32 s at every capacity from 32,768 to 2M rows, 2 to 2.5
+    times the int32 program of that capacity, which the validity pass of
+    the same sort compiles anyway (PERF.md, PR 36: six such programs were
+    122 s of one cell's cold run). Join keys and group keys are int64 from
+    the first stage boundary on (a shuffle's partitions are read back at
+    their logical width, so that every partition has one), so nearly every
+    sort behind an exchange had one. The halves sort as two stable passes
+    through the int32 program, in the same order, ties included. The pass
+    this adds costs what the first pass of every sort no longer does
+    (``multi_key_perm``)."""
+    if jax.default_backend() == "cpu":
+        return passes
+    out = []
+    for col, desc in passes:
+        if col.dtype == jnp.int64:
+            hi, lo = _i64_keys_program(col.shape[0], desc)(col)
+            out += [(hi, False), (lo, False)]
+        else:
+            out.append((col, desc))
+    return out
+
+
 def multi_key_perm(
     passes: list[tuple[jnp.ndarray, bool]],
 ) -> jnp.ndarray:
     """Permutation sorting by ``passes`` in MOST-significant-first order.
-    Each pass is (column, descending). Executes least-significant first."""
-    cap = passes[0][0].shape[0]
-    perm = jnp.arange(cap, dtype=jnp.int32)
-    for col, desc in reversed(narrow_passes(passes)):
+    Each pass is (column, descending). Executes least-significant first.
+    The first pass sorts the rows where they lie: gathering its key by the
+    identity, and the identity by its result, would be two of the random
+    access passes that are most of a sort's device time (PERF.md §5)."""
+    todo = list(reversed(split_wide_ints(narrow_passes(passes))))
+    col, desc = todo[0]
+    perm = stable_argsort(col, desc)
+    for col, desc in todo[1:]:
         perm = refine_perm(perm, col, desc)
     return perm
 

@@ -510,6 +510,8 @@ def push_down_filters(plan: LogicalPlan) -> LogicalPlan:
     kids = [push_down_filters(c) for c in plan.children()]
     if kids:
         plan = plan.with_children(kids)
+    if isinstance(plan, Join) and plan.filter is not None:
+        return _push_on_conjuncts(plan)
     if not isinstance(plan, Filter):
         return plan
     conjuncts = _split_conjuncts(plan.predicate)
@@ -520,6 +522,47 @@ def push_down_filters(plan: LogicalPlan) -> LogicalPlan:
     if kept:
         return Filter(pushed, _conjoin(kept))
     return pushed
+
+
+def _push_on_conjuncts(plan: Join) -> LogicalPlan:
+    """Conjuncts of a join's ON clause that read one side only, pushed into
+    that side where the join's kind allows it. A side whose rows the join
+    does not preserve may lose, before the join sees them, the rows that
+    could never match: ``a LEFT JOIN b ON a.k = b.k AND b.c NOT LIKE ...``
+    (TPC-H q13) is the left join of ``a`` to the filtered ``b``, and ``b.c``
+    then crosses no stage boundary and rides through no join program. A
+    preserved side may not: its rows that fail the conjunct still come out,
+    unmatched."""
+    jt = plan.join_type
+    left_ok = jt in (JoinType.INNER, JoinType.RIGHT, JoinType.SEMI)
+    right_ok = jt in (
+        JoinType.INNER, JoinType.LEFT, JoinType.SEMI, JoinType.ANTI,
+    )
+    ls, rs = plan.left.schema(), plan.right.schema()
+    lq, rq = _qualifiers(plan.left), _qualifiers(plan.right)
+    left_push, right_push, kept = [], [], []
+    for c in _split_conjuncts(plan.filter):
+        cols = L.find_columns(c)
+        on_left = bool(cols) and all(_resolvable_on(ls, lq, n) for n in cols)
+        on_right = bool(cols) and all(_resolvable_on(rs, rq, n) for n in cols)
+        if left_ok and on_left and not on_right:
+            left_push.append(c)
+        elif right_ok and on_right and not on_left:
+            right_push.append(c)
+        else:
+            kept.append(c)
+    if not left_push and not right_push:
+        return plan
+    sides = []
+    for side, pushed in ((plan.left, left_push), (plan.right, right_push)):
+        if pushed:
+            side, not_pushed = _push_conjuncts(side, pushed)
+            if not_pushed:
+                side = Filter(side, _conjoin(not_pushed))
+        sides.append(side)
+    return Join(
+        sides[0], sides[1], plan.on, jt, _conjoin(kept) if kept else None
+    )
 
 
 def _push_conjuncts(
@@ -688,17 +731,32 @@ def _prune(plan: LogicalPlan, required: set[str] | None) -> LogicalPlan:
         ]
         needed = set(names) | _expr_columns(plan.filters)
         proj = tuple(f.name for f in plan.source_schema if f.name in needed)
-        if len(proj) == len(plan.source_schema):
-            return plan
         if not proj:
             proj = (plan.source_schema.fields[0].name,)
-        return TableScan(
-            plan.table_name, plan.source_schema, proj, plan.filters,
-            plan.source,
-        )
+        scan = plan
+        if len(proj) < len(plan.source_schema):
+            scan = TableScan(
+                plan.table_name, plan.source_schema, proj, plan.filters,
+                plan.source,
+            )
+        # a string column that only the scan's filters read goes once they
+        # have run: its dictionary (host strings, static data of every
+        # program a batch rides through, merged at every stage boundary)
+        # would otherwise travel as far as the next projection
+        filter_only = [
+            f for f in plan.source_schema
+            if f.name in proj and f.name not in names
+        ]
+        if names and any(f.dtype == DataType.STRING for f in filter_only):
+            return Projection(scan, tuple(L.Column(n) for n in names))
+        return scan
     if isinstance(plan, Projection):
         need = _expr_columns(plan.exprs)
-        return Projection(_prune(plan.input, need), plan.exprs)
+        inner = _prune(plan.input, need)
+        if isinstance(plan.input, TableScan) and isinstance(inner, Projection):
+            # this projection already drops what only the filters read
+            inner = inner.input
+        return Projection(inner, plan.exprs)
     if isinstance(plan, Filter):
         need = None if required is None else required | _expr_columns([plan.predicate])
         return Filter(_prune(plan.input, need), plan.predicate)

@@ -90,6 +90,59 @@ def test_sort_desc_string_and_int_min():
     pd.testing.assert_frame_equal(out.reset_index(drop=True), expect)
 
 
+@pytest.mark.parametrize("descending", [False, True])
+def test_an_int64_key_sorts_as_two_int32_keys(descending, monkeypatch):
+    """``ops/perm.py split_wide_ints`` (the TPU's path, steered here): the
+    halves of an int64 key, sorted as two stable passes, give the order and
+    the ties of the one-pass sort, extremes and negatives included."""
+    import jax
+
+    from ballista_tpu.ops import perm as P
+
+    rng = np.random.default_rng(36)
+    lim = np.iinfo(np.int64)
+    vals = np.concatenate([
+        rng.integers(lim.min, lim.max, 3000, dtype=np.int64),
+        rng.integers(-5, 5, 500),  # ties, around zero
+        rng.integers(2**32 - 3, 2**32 + 3, 200),  # across the halves
+        [lim.min, lim.max, 0, -1, 2**31, -(2**31), 2**32, -(2**32)] * 4,
+    ])
+    rng.shuffle(vals)
+    col = jnp.asarray(np.pad(vals, (0, 4096 - len(vals))))
+    flag = jnp.asarray(np.arange(4096) >= len(vals))  # padding last
+    passes = [(flag, False), (col, descending)]
+    assert P.split_wide_ints(passes) is passes  # the CPU keeps one pass
+    with monkeypatch.context() as on_the_chip:
+        on_the_chip.setattr(jax, "default_backend", lambda: "tpu")
+        split = P.split_wide_ints(passes)
+    assert [str(c.dtype) for c, _ in split] == ["bool", "int32", "int32"]
+    got = np.asarray(P.multi_key_perm(split))
+    want = np.asarray(P.multi_key_perm(passes))
+    assert np.array_equal(got, want)
+    key = -vals.astype(object) if descending else vals.astype(object)
+    order = sorted(range(len(vals)), key=lambda i: (key[i], i))
+    assert got[: len(vals)].tolist() == order
+
+
+@pytest.mark.parametrize("keys", [1, 2, 3])
+def test_the_first_pass_of_a_sort_takes_no_gather(keys, monkeypatch):
+    """A sort of k passes is k argsorts and 2(k - 1) gathers: the first pass
+    sorts the rows where they lie."""
+    from ballista_tpu.ops import perm as P
+
+    taken = []
+    take = P.take
+    monkeypatch.setattr(P, "take", lambda c, p: taken.append(1) or take(c, p))
+    rng = np.random.default_rng(keys)
+    cols = [rng.integers(0, 4, 2048).astype(np.int32) for _ in range(keys)]
+    perm = np.asarray(P.multi_key_perm([(jnp.asarray(c), i == 1)
+                                        for i, c in enumerate(cols)]))
+    assert len(taken) == 2 * (keys - 1)
+    by = [(-c if i == 1 else c) for i, c in enumerate(cols)]
+    want = sorted(range(2048), key=lambda r: tuple(b[r] for b in by) + (r,))
+    assert perm.tolist() == want
+
+
 def test_group_aggregate_matches_pandas(sample_table):
     b = _batch(sample_table)
     schema = b.schema

@@ -37,7 +37,7 @@ def _delta(before: dict, after: dict) -> dict:
 
 
 def test_phases_are_a_closed_list():
-    assert len(set(obs_trace.PHASES)) == len(obs_trace.PHASES) == 18
+    assert len(set(obs_trace.PHASES)) == len(obs_trace.PHASES) == 19
     with pytest.raises(ValueError, match="not in obs.trace.PHASES"):
         obs_trace.phase("task.something_new")
 
@@ -243,7 +243,7 @@ ctx = BallistaContext.standalone(cfg, concurrent_tasks=4)
 for name, table in tpch.gen_all(0.002, 7).items():
     ctx.register_table(name, table)
 sched = ctx._standalone_cluster.scheduler
-sql = {q: open(f"benchmarks/queries/{q}.sql").read() for q in ("q1", "q6", "q3")}
+sql = {q: open(f"benchmarks/queries/{q}.sql").read() for q in ("q1", "q6", "q3", "q13")}
 rounds = []
 for _ in range(3):  # the first compiles and learns; two more repeat
     before = phases()
@@ -280,14 +280,15 @@ def served(tmp_path_factory):
                 if ln.startswith("RESULT "))
     out = json.loads(line[len("RESULT "):])
     assert [r["rows"] for r in out["rounds"]] == [
-        {"q1": 4, "q6": 1, "q3": 10}] * 3
+        {"q1": 4, "q6": 1, "q3": 10, "q13": 19}] * 3
     return out
 
 
 @pytest.mark.parametrize("name", obs_trace.PHASES)
 def test_served_path_reaches_every_phase(served, name):
-    """Standalone, pull-staged, three templates: client, scheduler, poll
-    loop, scan, both transfers, shuffle both ways, hints and report."""
+    """Standalone, pull-staged, four templates: client, scheduler, poll
+    loop, scan, both transfers, shuffle both ways, hints and report; q13
+    for the string predicate over a dictionary."""
     assert served["counters"].get(f"phase.{name}.count", 0) > 0
     assert served["counters"].get(f"phase.{name}.seconds", 0) >= 0
 

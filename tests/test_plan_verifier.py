@@ -212,6 +212,11 @@ def test_mutation_stage_schema_drift(tpch_ctx):
     mutated = False
     for stage in stages:
         for u in find_unresolved_shuffles(stage.plan):
+            if len(u._schema.fields) < 2:
+                # customer's exchange carries its key alone (the segment
+                # goes at the scan): without it the join's key check
+                # would speak first
+                continue
             u._schema = Schema(list(u._schema.fields)[:-1])
             mutated = True
             break

@@ -119,6 +119,18 @@ def test_argsort_pass_program(S, dtype):
     assert " sort(" in _compile(prog, S((8192,), dtype))
 
 
+@pytest.mark.parametrize("descending", [False, True])
+def test_int64_key_halves_program_at_a_join_build_capacity(S, descending):
+    """``ops/perm.py split_wide_ints``: the elementwise program that makes
+    two int32 keys of an int64 key, at the 2M rows of q4's join build. It
+    holds no sort: the halves ride the int32 argsort program."""
+    from ballista_tpu.ops import perm
+
+    n = 1 << 21
+    text = _compile(perm._i64_keys_program(n, descending), S((n,), "int64"))
+    assert " sort(" not in text
+
+
 def test_stacked_gather_and_scatter_at_lineitem_capacity(S):
     from ballista_tpu.ops import aggregate as A
     from ballista_tpu.ops import perm
