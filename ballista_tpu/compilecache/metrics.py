@@ -24,9 +24,11 @@ telemetry uses) and keeps process-global counters:
   the served path (``obs.trace.phase``, docs/observability.md), and for
   ``task.d2h`` the same three per call site (``...:<site>``).
 - ``agg.capacity_retries`` / ``agg.sort_passes`` / ``agg.dense_passes`` /
-  ``agg.groups_out`` — the grouped aggregate (docs/observability.md):
-  tasks run again after a ``CapacityError``, device passes dispatched by
-  kind, rows the final aggregates emitted. Declared at 0, so that a
+  ``agg.dense_factored_passes`` / ``agg.groups_out`` — the grouped
+  aggregate (docs/observability.md): tasks run again after a
+  ``CapacityError``, device passes dispatched by kind (and of the dense
+  ones, those whose counts and integer sums took the factorized one-hot),
+  rows the final aggregates emitted. Declared at 0, so that a
   reader tells "none" from "a program without the counter".
 - ``holistic.tasks`` / ``holistic.rows_sorted`` / ``holistic.sort_passes``
   — the window and percentile operators (docs/observability.md): tasks
@@ -73,7 +75,7 @@ import threading
 _LOCK = threading.Lock()
 AGG_COUNTERS = (
     "agg.capacity_retries", "agg.sort_passes", "agg.dense_passes",
-    "agg.groups_out",
+    "agg.dense_factored_passes", "agg.groups_out",
 )
 # the operators that need every row of a group in one place (exec/window.py,
 # exec/percentile.py), summed from their metrics as a task ends

@@ -33,7 +33,9 @@ from ballista_tpu.expr.physical import compile_expr
 from ballista_tpu.ops.aggregate import (
     DENSE_AGG_MAX_SLOTS,
     AggOp,
+    dense_factored,
     dense_group_aggregate,
+    dense_slots,
     group_aggregate,
     scalar_aggregate,
 )
@@ -859,13 +861,14 @@ class HashAggregateExec(ExecutionPlan):
         # capacity was grown for a big merge
         cap = min(cap, max(batch.capacity, 16))
         # dictionary-coded / boolean keys with a small domain take the dense
-        # (sort-free, one-fused-scatter) kernel — the q1 shape
+        # (sort-free, one fused program) kernel — the q1 shape
         vocab = self._dense_vocab(batch, n_groups)
         # exact decimal summation (sort path only): money/quantity columns
         # sum as scaled int64 (order-independent, bit-exact across tiers);
-        # sums divide back below. The dense kernel keeps f64 — int64 values
-        # would force it onto the serialized scatter path, and its f32-split
-        # matmul is deliberately approximate (~2e-8, ops/pallas_agg.py).
+        # sums divide back below. The dense kernel keeps f64 — up to
+        # 2048 slots int64 sums take a scatter where f64 sums ride the
+        # one-hot matmul, and its f32-split Pallas matmul is deliberately
+        # approximate (~2e-8, ops/pallas_agg.py).
         if vocab is None:
             val_cols, dec_unscale = self._dec_scaled_sums(
                 val_cols, val_nulls, ops, batch, ctx, site, from_state
@@ -874,6 +877,8 @@ class HashAggregateExec(ExecutionPlan):
             dec_unscale = [None] * len(val_cols)
         if vocab is not None:
             compile_metrics.add("agg.dense_passes")
+            if dense_factored(dense_slots(vocab)):
+                compile_metrics.add("agg.dense_factored_passes")
             res = dense_group_aggregate(
                 key_cols, key_nulls, vocab, batch.valid, val_cols,
                 val_nulls, list(ops),

@@ -19,6 +19,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+import math
 from enum import Enum
 
 import jax
@@ -168,36 +169,165 @@ _MATMUL_MAX_ONEHOT_BYTES = 512 << 20
 # XLA f64 path is cheap anyway.
 _PALLAS_MIN_ROWS = 1 << 20
 
+# Past _MATMUL_MAX_SLOTS a dense pass reduces its counts and integer sums
+# through a factorized one-hot (``_factored_sums``), never by scatter. It
+# did not lose to the int64 scatter-add at any size measured on a v5e (PR
+# 37, PERF.md §6: ``_dense_agg`` jitted, one int64 SUM; scatter against
+# factorized, ms): 10,201 slots at 2,097,152 rows 401.8 / 13.1, at 16,384
+# rows 4.27 / 1.07, at 1,024 1.23 / 1.00, at 256 0.97 / 0.98; 50,001 slots
+# (three sums, one float64 still by scatter) at 262,144 rows 73.6 / 39.7,
+# at 1,024 1.72 / 1.47.
+# Rows of one factorized chunk: its partials stay exact in f32 (below 2^24
+# at 255 a row), and its (rows, K x P2) right operand near this many bytes.
+_FACTORED_MAX_CHUNK = 1 << 16
+_FACTORED_CHUNK_BYTES = 16 << 20
+
+
+def dense_factored(capacity: int) -> bool:
+    """Whether a dense pass into ``capacity`` slots reduces its counts and
+    integer sums through the factorized one-hot (``exec/aggregate.py``
+    counts such passes)."""
+    return capacity > _MATMUL_MAX_SLOTS
+
+
+def _factored_layout(capacity: int, n: int, K: int) -> tuple[int, int, int]:
+    """``(P1, P2, chunk)`` of ``_factored_sums``: slots as a P1 x P2 grid
+    with P2 = ceil(sqrt(capacity)), and the rows of one chunk for ``n``
+    rows and K limb columns, a power of two where it is below ``n`` (a
+    power of two divides a batch's capacity: no padded copy)."""
+    P2 = math.isqrt(capacity - 1) + 1
+    P1 = -(-capacity // P2)
+    chunk = max(512, _FACTORED_CHUNK_BYTES // (2 * P2 * K))
+    chunk = min(n, _FACTORED_MAX_CHUNK, 1 << (chunk.bit_length() - 1))
+    return P1, P2, chunk
+
+
+def _limb_shifts(src_dtype) -> list[int]:
+    """The shifts of ``_byte_limbs``: one limb a byte of the source width
+    (an int64 for any other integer), one for a bool."""
+    src = jnp.dtype(src_dtype)
+    if src == jnp.bool_:
+        return [0]
+    width = src.itemsize if jnp.issubdtype(src, jnp.signedinteger) else 8
+    return [8 * b for b in range(width)]
+
+
+def _byte_limbs(x, src_dtype) -> list:
+    """``x`` (an int64 contribution of a ``src_dtype`` column) as limbs with
+    ``x == sum(limb << shift)`` over ``_limb_shifts`` exactly: unsigned
+    bytes below a signed top byte, so every limb lies in [-128, 255], exact
+    in bfloat16."""
+    shifts = _limb_shifts(src_dtype)
+    return [(x >> s) & 255 for s in shifts[:-1]] + [x >> shifts[-1]]
+
+
+def _factored_sums(rid, capacity: int, flags: list, ints: list):
+    """Slot sums through the MXU, exact: the counts of the bool columns
+    ``flags`` ((capacity, len(flags)) int64) and the sums of the int64
+    columns of ``ints`` ((contribution, source dtype) pairs; (capacity,
+    len(ints)) int64, wrapping as a scatter-add does).
+
+    With ``hi = rid // P2`` and ``lo = rid % P2``, ``S[hi, lo] = sum_n
+    onehot_hi[n, hi] * (x_n * onehot_lo[n, lo])`` is one (P1, n) x (n, P2 *
+    K) contraction per row chunk over the K limb columns (a flag is one, an
+    int its ``_byte_limbs``): 0/1 one-hots and limbs in [-128, 255] are
+    exact in bfloat16 and a chunk's f32 partials exact under 2^24; chunks
+    add up in int64 and the limbs recombine by their shifts. Rows with
+    ``rid == capacity`` meet no slot."""
+    n = rid.shape[0]
+    shifts = [[0] for _ in flags] + [_limb_shifts(src) for _, src in ints]
+    K = sum(len(sh) for sh in shifts)
+    P1, P2, chunk = _factored_layout(capacity, n, K)
+    nb = -(-n // chunk)
+    pad = nb * chunk - n
+    hi = jnp.where(rid < capacity, rid // P2, P1).astype(jnp.int32)
+    lo = (rid % P2).astype(jnp.int32)
+    xs = [hi, lo, jnp.stack(flags, axis=1)]
+    if ints:
+        xs.append(jnp.stack([x for x, _ in ints], axis=1))
+    # padded rows hold zeros in every limb: they add nothing where they land
+    xs = [
+        jnp.pad(x, [(0, pad)] + [(0, 0)] * (x.ndim - 1)).reshape(
+            (nb, chunk) + x.shape[1:]
+        )
+        for x in xs
+    ]
+    iota1 = jax.lax.broadcasted_iota(jnp.int32, (chunk, P1), 1)
+    iota2 = jax.lax.broadcasted_iota(jnp.int32, (chunk, 1, P2), 2)
+
+    def body(acc, xs_c):
+        hi_c, lo_c, flags_c, *ints_c = xs_c
+        limbs = [flags_c[:, j] for j in range(len(flags))]
+        for j, (_, src) in enumerate(ints):
+            limbs += _byte_limbs(ints_c[0][:, j], src)
+        mat = jnp.stack([l.astype(jnp.bfloat16) for l in limbs], axis=1)
+        oh_hi = (hi_c[:, None] == iota1).astype(jnp.bfloat16)
+        rhs = jnp.where(
+            lo_c[:, None, None] == iota2, mat[:, :, None],
+            jnp.zeros((), jnp.bfloat16),
+        ).reshape(chunk, K * P2)
+        part = jax.lax.dot_general(
+            oh_hi, rhs, (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )
+        return acc + part.astype(jnp.int64), None
+
+    acc, _ = jax.lax.scan(body, jnp.zeros((P1, K * P2), jnp.int64), xs)
+    sums = acc.reshape(P1, K, P2).transpose(0, 2, 1).reshape(P1 * P2, K)
+    sums = sums[:capacity]
+    out, k = [], 0
+    for sh in shifts:
+        # int64 wraps as the scatter-add this replaces does
+        out.append(functools.reduce(
+            jnp.add, [sums[:, k + j] << s for j, s in enumerate(sh)]
+        ))
+        k += len(sh)
+    counts = jnp.stack(out[: len(flags)], axis=1)
+    int_sums = jnp.stack(out[len(flags):], axis=1) if ints else None
+    return counts, int_sums
+
 
 def _stacked_reduce(
     rid, capacity: int, vals: list, lives: list, ops: tuple
-) -> tuple[list, list]:
-    """All value reductions with ONE scatter per (reduction kind, dtype).
+) -> tuple[list, list, jnp.ndarray]:
+    """All value reductions with ONE reduction per (kind, dtype), and the
+    rows each slot holds.
 
     ``rid`` is the common slot index (``capacity`` = dropped); per-column
     NULL masks are folded into the *contribution* instead of the index
     (SUM adds 0, MIN/MAX add their identity, COUNT adds 0) so every column
-    shares the same scatter. The non-null count matrix doubles as COUNT
-    output and the SQL all-NULL flags.
+    shares the same reduction. A live mask of ``None`` is every row with a
+    slot. The non-null count matrix doubles as COUNT output and the SQL
+    all-NULL flags; its first column counts the rows with a slot, whose
+    ``> 0`` is the slot's occupancy (returned third).
 
     Small slot counts (the dense dictionary-key path — TPC-H q1 has 12)
     route f64 sums and the count matrix over the MXU instead: a one-hot
     (P, n) f64 matmul is ~2x the speed of even the stacked scatter on a
     v5e (measured 45ms vs 100ms net for 1M rows x 8 columns). Counts are
     exact through f64 (< 2^53); int64 sums keep the scatter (their sums
-    may exceed f64's exact-integer range)."""
+    may exceed f64's exact-integer range). Past ``_MATMUL_MAX_SLOTS`` the
+    counts and integer sums take the factorized one-hot (int sums as byte
+    limbs, recombined in int64: bit-identical to the scatter-add,
+    wraparound included); f64 sums and MIN/MAX keep their scatter there."""
     m = len(vals)
     out_vals: list = [None] * m
     out_val_nulls: list = [None] * m
-    if m == 0:
-        return out_vals, out_val_nulls
     n = rid.shape[0]
     use_mm = capacity <= _MATMUL_MAX_SLOTS
+    use_factored = dense_factored(capacity)
     use_pallas = False
     if use_mm and n >= _PALLAS_MIN_ROWS:
         from ballista_tpu.ops import pallas_agg
 
         use_pallas = pallas_agg.available()
+    # count columns: the rows with a slot, then each live mask of its own
+    cnt_cols = [rid < capacity]
+    live_idx = []
+    for l in lives:
+        live_idx.append(0 if l is None else len(cnt_cols))
+        if l is not None:
+            cnt_cols.append(l)
 
     # chunk so the materialized (capacity, chunk) f64 one-hot stays within
     # budget; rows beyond n (chunk padding) and dropped rows (rid ==
@@ -242,40 +372,39 @@ def _stacked_reduce(
     add_groups: dict[str, list] = {}
     min_groups: dict[str, list] = {}
     max_groups: dict[str, list] = {}
+    int_sums: list = []  # (column, contribution, source dtype): factorized
+    nc = len(cnt_cols)
     if use_pallas:
         # ONE kernel call covers the count matrix and every f64 sum: live
         # flags ride as f32 0/1 rows (counts stay exact — see module note
         # in pallas_agg), f64 contributions as exact (hi, lo) f32 pairs.
         from ballista_tpu.ops import pallas_agg
 
-        rows = [l.astype(jnp.float32) for l in lives]
+        rows = [c.astype(jnp.float32) for c in cnt_cols]
         f64_cols: list[int] = []
         contribs_f64: dict[int, jnp.ndarray] = {}
-        nonnull = None  # filled after the single kernel call below
+        counts = None  # filled after the single kernel call below
     elif use_mm:
-        cnt_mat = jnp.stack([l.astype(jnp.float64) for l in lives], axis=1)
-        nonnull = _mm(cnt_mat).astype(jnp.int64)
-    else:
-        cnt_mat = jnp.stack([l.astype(jnp.int64) for l in lives], axis=1)
-        nonnull = jnp.zeros((capacity, m), dtype=jnp.int64).at[rid].add(
-            cnt_mat, mode="drop"
-        )
-    for i, (vc, live, op) in enumerate(zip(vals, lives, ops)):
+        cnt_mat = jnp.stack([c.astype(jnp.float64) for c in cnt_cols], axis=1)
+        counts = _mm(cnt_mat).astype(jnp.int64)
+    for i, (vc, op) in enumerate(zip(vals, ops)):
+        live = cnt_cols[live_idx[i]]
         if op == AggOp.COUNT:
             continue
         if op == AggOp.SUM:
-            acc_t = _sum_dtype(vc.dtype)
+            acc_t = jnp.dtype(_sum_dtype(vc.dtype))
             contrib = jnp.where(live, vc, jnp.zeros_like(vc)).astype(acc_t)
-            if use_pallas and jnp.dtype(acc_t) == jnp.float64:
+            if use_pallas and acc_t == jnp.float64:
                 hi, lo = pallas_agg.split_hi_lo(contrib)
                 rows.append(hi)
                 rows.append(lo)
                 f64_cols.append(i)
                 contribs_f64[i] = contrib
                 continue
-            add_groups.setdefault(
-                str(jnp.dtype(acc_t)), []
-            ).append((i, contrib))
+            if use_factored and acc_t == jnp.int64:
+                int_sums.append((i, contrib, vc.dtype))
+                continue
+            add_groups.setdefault(str(acc_t), []).append((i, contrib))
         elif op == AggOp.MIN:
             masked = jnp.where(live, vc, _max_ident(vc.dtype))
             min_groups.setdefault(str(vc.dtype), []).append((i, masked))
@@ -284,9 +413,15 @@ def _stacked_reduce(
             max_groups.setdefault(str(vc.dtype), []).append((i, masked))
         else:  # pragma: no cover
             raise ExecutionError(f"unknown agg op {op}")
+    if use_factored:
+        counts, int_out = _factored_sums(
+            rid, capacity, cnt_cols, [(x, src) for _, x, src in int_sums]
+        )
+        for j, (i, _, _) in enumerate(int_sums):
+            out_vals[i] = int_out[:, j]
     if use_pallas:
         sums = pallas_agg.onehot_sums(rid, rows, capacity)
-        nonnull = jnp.round(sums[:, :m]).astype(jnp.int64)
+        counts = jnp.round(sums[:, :nc]).astype(jnp.int64)
         if f64_cols:
             # The kernel accumulates in f32: a value beyond ~1e30 (or a
             # NaN/Inf input) would overflow hi/lo or poison every slot of
@@ -301,7 +436,7 @@ def _stacked_reduce(
             ))) < 1e30
             pallas_sums = jnp.stack(
                 [
-                    sums[:, m + 2 * j] + sums[:, m + 2 * j + 1]
+                    sums[:, nc + 2 * j] + sums[:, nc + 2 * j + 1]
                     for j in range(len(f64_cols))
                 ],
                 axis=1,
@@ -314,10 +449,11 @@ def _stacked_reduce(
             for j, i in enumerate(f64_cols):
                 out_vals[i] = safe[:, j]
     for i, op in enumerate(ops):
+        nonnull = counts[:, live_idx[i]]
         if op == AggOp.COUNT:
-            out_vals[i] = nonnull[:, i]
+            out_vals[i] = nonnull
         else:
-            out_val_nulls[i] = nonnull[:, i] == 0  # agg over no values: NULL
+            out_val_nulls[i] = nonnull == 0  # agg over no values: NULL
     for groups, kind in (
         (add_groups, "add"), (min_groups, "min"), (max_groups, "max")
     ):
@@ -340,7 +476,7 @@ def _stacked_reduce(
                 res = init.at[rid].max(stacked, mode="drop")
             for j, (i, _) in enumerate(entries):
                 out_vals[i] = res[:, j]
-    return out_vals, out_val_nulls
+    return out_vals, out_val_nulls, counts[:, 0] > 0
 
 
 # -- segment-reduction finisher -----------------------------------------------
@@ -969,15 +1105,14 @@ def _dense_agg(
     """Dense grouped aggregation for dictionary-coded / small-domain keys:
     the group slot is the mixed-radix index over (vocab+1) values per key
     (the +1 slot is NULL — SQL groups NULLs together), and every reduction
-    is ONE scatter — no sorting at all. This is the hot TPC-H q1 shape
-    (GROUP BY returnflag, linestatus -> 6 slots): one fused XLA program
-    per batch instead of a cascade of sort passes.
+    is one reduction of its kind over all columns (``_stacked_reduce``) —
+    no sorting at all. This is the hot TPC-H q1 shape (GROUP BY
+    returnflag, linestatus -> 6 slots): one fused XLA program per batch
+    instead of a cascade of sort passes.
 
     Capacity is exactly ``prod(vocab+1)``, so overflow is impossible."""
     radix = [v + 1 for v in vocab_sizes]
-    P = 1
-    for r in radix:
-        P *= r
+    P = dense_slots(vocab_sizes)
     seg = None
     for code, nm, v in zip(key_codes, key_nulls, vocab_sizes):
         c = jnp.clip(code.astype(jnp.int32), 0, v - 1)
@@ -986,13 +1121,10 @@ def _dense_agg(
         seg = c if seg is None else seg * (v + 1) + c
     rid_all = jnp.where(valid, seg, P)
 
-    # which slots hold at least one live row
-    occupied = jnp.zeros(P, dtype=bool).at[rid_all].set(True, mode="drop")
-
-    lives = [
-        valid if vn is None else (valid & ~vn) for vn in val_nulls
-    ]
-    out_vals, out_val_nulls = _stacked_reduce(
+    # a value without NULLs is live in every valid row: the reduction's
+    # count of rows a slot, which is also the slot's occupancy
+    lives = [None if vn is None else (valid & ~vn) for vn in val_nulls]
+    out_vals, out_val_nulls, occupied = _stacked_reduce(
         rid_all, P, list(val_cols), lives, ops
     )
 
@@ -1030,8 +1162,15 @@ _dense_agg_jit = jax.jit(
 )
 
 # Dense slots grow as prod(vocab+1); past this the sort-based kernel's
-# O(n log n) wins back (and scatter outputs stop being cache-friendly).
+# O(n log n) wins back: the factorized one-hot's MXU work grows as slots x
+# rows, and the f64 sums and MIN/MAX that keep their scatter stop being
+# cache-friendly.
 DENSE_AGG_MAX_SLOTS = 1 << 16
+
+
+def dense_slots(vocab_sizes) -> int:
+    """The dense path's slot count: the mixed radix of (vocab + 1) a key."""
+    return math.prod(v + 1 for v in vocab_sizes)
 
 
 def dense_group_aggregate(

@@ -42,15 +42,22 @@ SEED = 2_900_000_021
 # stage: a partial pass per map task and a merge pass per reduce task. A
 # reduce task's half of the ``id3`` values is a dictionary of under 65,536
 # entries, so its merge is dense, here (63,000 slots) as in the cell (50,001).
-# A map
+# Every dense pass here is past 2,048 slots (``dense_factored``), so each
+# reduces its counts and integer sums on the factorized one-hot: g1q2's
+# partials (131,072 rows into 10,201 slots) and merges (16,384 into 10,201),
+# g1q3's and g1q7's merges (131,072 into some 63,000; g1q7's MIN and MAX
+# keep their scatter, its counts do not). A map
 # task turns each string key back into strings (one entry a column); a reduce
 # task encodes what it read (one entry for all its string columns) and decodes
 # its answer (one a column).
 CASES = {
-    "g1q3": (1, {"agg.sort_passes": 2, "agg.dense_passes": 2}, 6),
+    "g1q3": (1, {"agg.sort_passes": 2, "agg.dense_passes": 2,
+                 "agg.dense_factored_passes": 2}, 6),
     "g1q5": (100, {"agg.sort_passes": 4}, 0),
-    "g1q2": (100, {"agg.dense_passes": 4}, 10),
-    "g1q7": (1, {"agg.sort_passes": 2, "agg.dense_passes": 2}, 6),
+    "g1q2": (100, {"agg.dense_passes": 4, "agg.dense_factored_passes": 4},
+             10),
+    "g1q7": (1, {"agg.sort_passes": 2, "agg.dense_passes": 2,
+                 "agg.dense_factored_passes": 2}, 6),
 }
 
 

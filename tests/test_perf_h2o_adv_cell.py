@@ -152,7 +152,8 @@ def test_a_traced_run_of_the_cell_reports_every_metric_it_is_held_to(tmp_path):
                if CELL in m.get("workloads", [CELL])
                and m["source"] != "device_trace" and m["layer"] != "device"}
     assert {"holistic_tasks_per_query", "holistic_rows_sorted_per_query",
-            "status_polls_per_query"} <= held_to
+            "status_polls_per_query",
+            "agg_dense_factored_passes_per_query"} <= held_to
     done = subprocess.run(
         [sys.executable, str(PERF / "run.py"), "--workload", CELL,
          "--seed", "3300000034", "--seconds", "1", "--trace", "1",
@@ -165,6 +166,8 @@ def test_a_traced_run_of_the_cell_reports_every_metric_it_is_held_to(tmp_path):
     assert held_to - set(line["metrics"]) == set()
     assert line["metrics"]["holistic_tasks_per_query"]["value"] == 2
     assert 1 <= line["metrics"]["status_polls_per_query"]["value"] < 10
+    # int64 keys: no dense pass, none on the factorized one-hot (PR 37)
+    assert line["metrics"]["agg_dense_factored_passes_per_query"]["value"] == 0
 
 
 # -- planted faults -----------------------------------------------------------------

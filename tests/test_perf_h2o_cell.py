@@ -157,8 +157,9 @@ def test_the_cell_in_rehearsal_is_what_the_comparison_said(capsys, monkeypatch):
 def test_a_traced_run_of_the_cell_reports_every_metric_it_is_held_to(tmp_path):
     """As ``tests/test_perf_h2o_adv_cell.py`` does for its cell: the
     ``--trace 1`` line of a rehearsal has every counter and host-clock
-    metric the cell is held to, ``status_polls_per_query`` (PR 34) and
-    ``dict_merge_ms_per_query`` (the string keys) among them."""
+    metric the cell is held to, ``status_polls_per_query`` (PR 34),
+    ``dict_merge_ms_per_query`` (the string keys) and
+    ``agg_dense_factored_passes_per_query`` (PR 37) among them."""
     import os
     import subprocess
 
@@ -166,7 +167,8 @@ def test_a_traced_run_of_the_cell_reports_every_metric_it_is_held_to(tmp_path):
     held_to = {m["name"] for m in bench["per_layer"]
                if CELL in m.get("workloads", [CELL])
                and m["source"] != "device_trace" and m["layer"] != "device"}
-    assert {"status_polls_per_query", "dict_merge_ms_per_query"} <= held_to
+    assert {"status_polls_per_query", "dict_merge_ms_per_query",
+            "agg_dense_factored_passes_per_query"} <= held_to
     done = subprocess.run(
         [sys.executable, str(PERF / "run.py"), "--workload", CELL,
          "--seed", "3400000034", "--seconds", "1", "--trace", "1",
@@ -179,6 +181,8 @@ def test_a_traced_run_of_the_cell_reports_every_metric_it_is_held_to(tmp_path):
     assert held_to - set(line["metrics"]) == set()
     # every query is asked about at least once, and a held ask is one
     assert 1 <= line["metrics"]["status_polls_per_query"]["value"] < 10
+    # g1q2's 10,201 slots (PR 37): its dense passes reduce on the MXU
+    assert line["metrics"]["agg_dense_factored_passes_per_query"]["value"] > 0
 
 
 # -- planted faults -----------------------------------------------------------------

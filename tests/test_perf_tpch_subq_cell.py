@@ -78,7 +78,10 @@ def test_the_cell_is_tpch_sf1_mem_cut_in_queries_only():
         assert m["moves"] == "queries_per_s"
         assert m["source"] == "program_counter"
         assert m.get("workloads") == cells
-    assert [m["name"] for m in bench["per_layer"][-4:]] == list(BROUGHT)
+    # appended together, after what was there (later PRs append after them)
+    names = [m["name"] for m in bench["per_layer"]]
+    at = names.index(next(iter(BROUGHT)))
+    assert names[at:at + len(BROUGHT)] == list(BROUGHT)
 
 
 @pytest.mark.parametrize("name", TEMPLATES)
@@ -156,7 +159,8 @@ def test_a_traced_run_of_the_cell_reports_every_metric_it_is_held_to(tmp_path):
                and m["source"] != "device_trace" and m["layer"] != "device"}
     assert set(BROUGHT) <= held_to
     assert {"holistic_tasks_per_query", "agg_groups_per_query",
-            "task_unnamed_ms_per_query"} <= held_to
+            "task_unnamed_ms_per_query",
+            "agg_dense_factored_passes_per_query"} <= held_to
     done = subprocess.run(
         [sys.executable, str(PERF / "run.py"), "--workload", CELL,
          "--seed", "3600000034", "--seconds", "1", "--trace", "1",
@@ -175,6 +179,8 @@ def test_a_traced_run_of_the_cell_reports_every_metric_it_is_held_to(tmp_path):
     assert metrics["dict_predicate_entries_per_query"] == 0
     assert metrics["dict_predicate_ms_per_query"] >= 0
     assert metrics["holistic_tasks_per_query"] == 0
+    # q4's dense aggregate has 6 slots, within the one-hot kernels (PR 37)
+    assert metrics["agg_dense_factored_passes_per_query"] == 0
 
 
 # -- planted faults -----------------------------------------------------------------

@@ -93,6 +93,33 @@ def test_q1_dense_aggregate_lowers_through_the_kernel(S, monkeypatch):
         tuple(S((N,), "float64") for _ in range(9)),
     )
     assert "tpu_custom_call" in text
+    # occupancy is the kernel's count of rows a slot, not a scatter-set
+    assert " scatter(" not in text
+
+
+def test_g1q2_dense_aggregate_reduces_without_a_scatter(S):
+    """g1q2's partial pass in ``h2o-g1-1e7-mem.groupby``: a 2M-row batch,
+    two string keys of 100 values (10,201 slots), ``SUM(v1)`` of an int64.
+    Counts, the sum and the occupancy go through the factorized one-hot
+    (``ops/aggregate.py _factored_sums``): no scatter is left."""
+    from ballista_tpu.ops import aggregate as A
+    from ballista_tpu.ops.aggregate import AggOp
+
+    n = 2 * N
+    assert A.dense_factored(A.dense_slots((100, 100)))
+
+    def g1q2_partial(codes, valid, v1):
+        res = A._dense_agg(list(codes), [None, None], (100, 100), valid,
+                           [v1], [None], (AggOp.SUM,))
+        return res.values, res.value_nulls, res.valid, res.n_groups
+
+    text = _compile(
+        jax.jit(g1q2_partial),
+        (S((n,), "int32"), S((n,), "int32")), S((n,), "bool"),
+        S((n,), "int64"),
+    )
+    assert " scatter(" not in text
+    assert " convolution(" in text or " dot(" in text
 
 
 def test_float_prefix_takes_the_matmul_branch(S):
