@@ -63,6 +63,9 @@ telemetry uses) and keeps process-global counters:
   entries a pass left out because their key carries a job id (summed over
   passes), files written, entries merged at load. The first three and the
   writer's phase seconds are declared at 0 likewise.
+- ``op.<family>.self_seconds`` — the operators' own time on their task
+  threads (``obs.trace.stretch``: each operator's ``self_s``), summed by
+  ``OP_FAMILIES`` as a task ends. Declared at 0 likewise.
 
 Counters surface per executor through the heartbeat -> scheduler REST
 path (docs/compile_cache.md).
@@ -109,10 +112,38 @@ HINT_COUNTERS = (
     "hints.marks", "hints.writes_skipped_unchanged",
     "hints.entries_job_scoped_skipped", "phase.executor.hints_write.seconds",
 )
+# operator classes by family, for the operators' own time
+# (obs.trace.stretch): a class listed nowhere is "other"
+OP_FAMILIES = {
+    "scan": ("MemoryScanExec", "ParquetScanExec", "CsvScanExec",
+             "AvroScanExec"),
+    "pipeline": ("FilterExec", "ProjectionExec", "RenameExec",
+                 "CoalescePartitionsExec", "GlobalLimitExec", "UnionExec",
+                 "EmptyExec"),
+    "aggregate": ("HashAggregateExec", "MeshAggregateExec"),
+    "join": ("HashJoinExec", "CrossJoinExec", "MeshJoinExec"),
+    "holistic": ("SortExec", "WindowExec", "PercentileExec", "MeshSortExec",
+                 "MeshWindowExec"),
+    "exchange": ("HashRepartitionExec", "ShuffleReaderExec",
+                 "ShuffleWriterExec"),
+    "other": (),
+}
+OP_COUNTERS = tuple(f"op.{family}.self_seconds" for family in OP_FAMILIES)
+_OP_COUNTER = {
+    operator: f"op.{family}.self_seconds"
+    for family, operators in OP_FAMILIES.items() for operator in operators
+}
+
+
+def op_counter(operator: str) -> str:
+    """The ``op.<family>.self_seconds`` counter of an operator class name."""
+    return _OP_COUNTER.get(operator, "op.other.self_seconds")
+
+
 _COUNTERS: dict[str, float] = dict.fromkeys(
     AGG_COUNTERS + HOLISTIC_COUNTERS + DICT_PREDICATE_COUNTERS
     + NONINNER_JOIN_COUNTERS + POLL_COUNTERS + STATUS_COUNTERS
-    + HINT_COUNTERS, 0
+    + HINT_COUNTERS + OP_COUNTERS, 0
 )
 _INSTALLED = False
 

@@ -319,7 +319,7 @@ class Executor:
 
         collector = collector_for(config, self.metrics_collector)
         if collector.wants_instrumentation():
-            # per-operator rows/bytes/dispatch_s metering (obs.profile):
+            # per-operator rows/bytes/self_s metering (obs.profile):
             # wrapped BEFORE execution; counters stay lazy device scalars
             # on the hot path and resolve once at record_stage
             from ballista_tpu.obs import profile
@@ -487,6 +487,15 @@ class Executor:
                     for name in ("probe_rows", "unmatched_rows")
                 ]
             )
+        # and each operator's own time on the task's threads, by family
+        own: dict[str, float] = {}
+        for r in op_metrics or ():
+            seconds = r["counters"].get("self_s")
+            if seconds:
+                key = compile_metrics.op_counter(r["operator"])
+                own[key] = own.get(key, 0.0) + seconds
+        if own:
+            compile_metrics.add_many(own.items())
         # cost accounting (docs/observability.md): this attempt's
         # resource vector — wall/CPU around the run, the plan's
         # data-plane counters (shuffle read, spill, push), the committed

@@ -35,7 +35,7 @@ class ExecutorMetricsCollector:
     def wants_instrumentation(self) -> bool:
         """Whether the executor should meter the decoded plan
         (obs.profile.instrument_plan) BEFORE running it — shipping needs
-        per-operator rows/bytes/dispatch_s; logging keeps the reference's
+        per-operator rows/bytes/self_s; logging keeps the reference's
         operator-recorded metrics only."""
         return False
 

@@ -12,15 +12,17 @@ batch) to meter, per operator:
 - ``output_batches`` / ``output_bytes`` — batch count and the device
   residency of what was produced (capacity x dtype widths, host
   arithmetic — no sync).
+- ``self_s`` (timer) — the operator's own stretches of the thread's
+  time (``obs.trace.stretch``): from entering its iterator's ``next()``
+  to the batch coming back, without its inputs' ``next()`` and without
+  any host phase, the metering of the batch included. Each stretch is a
+  ``ballista/op.<Operator>`` annotation on the profiler's clock. On the
+  served path nothing syncs, so this is host time: Python, tracing and
+  the dispatch of the operator's programs, not the time they run.
 - ``elapsed`` (timer, ``EXPLAIN ANALYZE`` only) — wall seconds spent
   INSIDE this operator's iterator until the batch it yields is COMPLETE
   on the device (the timer ends in ``block_until_ready``), cumulative
-  over the operator and its inputs (the Spark UI convention; subtracting
-  a child's elapsed gives self time).
-- ``dispatch_s`` (timer, the shipping collector's path) — the same
-  bracket WITHOUT the sync: JAX dispatch is asynchronous, so this is the
-  host time to trace, look up and enqueue the operator's programs, not
-  the time they run. Nothing on the served path syncs for a timer.
+  over the operator and its inputs (the Spark UI convention).
 
 The same counters feed three consumers: ``EXPLAIN ANALYZE`` renders
 :func:`annotated_display`; the executor's ShippingMetricsCollector
@@ -36,6 +38,9 @@ import time
 import jax
 
 from ballista_tpu.datatypes import DataType
+from ballista_tpu.obs import trace as obs_trace
+
+_DONE = object()  # next()'s default: the input is exhausted
 
 # device-resident width per column dtype (bytes/row at capacity) — host
 # arithmetic only, mirroring columnar/batch.py's storage choices
@@ -62,47 +67,57 @@ def batch_nbytes(batch) -> int:
 def instrument_plan(plan, sync: bool = False) -> None:
     """Wrap every node's ``execute`` with the metering shim (idempotent:
     re-instrumenting an already-wrapped node is a no-op, so cached plan
-    instances survive repeated EXPLAIN ANALYZE runs). ``sync`` (EXPLAIN
-    ANALYZE) ends each timed step in ``block_until_ready`` on the batch
-    and calls the timer ``elapsed``; without it the timer is what it
-    measures, ``dispatch_s``."""
-    timer = "elapsed" if sync else "dispatch_s"
+    instances survive repeated EXPLAIN ANALYZE runs), and a task root's
+    ``execute_shuffle_write`` with its stretch. ``sync`` (EXPLAIN ANALYZE)
+    also times each step to ``block_until_ready`` on the batch, cumulative
+    over the inputs, as ``elapsed``."""
 
     def wrap(node) -> None:
         if getattr(node, "_obs_metered", False):
             return
         orig = node.execute
+        label = f"ballista/op.{type(node).__name__}"
 
         def metered(partition, ctx, _orig=orig, _node=node):
             m = _node.metrics
-            it = iter(_orig(partition, ctx))
+            own = obs_trace.stretch(label, m)
+            with own:
+                it = iter(_orig(partition, ctx))
             try:
                 while True:
-                    t0 = time.perf_counter()
-                    try:
-                        batch = next(it)
+                    with own:
+                        t0 = time.perf_counter() if sync else 0.0
+                        batch = next(it, _DONE)
                         if sync:
-                            jax.block_until_ready(batch)
-                    except StopIteration:
-                        m.timers[timer] = m.timers.get(timer, 0.0) + (
-                            time.perf_counter() - t0
-                        )
-                        break
-                    m.timers[timer] = m.timers.get(timer, 0.0) + (
-                        time.perf_counter() - t0
-                    )
-                    m.add("output_batches")
-                    if batch.valid is not None:
-                        # lazy device scalar; Metrics.summary resolves it
-                        m.add("output_rows", batch.valid.sum())
-                        m.add("output_bytes", batch_nbytes(batch))
+                            if batch is not _DONE:
+                                jax.block_until_ready(batch)
+                            m.timers["elapsed"] = m.timers.get(
+                                "elapsed", 0.0
+                            ) + (time.perf_counter() - t0)
+                        if batch is _DONE:
+                            break
+                        m.add("output_batches")
+                        if batch.valid is not None:
+                            # lazy device scalar; Metrics.summary resolves it
+                            m.add("output_rows", batch.valid.sum())
+                            m.add("output_bytes", batch_nbytes(batch))
+                    # no stretch is held across the yield
                     yield batch
             finally:
                 close = getattr(it, "close", None)
                 if close is not None:
-                    close()
+                    with own:
+                        close()
 
         node.execute = metered
+        write = getattr(node, "execute_shuffle_write", None)
+        if write is not None:
+
+            def metered_write(partition, ctx, _orig=write, _node=node):
+                with obs_trace.stretch(label, _node.metrics):
+                    return _orig(partition, ctx)
+
+            node.execute_shuffle_write = metered_write
         node._obs_metered = True
         for c in node.children():
             wrap(c)
@@ -164,8 +179,8 @@ def merge_counter_maps(maps) -> dict:
 
 def annotated_display(plan, extra: dict | None = None) -> str:
     """The physical plan display re-printed with measured
-    rows/bytes/elapsed per operator (the EXPLAIN ANALYZE body; records
-    shipped from executors carry ``dispatch`` in place of ``elapsed``).
+    rows/bytes/elapsed/self time per operator (the EXPLAIN ANALYZE body;
+    records shipped from executors carry ``self`` without ``elapsed``).
     ``extra``: {path: counter-map} merged in (e.g. scheduler-side
     aggregates for operators that ran remotely)."""
     lines = []
@@ -177,7 +192,7 @@ def annotated_display(plan, extra: dict | None = None) -> str:
         rows = counters.pop("output_rows", None)
         nbytes = counters.pop("output_bytes", None)
         elapsed = counters.pop("elapsed", None)
-        dispatch = counters.pop("dispatch_s", None)
+        own = counters.pop("self_s", None)
         parts = []
         if rows is not None:
             parts.append(f"rows={int(rows)}")
@@ -185,8 +200,8 @@ def annotated_display(plan, extra: dict | None = None) -> str:
             parts.append(f"bytes={int(nbytes)}")
         if elapsed is not None:
             parts.append(f"elapsed={float(elapsed):.6f}s")
-        if dispatch is not None:
-            parts.append(f"dispatch={float(dispatch):.6f}s")
+        if own is not None:
+            parts.append(f"self={float(own):.6f}s")
         parts += [f"{k}={v}" for k, v in sorted(counters.items())]
         line = "  " * d + node.describe()
         if parts:

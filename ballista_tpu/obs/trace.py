@@ -30,6 +30,13 @@ that each feed three things from ONE call site: a
 process-wide counters of ``compilecache/metrics.py`` that ride the
 executor's poll, and, under ``ballista.tpu.trace``, the enclosing
 ``task_attempt`` span's attrs. No span is minted per phase.
+
+Operator stretches (:class:`stretch`) carry the same mechanism down into
+the operators: a thread keeps a stack of the operators it is running, and
+its time belongs to exactly one owner at every moment, the innermost
+operator or an open phase. Each stretch is a ``ballista/op.<Operator>``
+annotation and the operator's ``self_s`` timer; at most one ``ballista/``
+annotation is open on a thread at any time.
 """
 
 from __future__ import annotations
@@ -396,7 +403,8 @@ class phase:
     event that covers most of it, so an enclosing phase would swallow
     every label inside it. A phase entered inside a phase raises under
     the tests and otherwise does nothing but count ``phase.nested``.
-    Never hold one across a ``yield``."""
+    A phase suspends the thread's running operator stretch and resumes it
+    on exit. Never hold one across a ``yield``."""
 
     __slots__ = ("name", "nbytes", "_names", "_t0", "_ann", "_nested")
 
@@ -414,6 +422,9 @@ class phase:
                     f"{self._nested!r}: phases are leaves"
                 )
             return self
+        owners = getattr(_TLS, "owners", None)
+        if owners:
+            owners[-1]._stop()
         _TLS.in_phase = self.name
         self._ann = TraceAnnotation(self._names[0])
         self._ann.__enter__()
@@ -427,6 +438,9 @@ class phase:
         dt = time.perf_counter() - self._t0
         self._ann.__exit__(*exc)
         _TLS.in_phase = None
+        owners = getattr(_TLS, "owners", None)
+        if owners:
+            owners[-1]._start()
         _, attr, keys, sited = self._names
         values = (dt, 1, int(self.nbytes))
         adds = [kv for kv in zip(keys, values) if kv[1]]
@@ -448,6 +462,74 @@ def account(name: str, seconds: float, count: int = 1) -> None:
     annotate, and nothing for a profiler to see."""
     keys = _phase_names(name, "")[2]
     metrics.add_many(((keys[0], seconds), (keys[1], count)))
+
+
+# ---------------------------------------------------------------------------
+# operator stretches: one owner of a thread's time at every moment
+# ---------------------------------------------------------------------------
+
+
+class stretch:
+    """An operator's own stretch of a thread's time::
+
+        with obs_trace.stretch("ballista/op.FilterExec", node.metrics):
+            batch = next(it)
+
+    Entering pushes the operator on the thread's owner stack and suspends
+    the operator below it; leaving pops it and resumes that one. While it
+    is the top of the stack and no phase is open, the thread's time is
+    its own: a ``TraceAnnotation`` named ``label`` on the profiler's clock
+    and the timer ``self_s`` in ``metrics`` (self time, without its inputs
+    and without any phase). A phase suspends the top stretch for its
+    length (:class:`phase`), so at most one ``ballista/`` annotation is
+    open on a thread at any time. Never hold one across a ``yield``: a
+    stretch is entered and left within one call, so the stack is restored
+    by every exit, an exception included."""
+
+    __slots__ = ("label", "metrics", "_ann", "_t0")
+
+    def __init__(self, label: str, metrics):
+        self.label = label
+        self.metrics = metrics
+        self._ann = None
+
+    def _start(self) -> None:
+        self._ann = TraceAnnotation(self.label)
+        self._ann.__enter__()
+        self._t0 = time.perf_counter()
+
+    def _stop(self) -> None:
+        if self._ann is None:
+            return
+        dt = time.perf_counter() - self._t0
+        self._ann.__exit__(None, None, None)
+        self._ann = None
+        timers = self.metrics.timers
+        timers["self_s"] = timers.get("self_s", 0.0) + dt
+
+    def __enter__(self) -> "stretch":
+        owners = getattr(_TLS, "owners", None)
+        if owners is None:
+            owners = _TLS.owners = []
+        elif owners:
+            owners[-1]._stop()
+        owners.append(self)
+        if getattr(_TLS, "in_phase", None) is None:
+            self._start()
+        return self
+
+    def __exit__(self, *exc) -> bool:
+        self._stop()
+        owners = _TLS.owners
+        owners.pop()
+        if owners and getattr(_TLS, "in_phase", None) is None:
+            owners[-1]._start()
+        return False
+
+
+def owners() -> list[str]:
+    """The labels of this thread's owner stack, innermost last (tests)."""
+    return [s.label for s in getattr(_TLS, "owners", None) or ()]
 
 
 # ---------------------------------------------------------------------------

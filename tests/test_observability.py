@@ -214,8 +214,10 @@ def test_instrument_plan_meters_every_operator():
         if r["counters"].get("output_batches"):
             assert r["counters"]["output_rows"] > 0
             assert r["counters"]["output_bytes"] > 0
-            # the shipping path's timer does not sync, and says so
-            assert r["counters"]["dispatch_s"] >= 0
+            # the shipping path's timer is the operator's own time, and
+            # does not sync
+            assert r["counters"]["self_s"] > 0
+            assert not [k for k in r["counters"] if k.startswith("dispatch")]
             assert "elapsed" not in r["counters"]
     # the root produced the query's rows
     root = recs[0]["counters"]
@@ -472,6 +474,12 @@ assert final and sum(
 assert detail["operator_metrics"], "no shipped operator metrics"
 some = next(iter(detail["operator_metrics"].values()))
 assert any("output_rows" in r["counters"] for r in some)
+# each operator's own time, the task's root too (obs.trace.stretch)
+for recs in detail["operator_metrics"].values():
+    assert not [k for r in recs for k in r["counters"]
+                if k.startswith("dispatch")]
+    assert recs[0]["operator"] == "ShuffleWriterExec"
+    assert recs[0]["counters"]["self_s"] > 0, recs[0]
 spans = detail["spans"]
 names = {s["name"] for s in spans}
 assert {"job", "stage", "task_attempt"} <= names, names
