@@ -303,6 +303,7 @@ CACHES: tuple[CacheEntry, ...] = (
             "ballista_tpu/exec/percentile.py::_pct_program",
             "ballista_tpu/exec/repartition.py::_jit_mask_partition",
             "ballista_tpu/exec/repartition.py::jit_partition_ids",
+            "ballista_tpu/exec/repartition.py::jit_bucket_counts",
             "ballista_tpu/exec/shrink.py::_shrink_program",
             "ballista_tpu/exec/sort.py::_fetch_program",
             "ballista_tpu/exec/window.py::_rank_program",

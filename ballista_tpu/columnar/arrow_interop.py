@@ -289,17 +289,40 @@ def batch_to_arrow(
 ) -> pa.RecordBatch:
     """Gather live rows to host and decode dictionaries back to strings.
     ``site`` names the caller for the device read (DeviceBatch.to_host)."""
+    schema, cols, nulls = batch.to_host(site)
+    return host_to_arrow(schema, cols, nulls, batch.dictionaries)
+
+
+def host_to_arrow(
+    schema: Schema,
+    cols: list[np.ndarray],
+    nulls: list[np.ndarray | None],
+    dictionaries,
+    take_layout: bool = False,
+) -> pa.RecordBatch:
+    """Host columns in the device's representation (what
+    ``DeviceBatch.to_host`` returns: codes for STRING columns, null masks
+    beside) to one Arrow batch, decoding codes by ``dictionaries``.
+
+    ``take_layout``: lay out a null slot as Arrow's ``take`` does, a zero
+    value or an empty string, so that columns gathered on the host carry
+    the bytes that a ``take`` of this function's plain output would."""
     from ballista_tpu.obs import trace as obs_trace
 
-    schema, cols, nulls = batch.to_host(site)
     arrays = []
     import pyarrow.compute as pc
 
     for field, col, nm in zip(schema, cols, nulls):
+        if nm is not None and not nm.any():
+            nm = None
+        mask = None
+        if take_layout and nm is not None:
+            mask = nm
+            col = np.where(mask, np.zeros((), col.dtype), col)
         if field.dtype == DataType.NULL:
             arr = pa.nulls(len(col), type=pa.null())
         elif field.dtype == DataType.STRING:
-            d = batch.dictionaries.get(field.name)
+            d = dictionaries.get(field.name)
             if d is None and len(col) == 0:
                 # zero live rows (e.g. a hash bucket that received no
                 # groups): there is nothing to decode — emit empty strings
@@ -317,15 +340,19 @@ def batch_to_arrow(
                     values = pa.array(d.values, type=pa.string())
                     codes = np.clip(col, 0, len(d) - 1).astype(np.int32)
                     arr = pa.DictionaryArray.from_arrays(
-                        pa.array(codes, type=pa.int32()), values
+                        pa.array(codes, type=pa.int32(), mask=mask), values
                     ).cast(pa.string())
         elif field.dtype == DataType.DATE32:
-            arr = pa.array(col.astype("int32"), type=pa.int32()).cast(pa.date32())
+            arr = pa.array(
+                col.astype("int32"), type=pa.int32(), mask=mask
+            ).cast(pa.date32())
         elif field.dtype == DataType.TIMESTAMP_US:
-            arr = pa.array(col.astype("int64"), type=pa.int64()).cast(pa.timestamp("us"))
+            arr = pa.array(
+                col.astype("int64"), type=pa.int64(), mask=mask
+            ).cast(pa.timestamp("us"))
         else:
-            arr = pa.array(col, type=dtype_to_arrow(field.dtype))
-        if nm is not None and nm.any() and field.dtype != DataType.NULL:
+            arr = pa.array(col, type=dtype_to_arrow(field.dtype), mask=mask)
+        if nm is not None and mask is None and field.dtype != DataType.NULL:
             arr = pc.if_else(
                 pa.array(nm), pa.scalar(None, type=arr.type), arr
             )

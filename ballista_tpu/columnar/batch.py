@@ -407,6 +407,46 @@ class DeviceBatch:
     # is not measured on the attached chip).
     _SLICED_FETCH_BYTES = 4 << 20
 
+    def sliced_fetch(self) -> bool:
+        """Whether a fetch of this batch is worth a count sync first: its
+        padded columns, null masks and ``valid`` pass
+        ``_SLICED_FETCH_BYTES``."""
+        n_null = sum(1 for m in self.nulls if m is not None)
+        width = sum(c.dtype.itemsize for c in self.columns) + 1 + n_null
+        return width * self.capacity > self._SLICED_FETCH_BYTES
+
+    def compacts_for(self, n: int) -> bool:
+        """Whether ``n`` live rows are few enough to compact on the device
+        and fetch a head of the batch (at most a quarter live)."""
+        return n * 4 <= self.capacity
+
+    @staticmethod
+    def head_rows(n: int) -> int:
+        """The head a compacted batch of ``n`` live rows is fetched as: the
+        next power of two, at least 8."""
+        m = 8
+        while m < n:
+            m <<= 1
+        return m
+
+    def fetch_host(
+        self, site: str, extra: Sequence[jnp.ndarray] = ()
+    ) -> tuple[list[np.ndarray], list[np.ndarray | None], list[np.ndarray]]:
+        """Every column, every null mask and the ``extra`` arrays at full
+        capacity, in ONE ``fetch_arrays`` round trip at ``site``. Returns
+        (columns, null masks with None in place, extras)."""
+        from ballista_tpu.ops.fetch import fetch_arrays
+
+        present = [m for m in self.nulls if m is not None]
+        fetched = fetch_arrays(
+            [*extra, *self.columns, *present], site=site
+        )
+        k = len(extra)
+        cols = fetched[k : k + len(self.columns)]
+        it = iter(fetched[k + len(self.columns) :])
+        nulls = [None if m is None else next(it) for m in self.nulls]
+        return cols, nulls, fetched[:k]
+
     def to_host(
         self, site: str = "to_host"
     ) -> tuple[Schema, list[np.ndarray], list[np.ndarray | None]]:
@@ -428,11 +468,8 @@ class DeviceBatch:
         # round trip. The sliced strategy adds one tiny count sync first.
         from ballista_tpu.ops.fetch import fetch_arrays
 
-        n_null = sum(1 for m in self.nulls if m is not None)
-        padded_bytes = sum(c.dtype.itemsize for c in self.columns)
-        padded_bytes = (padded_bytes + 1 + n_null) * self.capacity
         b = self
-        if padded_bytes > self._SLICED_FETCH_BYTES:
+        if self.sliced_fetch():
             # an operator that KNOWS a live-row ceiling host-side (e.g.
             # GlobalLimit's fetch) saves the count sync — one fewer
             # blocking round trip on the query's critical path. The
@@ -441,31 +478,18 @@ class DeviceBatch:
             # (fetching the full padded capacity on its say-so could cost
             # far more than the one round trip it saves).
             n = getattr(self, "host_rows_max", None)
-            if n is None or n * 4 > self.capacity:
+            if n is None or not self.compacts_for(n):
                 n = int(
                     fetch_arrays([self.count_valid()], site=f"{site}.count")[0]
                 )
-            if n * 4 <= self.capacity:
+            if self.compacts_for(n):
                 from ballista_tpu.ops.compact import compact
 
-                m = 8
-                while m < n:
-                    m <<= 1
-                b = compact(self).head(m)
-        fetched = fetch_arrays(
-            [b.valid, *b.columns, *[m for m in b.nulls if m is not None]],
-            site=site,
-        )
-        valid = fetched[0]
-        cols_h = fetched[1 : 1 + len(b.columns)]
-        null_arrs = fetched[1 + len(b.columns) :]
+                b = compact(self).head(self.head_rows(n))
+        cols_h, nulls_h, (valid,) = b.fetch_host(site, (b.valid,))
         idx = np.nonzero(valid)[0]
-        cols = [np.asarray(c)[idx] for c in cols_h]
-        it = iter(null_arrs)
-        nulls = [
-            None if m is None else np.asarray(next(it))[idx]
-            for m in b.nulls
-        ]
+        cols = [c[idx] for c in cols_h]
+        nulls = [None if m is None else m[idx] for m in nulls_h]
         return self.schema, cols, nulls
 
     def __repr__(self) -> str:

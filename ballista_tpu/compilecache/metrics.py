@@ -63,6 +63,10 @@ telemetry uses) and keeps process-global counters:
   entries a pass left out because their key carries a job id (summed over
   passes), files written, entries merged at load. The first three and the
   writer's phase seconds are declared at 0 likewise.
+- ``shuffle.split_batches`` / ``shuffle.split_device_ordered`` — the
+  hash exchange's writer (``executor/shuffle.py split_batch``): batches it
+  split among two or more output partitions, and of them those whose
+  bucket order came from the device's compaction. Declared at 0 likewise.
 - ``op.<family>.self_seconds`` — the operators' own time on their task
   threads (``obs.trace.stretch``: each operator's ``self_s``), summed by
   ``OP_FAMILIES`` as a task ends. Declared at 0 likewise.
@@ -112,6 +116,9 @@ HINT_COUNTERS = (
     "hints.marks", "hints.writes_skipped_unchanged",
     "hints.entries_job_scoped_skipped", "phase.executor.hints_write.seconds",
 )
+# the hash exchange's split of a batch (executor/shuffle.py split_batch):
+# batches split, and those the device put in bucket order
+SHUFFLE_COUNTERS = ("shuffle.split_batches", "shuffle.split_device_ordered")
 # operator classes by family, for the operators' own time
 # (obs.trace.stretch): a class listed nowhere is "other"
 OP_FAMILIES = {
@@ -143,7 +150,7 @@ def op_counter(operator: str) -> str:
 _COUNTERS: dict[str, float] = dict.fromkeys(
     AGG_COUNTERS + HOLISTIC_COUNTERS + DICT_PREDICATE_COUNTERS
     + NONINNER_JOIN_COUNTERS + POLL_COUNTERS + STATUS_COUNTERS
-    + HINT_COUNTERS + OP_COUNTERS, 0
+    + HINT_COUNTERS + SHUFFLE_COUNTERS + OP_COUNTERS, 0
 )
 _INSTALLED = False
 

@@ -141,6 +141,9 @@ VOCABULARY: dict[str, KernelSpec] = {
     "exec.repartition.repartition_mask": KernelSpec(
         None, "hash key indexes + partition count; one partition's mask"
     ),
+    "exec.repartition.repartition_bucket_counts": KernelSpec(
+        None, "partition count from the plan, per capacity"
+    ),
     "exec.aggregate.agg_ones": KernelSpec(None, "count column, per capacity"),
     "exec.aggregate.agg_dec_learn": KernelSpec(
         None, "decimal-scale discovery per (capacity, null layout)"
@@ -241,6 +244,7 @@ _JOIN = (
 ) + _PERM + _CONCAT + _FETCH
 _REPARTITION = (
     "exec.repartition.repartition_hash", "exec.repartition.repartition_mask",
+    "exec.repartition.repartition_bucket_counts",
 )
 
 OPERATOR_KERNELS: dict[str, tuple[str, ...]] = {

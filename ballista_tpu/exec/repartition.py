@@ -23,6 +23,7 @@ import functools
 from typing import Iterator
 
 import jax
+import jax.numpy as jnp
 
 from ballista_tpu.columnar.batch import DeviceBatch
 from ballista_tpu.datatypes import Schema
@@ -57,6 +58,21 @@ def jit_partition_ids(key_idxs: tuple, num_partitions: int):
         return partition_ids(b, list(key_idxs), num_partitions, tables)
 
     return jax.jit(repartition_hash)
+
+
+@functools.lru_cache(maxsize=None)
+def jit_bucket_counts(num_partitions: int):
+    """Jitted count of the rows of each bucket of ``jit_partition_ids``'
+    output, the drop bucket ``num_partitions`` last: ``num_partitions + 1``
+    counts, which bound the buckets of the shuffle writer's split
+    (executor/shuffle.py split_batch)."""
+
+    def repartition_bucket_counts(pids):
+        # rows along the minor axis: a row reduction, lanes full
+        buckets = jnp.arange(num_partitions + 1, dtype=pids.dtype)
+        return jnp.sum(buckets[:, None] == pids, axis=1, dtype=jnp.int32)
+
+    return jax.jit(repartition_bucket_counts)
 
 
 class HashRepartitionExec(ExecutionPlan):

@@ -3,8 +3,8 @@
 ref ballista/rust/core/src/execution_plans/shuffle_writer.rs:65-431. For
 each input partition it executes the child fragment, hash-partitions rows
 on DEVICE (ops/partition.py — the reference's BatchPartitioner runs on CPU,
-:209-256), gathers each bucket to host, and appends to one Arrow IPC file
-per output partition:
+:209-256), brings each batch to host in bucket order (``split_batch``),
+and appends each bucket to one Arrow IPC file per output partition:
 
     <work_dir>/<job_id>/<stage_id>/<output_partition>/data-<input_partition>.arrow
 
@@ -36,9 +36,10 @@ import numpy as np
 import pyarrow as pa
 import pyarrow.ipc as paipc
 
-from ballista_tpu.columnar.arrow_interop import batch_to_arrow
+from ballista_tpu.columnar.arrow_interop import batch_to_arrow, host_to_arrow
 from ballista_tpu.columnar.batch import DeviceBatch
 from ballista_tpu.columnar.coalesce import BatchCoalescer
+from ballista_tpu.compilecache import metrics as compile_metrics
 from ballista_tpu.datatypes import Schema
 from ballista_tpu.errors import ExecutionError
 from ballista_tpu.exec.base import (
@@ -47,9 +48,10 @@ from ballista_tpu.exec.base import (
     TaskContext,
     UnknownPartitioning,
 )
-from ballista_tpu.exec.repartition import jit_partition_ids
+from ballista_tpu.exec.repartition import jit_bucket_counts, jit_partition_ids
 from ballista_tpu.expr import logical as L
 from ballista_tpu.obs import trace as obs_trace
+from ballista_tpu.ops.compact import compact
 from ballista_tpu.ops.fetch import read_array
 from ballista_tpu.ops.partition import string_key_tables
 from ballista_tpu.scheduler_types import ShuffleWritePartitionMeta
@@ -61,6 +63,85 @@ def resolve_file_codec(codec: str) -> str:
     at fetch time (reader.py), so compressing the at-rest bytes would
     only tax colocated readers' zero-copy mmap path."""
     return "none" if codec == "auto" else codec
+
+
+def bucket_order(
+    pids: np.ndarray, num_partitions: int, live: int
+) -> np.ndarray:
+    """The indices of the ``live`` live rows grouped by bucket, in input
+    order within a bucket. ``pids``: a partition id a row,
+    ``num_partitions`` for a dead one, so the dead rows sort last and are
+    cut off.
+
+    One O(n) pass: numpy's stable argsort of an 8- or 16-bit key is a
+    radix sort, so the ids narrow to the smallest type that holds the drop
+    bucket first."""
+    if num_partitions < 1 << 8:
+        pids = pids.astype(np.uint8)
+    elif num_partitions < 1 << 16:
+        pids = pids.astype(np.uint16)
+    return np.argsort(pids, kind="stable")[:live]
+
+
+def split_batch(
+    batch: DeviceBatch, pids, num_partitions: int, site: str
+) -> tuple[pa.RecordBatch, np.ndarray] | None:
+    """One device batch's live rows among ``num_partitions`` output
+    partitions: (the rows in bucket order as one Arrow batch, the
+    ``num_partitions + 1`` bounds of the buckets in it), or None when no
+    row is live. Within a bucket the rows keep their input order.
+
+    ``pids``: the batch's partition ids on the device, ``num_partitions``
+    for a dead row (``jit_partition_ids``); the device counts each bucket's
+    rows. The fetch follows ``DeviceBatch.to_host``'s rule. A batch fetched
+    whole is one read at ``site`` of its ids, counts, columns and null
+    masks; the host orders the ids (``bucket_order``) and gathers each
+    column once. A large batch reads its counts first (``<site>.count``);
+    at most a quarter live, the device's compaction sorts its rows by id,
+    and the host reads the head of that and only slices it. Dictionaries
+    are decoded after the gather, and a null slot holds what a ``take``
+    leaves there: the bytes of the split by a host sort and ``take`` that
+    this replaced."""
+    compile_metrics.add("shuffle.split_batches")
+    counts = jit_bucket_counts(num_partitions)(pids)
+    if batch.sliced_fetch():
+        bounds = _bucket_bounds(read_array(counts, f"{site}.count"))
+        n = int(bounds[-1])
+        if n == 0:
+            return None
+        if batch.compacts_for(n):
+            compile_metrics.add("shuffle.split_device_ordered")
+            head = compact(batch, key=pids).head(batch.head_rows(n))
+            cols, nulls, _ = head.fetch_host(site)
+            rb = host_to_arrow(
+                batch.schema,
+                [c[:n] for c in cols],
+                [None if m is None else m[:n] for m in nulls],
+                batch.dictionaries,
+                take_layout=True,
+            )
+            return rb, bounds
+    cols, nulls, (ids, counts) = batch.fetch_host(site, (pids, counts))
+    bounds = _bucket_bounds(counts)
+    if not bounds[-1]:
+        return None
+    with obs_trace.phase("task.shuffle_write") as ph:
+        order = bucket_order(ids, num_partitions, int(bounds[-1]))
+        cols = [np.take(c, order) for c in cols]
+        nulls = [None if m is None else np.take(m, order) for m in nulls]
+        ph.nbytes = sum(c.nbytes for c in cols)
+    rb = host_to_arrow(
+        batch.schema, cols, nulls, batch.dictionaries, take_layout=True
+    )
+    return rb, bounds
+
+
+def _bucket_bounds(counts: np.ndarray) -> np.ndarray:
+    """``jit_bucket_counts``' counts, the drop bucket last, to the bounds of
+    the live buckets in bucket order: one more than there are buckets."""
+    bounds = np.zeros(len(counts), dtype=np.int64)
+    np.cumsum(counts[:-1], out=bounds[1:])
+    return bounds
 
 
 class ShuffleWriterExec(ExecutionPlan):
@@ -181,37 +262,25 @@ class ShuffleWriterExec(ExecutionPlan):
                         continue
                     with self.metrics.time("repart_time"):
                         tables = string_key_tables(batch, list(key_idxs))
-                        pids = read_array(
-                            jit_partition_ids(
-                                key_idxs, self.output_partitions
-                            )(batch, tables),
-                            "shuffle_write.pids",
-                        )
-                    rb = batch_to_arrow(batch, site="shuffle_write.rows")
-                    live_pids = pids[
-                        read_array(batch.valid, "shuffle_write.valid")
-                    ]
-                    # Single sort-based scatter: ONE stable argsort + ONE
-                    # gather into bucket order, then zero-copy slices per
-                    # bucket — the per-unique-pid rb.take loop re-walked
-                    # every column's buffers once per populated bucket
-                    # (K gathers of the whole batch instead of one).
+                        pids = jit_partition_ids(
+                            key_idxs, self.output_partitions
+                        )(batch, tables)
+                    split = split_batch(
+                        batch, pids, self.output_partitions,
+                        site="shuffle_write.rows",
+                    )
+                    if split is None:
+                        continue
+                    rb, bounds = split
                     with obs_trace.phase(
                         "task.shuffle_write", nbytes=rb.nbytes
                     ):
-                        order = np.argsort(live_pids, kind="stable")
-                        sorted_rb = rb.take(pa.array(order))
-                        sorted_pids = live_pids[order]
-                        bounds = np.searchsorted(
-                            sorted_pids,
-                            np.arange(self.output_partitions + 1),
-                        )
                         for out_part in range(self.output_partitions):
                             lo = int(bounds[out_part])
                             hi = int(bounds[out_part + 1])
                             if hi > lo:
                                 appender(out_part).write(
-                                    sorted_rb.slice(lo, hi - lo)
+                                    rb.slice(lo, hi - lo)
                                 )
         except BaseException:
             # a failed ATTEMPT must leave nothing observable: push streams

@@ -307,6 +307,8 @@ def test_d2h_sites_cover_every_read_and_sum_to_the_total(served):
     assert sum(sites.values()) == c["phase.task.d2h.count"]
     assert {"shuffle_write.rows", "deferred_checks", "operator_metrics",
             "join.build_flags"} <= set(sites), sites
+    # the hash split reads ids, rows and null masks in one round trip
+    assert not {"shuffle_write.pids", "shuffle_write.valid"} & set(sites)
     assert "fetch" not in sites, "a fetch_arrays caller gave no site"
 
 
