@@ -70,6 +70,12 @@ telemetry uses) and keeps process-global counters:
 - ``op.<family>.self_seconds`` — the operators' own time on their task
   threads (``obs.trace.stretch``: each operator's ``self_s``), summed by
   ``OP_FAMILIES`` as a task ends. Declared at 0 likewise.
+- ``subquery.agg_rows`` / ``subquery.agg_groups`` /
+  ``subquery.agg_self_seconds`` — the aggregates that decorrelate a scalar
+  subquery (``HashAggregateExec`` marked ``subquery``): live rows into
+  their partials, groups out of their finals, and their ``self_s``, which
+  ``op.aggregate.self_seconds`` counts too; summed as a task ends.
+  Declared at 0 likewise.
 
 Counters surface per executor through the heartbeat -> scheduler REST
 path (docs/compile_cache.md).
@@ -136,6 +142,11 @@ OP_FAMILIES = {
     "other": (),
 }
 OP_COUNTERS = tuple(f"op.{family}.self_seconds" for family in OP_FAMILIES)
+# the aggregates that decorrelate a scalar subquery (exec/aggregate.py
+# HashAggregateExec.subquery), summed from their metrics as a task ends
+SUBQUERY_COUNTERS = (
+    "subquery.agg_rows", "subquery.agg_groups", "subquery.agg_self_seconds",
+)
 _OP_COUNTER = {
     operator: f"op.{family}.self_seconds"
     for family, operators in OP_FAMILIES.items() for operator in operators
@@ -150,7 +161,7 @@ def op_counter(operator: str) -> str:
 _COUNTERS: dict[str, float] = dict.fromkeys(
     AGG_COUNTERS + HOLISTIC_COUNTERS + DICT_PREDICATE_COUNTERS
     + NONINNER_JOIN_COUNTERS + POLL_COUNTERS + STATUS_COUNTERS
-    + HINT_COUNTERS + SHUFFLE_COUNTERS + OP_COUNTERS, 0
+    + HINT_COUNTERS + SHUFFLE_COUNTERS + OP_COUNTERS + SUBQUERY_COUNTERS, 0
 )
 _INSTALLED = False
 

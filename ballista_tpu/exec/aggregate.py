@@ -645,7 +645,11 @@ _OVERFLOW_MESSAGE = (
 class HashAggregateExec(ExecutionPlan):
     """mode='partial' emits group keys + state columns per input partition;
     mode='final' merges partial outputs into final values (single output
-    partition unless fed by a hash repartition)."""
+    partition unless fed by a hash repartition). ``subquery`` marks the
+    two halves of an aggregate that decorrelates a scalar subquery
+    (``plan.logical.Aggregate.subquery``): the partial counts its live
+    input rows (``subquery_rows``) and the executor sums the marked
+    operators into ``subquery.*`` as a task ends."""
 
     # Max per-batch partial states held live before an incremental fold
     # (see _execute_partial): bounds HBM at wide cardinalities.
@@ -668,6 +672,7 @@ class HashAggregateExec(ExecutionPlan):
         spec: AggSpec | None = None,
         capacity: int | None = None,
         planned_input_schema: Schema | None = None,
+        subquery: bool = False,
     ) -> None:
         super().__init__()
         if mode not in ("partial", "final"):
@@ -677,6 +682,7 @@ class HashAggregateExec(ExecutionPlan):
         self.agg_exprs = list(agg_exprs)
         self.mode = mode
         self.capacity = capacity
+        self.subquery = subquery
         self._jit_cache: dict = {}
         ins = input.schema()
         # Schema the aggregate exprs were planned against (= the partial's
@@ -749,7 +755,9 @@ class HashAggregateExec(ExecutionPlan):
     def describe(self) -> str:
         g = ", ".join(self.spec.group_names)
         a = ", ".join(s.name for s in self.spec.slots)
-        return f"HashAggregateExec(mode={self.mode}): gby=[{g}], aggr=[{a}]"
+        mark = ", subquery" if self.subquery else ""
+        return (f"HashAggregateExec(mode={self.mode}{mark}): gby=[{g}], "
+                f"aggr=[{a}]")
 
     # -- execution -----------------------------------------------------------
     def _agg_capacity(self, ctx: TaskContext) -> int:
@@ -1048,6 +1056,7 @@ class HashAggregateExec(ExecutionPlan):
             # scalar aggregate: one-row state per partition
             states: list[DeviceBatch] = []
             for b in pre.execute(partition, ctx):
+                self._count_subquery_rows(b)
                 with self.metrics.time("agg_time"):
                     states.append(self._scalar_state_fn()(b))
             if not states:
@@ -1139,6 +1148,7 @@ class HashAggregateExec(ExecutionPlan):
         # _FOLD_WIDTH at the cost of re-merging already-folded groups
         # (merge ops are associative).
         for b in pre.execute(partition, ctx):
+            self._count_subquery_rows(b)
             with self.metrics.time("agg_time"):
                 # per-batch states come out at min(cap, batch capacity)
                 # (_run_group_agg clamps internally) — a batch of N rows
@@ -1232,6 +1242,13 @@ class HashAggregateExec(ExecutionPlan):
             out = fold(partials)
             out.keys_unique = True
             yield out
+
+    def _count_subquery_rows(self, b: DeviceBatch) -> None:
+        """A decorrelating partial's live input rows, as a lazy device
+        scalar that ``Metrics.summary`` resolves with the task's other
+        counters."""
+        if self.subquery:
+            self.metrics.add("subquery_rows", b.valid.sum())
 
     def _spec_cache_key(self) -> tuple:
         """Canonical signature of the scalar-aggregate programs: the spec

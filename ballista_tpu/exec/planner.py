@@ -294,7 +294,8 @@ class PhysicalPlanner:
                 self.mesh_runtime,
             )
         partial = HashAggregateExec(
-            child, list(node.group_exprs), list(node.agg_exprs), mode="partial"
+            child, list(node.group_exprs), list(node.agg_exprs),
+            mode="partial", subquery=node.subquery,
         )
         if node.group_exprs and self._repartition_aggregations():
             # hash-exchange the partial states on the group keys: the final
@@ -313,6 +314,7 @@ class PhysicalPlanner:
             merged, list(node.group_exprs), list(node.agg_exprs),
             mode="final", spec=partial.spec,
             planned_input_schema=partial.planned_input_schema,
+            subquery=node.subquery,
         )
 
     def _plan_join(self, node: P.Join) -> ExecutionPlan:

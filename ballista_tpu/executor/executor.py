@@ -487,13 +487,24 @@ class Executor:
                     for name in ("probe_rows", "unmatched_rows")
                 ]
             )
-        # and each operator's own time on the task's threads, by family
+        # and each operator's own time on the task's threads, by family; the
+        # aggregates that decorrelate a scalar subquery also apart: rows into
+        # their partials, groups out of their finals, their own time
         own: dict[str, float] = {}
         for r in op_metrics or ():
-            seconds = r["counters"].get("self_s")
-            if seconds:
-                key = compile_metrics.op_counter(r["operator"])
-                own[key] = own.get(key, 0.0) + seconds
+            c = r["counters"]
+            moved = [(compile_metrics.op_counter(r["operator"]),
+                      c.get("self_s", 0.0))]
+            if r.get("subquery"):
+                moved += [
+                    ("subquery.agg_rows", c.get("subquery_rows", 0)),
+                    ("subquery.agg_groups", c.get("output_rows", 0)
+                     if "mode=final" in r["describe"] else 0),
+                    ("subquery.agg_self_seconds", c.get("self_s", 0.0)),
+                ]
+            for key, value in moved:
+                if value:
+                    own[key] = own.get(key, 0) + value
         if own:
             compile_metrics.add_many(own.items())
         # cost accounting (docs/observability.md): this attempt's

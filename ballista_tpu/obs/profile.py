@@ -153,7 +153,9 @@ def walk_paths(plan):
 def operator_metrics(plan) -> list[dict]:
     """Per-operator metric records for one executed plan tree — the
     payload the ShippingMetricsCollector sends home. Device-scalar
-    counters resolve here (one sync, at report time)."""
+    counters resolve here (one sync, at report time). ``subquery`` marks
+    the operators of an aggregate that decorrelates a scalar subquery;
+    the executor reads it and it does not ship."""
     out = []
     for path, node in walk_paths(plan):
         out.append(
@@ -162,6 +164,7 @@ def operator_metrics(plan) -> list[dict]:
                 "operator": type(node).__name__,
                 "describe": node.describe(),
                 "counters": node.metrics.summary(),
+                "subquery": getattr(node, "subquery", False),
             }
         )
     return out

@@ -146,11 +146,16 @@ class Filter(LogicalPlan):
 @dataclasses.dataclass(frozen=True, eq=False)
 class Aggregate(LogicalPlan):
     """GROUP BY. Output schema = group exprs then aggregate exprs
-    (DataFusion's column order, which the reference's stage tests rely on)."""
+    (DataFusion's column order, which the reference's stage tests rely on).
+    ``subquery`` marks the aggregate the SQL planner introduces to
+    decorrelate a scalar subquery (grouped by its correlation keys): the
+    executor counts its work apart (``subquery.*``, docs/observability.md);
+    it changes nothing of how the aggregate runs."""
 
     input: LogicalPlan
     group_exprs: tuple[L.Expr, ...]
     agg_exprs: tuple[L.Expr, ...]  # each contains >=1 AggregateExpr
+    subquery: bool = False
 
     def schema(self) -> Schema:
         ins = self.input.schema()
@@ -168,12 +173,13 @@ class Aggregate(LogicalPlan):
         return [self.input]
 
     def with_children(self, children: list[LogicalPlan]) -> "Aggregate":
-        return Aggregate(children[0], self.group_exprs, self.agg_exprs)
+        return dataclasses.replace(self, input=children[0])
 
     def describe(self) -> str:
         g = ", ".join(e.name() for e in self.group_exprs)
         a = ", ".join(e.name() for e in self.agg_exprs)
-        return f"Aggregate: groupBy=[{g}], aggr=[{a}]"
+        mark = ", subquery" if self.subquery else ""
+        return f"Aggregate: groupBy=[{g}], aggr=[{a}]{mark}"
 
 
 @dataclasses.dataclass(frozen=True, eq=False)

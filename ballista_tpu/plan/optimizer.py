@@ -17,6 +17,7 @@ rules the TPC-H plans actually need:
 from __future__ import annotations
 
 import calendar
+import dataclasses
 import datetime
 
 from ballista_tpu.datatypes import DataType, Schema
@@ -161,7 +162,7 @@ def split_percentiles(plan: LogicalPlan) -> LogicalPlan:
     if rest:
         # base aggregate keeps the ORIGINAL group exprs (real NULLs in
         # its output keys); a projection adds the null-safe join pair
-        base = Aggregate(plan.input, plan.group_exprs, rest)
+        base = dataclasses.replace(plan, agg_exprs=rest)
         base_cols = [L.Column(f.name) for f in base.schema()]
         bz: list[L.Alias] = []
         for i, (g, nl) in enumerate(zip(plan.group_exprs, nullable)):
@@ -227,10 +228,10 @@ def map_plan_expressions(plan: LogicalPlan, fn) -> LogicalPlan:
     if isinstance(plan, Filter):
         return Filter(plan.input, _rw(plan.predicate, fn))
     if isinstance(plan, Aggregate):
-        return Aggregate(
-            plan.input,
-            tuple(_rw(e, fn) for e in plan.group_exprs),
-            tuple(_rw(e, fn) for e in plan.agg_exprs),
+        return dataclasses.replace(
+            plan,
+            group_exprs=tuple(_rw(e, fn) for e in plan.group_exprs),
+            agg_exprs=tuple(_rw(e, fn) for e in plan.agg_exprs),
         )
     if isinstance(plan, Sort):
         return Sort(
@@ -762,7 +763,7 @@ def _prune(plan: LogicalPlan, required: set[str] | None) -> LogicalPlan:
         return Filter(_prune(plan.input, need), plan.predicate)
     if isinstance(plan, Aggregate):
         need = _expr_columns(plan.group_exprs) | _expr_columns(plan.agg_exprs)
-        return Aggregate(_prune(plan.input, need), plan.group_exprs, plan.agg_exprs)
+        return dataclasses.replace(plan, input=_prune(plan.input, need))
     if isinstance(plan, Sort):
         need = (
             None

@@ -343,6 +343,7 @@ def logical_to_proto(plan: P.LogicalPlan) -> pb.LogicalPlanNode:
                 input=logical_to_proto(plan.input),
                 group_exprs=[expr_to_proto(e) for e in plan.group_exprs],
                 agg_exprs=[expr_to_proto(e) for e in plan.agg_exprs],
+                subquery=plan.subquery,
             )
         )
     if isinstance(plan, P.Sort):
@@ -509,6 +510,7 @@ def logical_from_proto(p: pb.LogicalPlanNode) -> P.LogicalPlan:
             logical_from_proto(p.aggregate.input),
             tuple(expr_from_proto(e) for e in p.aggregate.group_exprs),
             tuple(expr_from_proto(e) for e in p.aggregate.agg_exprs),
+            p.aggregate.subquery,
         )
     if kind == "sort":
         return P.Sort(
@@ -655,6 +657,7 @@ class BallistaCodec:
                     mode=plan.mode,
                     capacity=plan.capacity or 0,
                     input_schema=schema_to_proto(plan.planned_input_schema),
+                    subquery=plan.subquery,
                 )
             )
         if isinstance(plan, SortExec):
@@ -950,6 +953,7 @@ class BallistaCodec:
                 spec=spec if n.mode == "final" else None,
                 capacity=n.capacity or None,
                 planned_input_schema=input_schema,
+                subquery=n.subquery,
             )
         if kind == "sort":
             n = p.sort

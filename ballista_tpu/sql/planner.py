@@ -583,6 +583,11 @@ class SqlPlanner:
             plan, projections, _ = self._plan_aggregate(
                 plan, group_cols, projections, None, {}
             )
+            if inner_corr_names:
+                # the aggregate grouped by the correlation keys: marked, so
+                # that its work is counted apart (subquery.*); it runs as
+                # any other
+                plan = dataclasses.replace(plan, subquery=True)
             # projections now reference agg outputs; append correlation keys
             proj_exprs = list(projections) + [
                 L.Column(n) for n in inner_corr_names

@@ -141,6 +141,71 @@ def test_q17_correlated_scalar(planner):
     walk(plan)
     # correlated avg subquery becomes an INNER join on l_partkey=p_partkey
     assert any("__sq" in str(j.on) for j in inner_joins)
+    # the aggregate the decorrelation introduced is marked, and no other
+    # (the outer SUM is not): through the optimizer and the logical serde
+    from ballista_tpu.plan.optimizer import optimize
+    from ballista_tpu.serde import logical_from_proto, logical_to_proto
+
+    def aggregates(p):
+        out = [p] if isinstance(p, Aggregate) else []
+        for c in p.children():
+            out += aggregates(c)
+        return out
+
+    for p in (plan, optimize(plan),
+              logical_from_proto(logical_to_proto(optimize(plan)))):
+        marks = {(tuple(g.name() for g in a.group_exprs), a.subquery)
+                 for a in aggregates(p)}
+        assert marks == {((), False), (("l_partkey",), True)}, marks
+        assert "Aggregate: groupBy=[l_partkey], aggr=[AVG(l_quantity)], " \
+               "subquery" in p.display()
+
+
+def test_q17_subquery_mark_survives_serde_to_the_executor():
+    """Both halves of the decorrelating aggregate carry the mark into the
+    physical plan, and through the codec that ships a stage to an executor;
+    q1's aggregate, which decorrelates nothing, carries none, nor q22's
+    uncorrelated aggregate subquery."""
+    from ballista_tpu.distributed_plan import DistributedPlanner
+    from ballista_tpu.exec.aggregate import HashAggregateExec
+    from ballista_tpu.exec.context import TpuContext
+    from ballista_tpu.exec.planner import PhysicalPlanner
+    from ballista_tpu.plan.optimizer import optimize
+    from ballista_tpu.serde import BallistaCodec
+    from ballista_tpu.tpch import gen_all
+
+    ctx = TpuContext()
+    for name, table in gen_all(scale=0.001).items():
+        ctx.register_table(name, table)
+    codec = BallistaCodec(ctx)
+
+    def shipped_aggregates(q):
+        logical = optimize(ctx.sql_to_logical((QUERIES / f"{q}.sql").read_text()))
+        phys = PhysicalPlanner(ctx, 2, config=ctx.config,
+                               distributed=True).plan(logical)
+        out = []
+        for stage in DistributedPlanner().plan_query_stages(f"job-{q}", phys):
+            node = codec.physical_to_proto(stage.plan)
+            wire = type(node).FromString(node.SerializeToString())
+
+            def walk(p):
+                if isinstance(p, HashAggregateExec):
+                    out.append((p.mode, tuple(p.spec.group_names), p.subquery))
+                for c in p.children():
+                    walk(c)
+
+            walk(codec.physical_from_proto(wire))
+        return out
+
+    q17 = shipped_aggregates("q17")
+    assert sorted(m for m in q17 if m[2]) == [
+        ("final", ("l_partkey",), True), ("partial", ("l_partkey",), True)]
+    assert all(not m[2] for m in q17 if m[1] != ("l_partkey",))
+    assert len(q17) == 4  # the outer SUM's two halves, unmarked
+    assert not any(m[2] for m in shipped_aggregates("q1"))
+    # q22's AVG(c_acctbal) subquery is uncorrelated: an ordinary SELECT,
+    # not grouped by any key of the outer query, so not marked
+    assert not any(m[2] for m in shipped_aggregates("q22"))
 
 
 def test_q4_exists_to_semi(planner):
