@@ -305,6 +305,8 @@ def _logical_exemplars() -> dict[str, list[P.LogicalPlan]]:
                 filter=L.BinaryExpr(_COLB, L.Operator.LT, L.Column("w")),
             ),
             P.Join(scan, scan2, ((_COL, L.Column("k")),), P.JoinType.ANTI),
+            P.Join(scan, scan2, ((_COL, L.Column("k")),), P.JoinType.SEMI,
+                   reduction=True),
         ],
         "CrossJoin": [P.CrossJoin(scan, scan2)],
         "Union": [P.Union((scan, scan), all=True), P.Union((scan, scan), all=False)],
@@ -452,6 +454,7 @@ def _physical_exemplars(ctx):
             HashRepartitionExec(mem2(), [L.Column("k")], 4),
             join_on, P.JoinType.SEMI, partition_mode="partitioned",
         ),
+        HashJoinExec(mem(), mem2(), join_on, P.JoinType.SEMI, reduction=True),
         HashRepartitionExec(mem(), [_COL, _COLB], 3),
         CrossJoinExec(mem(), mem2()),
         UnionExec([mem(), mem()]),

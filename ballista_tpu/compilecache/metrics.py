@@ -75,7 +75,9 @@ telemetry uses) and keeps process-global counters:
   subquery (``HashAggregateExec`` marked ``subquery``): live rows into
   their partials, groups out of their finals, and their ``self_s``, which
   ``op.aggregate.self_seconds`` counts too; summed as a task ends.
-  Declared at 0 likewise.
+  ``subquery.agg_reduced`` — +1 a task that ran such a partial over an
+  input cut by its reduction (the semi join to the outer query's key
+  domain, ``HashJoinExec`` marked ``reduction``). Declared at 0 likewise.
 
 Counters surface per executor through the heartbeat -> scheduler REST
 path (docs/compile_cache.md).
@@ -146,6 +148,7 @@ OP_COUNTERS = tuple(f"op.{family}.self_seconds" for family in OP_FAMILIES)
 # HashAggregateExec.subquery), summed from their metrics as a task ends
 SUBQUERY_COUNTERS = (
     "subquery.agg_rows", "subquery.agg_groups", "subquery.agg_self_seconds",
+    "subquery.agg_reduced",
 )
 _OP_COUNTER = {
     operator: f"op.{family}.self_seconds"

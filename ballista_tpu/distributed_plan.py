@@ -174,7 +174,8 @@ class DistributedPlanner:
             # the collected (build) side becomes its own single-output stage
             right = self._materialize_collected(job_id, right, stages)
             return HashJoinExec(
-                left, right, plan.on, plan.join_type, plan.filter
+                left, right, plan.on, plan.join_type, plan.filter,
+                reduction=plan.reduction,
             )
 
         return replace_children(plan, children)

@@ -130,12 +130,16 @@ class HashJoinExec(ExecutionPlan):
         join_type: JoinType,
         filter: L.Expr | None = None,
         partition_mode: str = "collect",
+        reduction: bool = False,
     ) -> None:
         """``partition_mode``: "collect" broadcasts the whole build side to
         every probe task (the reference's COLLECT_LEFT); "partitioned"
         assumes BOTH inputs are hash-partitioned on the join keys (the
         planner inserts HashRepartitionExec) and each task joins only its
-        bucket (ref PartitionMode, ballista.proto:474-487)."""
+        bucket (ref PartitionMode, ballista.proto:474-487). ``reduction``
+        marks the semi join below a decorrelated subquery's aggregate
+        (``plan.logical.Join.reduction``): it runs as any other, and the
+        executor counts the aggregates it feeds (``subquery.agg_reduced``)."""
         super().__init__()
         if partition_mode not in ("collect", "partitioned"):
             raise PlanError(f"bad join partition mode {partition_mode!r}")
@@ -145,6 +149,7 @@ class HashJoinExec(ExecutionPlan):
         self.join_type = join_type
         self.filter = filter
         self.partition_mode = partition_mode
+        self.reduction = reduction
         self._build_cache: dict = {}
         # build-strategy flags (dups/overflow of the collected right side)
         # are partition-invariant: compute once, reuse across partitions
@@ -177,9 +182,10 @@ class HashJoinExec(ExecutionPlan):
     def describe(self) -> str:
         on = ", ".join(f"{a.name()} = {b.name()}" for a, b in self.on)
         f = f", filter={self.filter.name()}" if self.filter is not None else ""
+        mark = ", reduction" if self.reduction else ""
         return (
             f"HashJoinExec({self.join_type.value}, "
-            f"{self.partition_mode}): on=[{on}]{f}"
+            f"{self.partition_mode}{mark}): on=[{on}]{f}"
         )
 
     # -- dictionaries ---------------------------------------------------------

@@ -359,6 +359,12 @@ class PhysicalPlanner:
             )
         left = self._plan(node.left)
         right = self._plan(node.right)
+        if node.reduction:
+            # the semi join below a decorrelated subquery's aggregate: its
+            # domain is small, its probe side the subquery's scan. Collected,
+            # the probe runs inside the scan's stage and no probe row is
+            # exchanged before the partial aggregate
+            return self._collected_existence_join(left, right, node)
         if self.mesh_runtime is not None and (
             jt == P.JoinType.INNER
             or (
@@ -407,16 +413,24 @@ class PhysicalPlanner:
                 partition_mode="partitioned",
             )
         if jt in (P.JoinType.SEMI, P.JoinType.ANTI) and node.filter is None:
-            # The kernel needs a unique build side; existence semantics allow
-            # dedup on the join keys (ref HashJoinExec handles dup builds
-            # natively — our sort-probe kernel dedups instead).
-            keys = [b for _, b in node.on]
-            dpartial = HashAggregateExec(right, keys, [], mode="partial")
-            right = HashAggregateExec(
-                CoalescePartitionsExec(dpartial), keys, [],
-                mode="final", spec=dpartial.spec,
-                planned_input_schema=dpartial.planned_input_schema,
-            )
-            on = [(a, L.Column(k.name())) for (a, _), k in zip(node.on, keys)]
-            return HashJoinExec(left, right, on, jt, None)
+            return self._collected_existence_join(left, right, node)
         return HashJoinExec(left, right, list(node.on), jt, node.filter)
+
+    def _collected_existence_join(
+        self, left: ExecutionPlan, right: ExecutionPlan, node: P.Join
+    ) -> ExecutionPlan:
+        """A SEMI or ANTI join without a residual filter in collect mode.
+        The kernel needs a unique build side; existence semantics allow
+        dedup on the join keys (ref HashJoinExec handles dup builds
+        natively — our sort-probe kernel dedups instead)."""
+        keys = [b for _, b in node.on]
+        dpartial = HashAggregateExec(right, keys, [], mode="partial")
+        right = HashAggregateExec(
+            CoalescePartitionsExec(dpartial), keys, [],
+            mode="final", spec=dpartial.spec,
+            planned_input_schema=dpartial.planned_input_schema,
+        )
+        on = [(a, L.Column(k.name())) for (a, _), k in zip(node.on, keys)]
+        return HashJoinExec(
+            left, right, on, node.join_type, None, reduction=node.reduction
+        )

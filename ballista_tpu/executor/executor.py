@@ -489,8 +489,11 @@ class Executor:
             )
         # and each operator's own time on the task's threads, by family; the
         # aggregates that decorrelate a scalar subquery also apart: rows into
-        # their partials, groups out of their finals, their own time
+        # their partials, groups out of their finals, their own time, and
+        # the task once if a partial's input passed through a reduction
         own: dict[str, float] = {}
+        reductions = [r["path"] for r in op_metrics or ()
+                      if r.get("reduction")]
         for r in op_metrics or ():
             c = r["counters"]
             moved = [(compile_metrics.op_counter(r["operator"]),
@@ -502,6 +505,9 @@ class Executor:
                      if "mode=final" in r["describe"] else 0),
                     ("subquery.agg_self_seconds", c.get("self_s", 0.0)),
                 ]
+                if "mode=partial" in r["describe"] and any(
+                        p.startswith(r["path"] + ".") for p in reductions):
+                    own["subquery.agg_reduced"] = 1
             for key, value in moved:
                 if value:
                     own[key] = own.get(key, 0) + value

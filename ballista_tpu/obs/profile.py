@@ -154,8 +154,9 @@ def operator_metrics(plan) -> list[dict]:
     """Per-operator metric records for one executed plan tree — the
     payload the ShippingMetricsCollector sends home. Device-scalar
     counters resolve here (one sync, at report time). ``subquery`` marks
-    the operators of an aggregate that decorrelates a scalar subquery;
-    the executor reads it and it does not ship."""
+    the operators of an aggregate that decorrelates a scalar subquery, and
+    ``reduction`` the semi join below one; the executor reads them and
+    they do not ship."""
     out = []
     for path, node in walk_paths(plan):
         out.append(
@@ -165,6 +166,7 @@ def operator_metrics(plan) -> list[dict]:
                 "describe": node.describe(),
                 "counters": node.metrics.summary(),
                 "subquery": getattr(node, "subquery", False),
+                "reduction": getattr(node, "reduction", False),
             }
         )
     return out

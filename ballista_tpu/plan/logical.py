@@ -227,13 +227,18 @@ class Limit(LogicalPlan):
 class Join(LogicalPlan):
     """Equi-join with optional residual filter (non-equi condition applied
     post-match), like DataFusion's Join { on, filter } (ballista.proto
-    JoinNode)."""
+    JoinNode). ``reduction`` marks the semi join the optimizer puts below
+    a decorrelated subquery's aggregate to keep only the groups its outer
+    query can join (``plan.optimizer.reduce_subquery_aggregates``): it is
+    planned in collect mode, and the executor counts the aggregates it
+    feeds (``subquery.agg_reduced``)."""
 
     left: LogicalPlan
     right: LogicalPlan
     on: tuple[tuple[L.Expr, L.Expr], ...]  # (left_key, right_key) pairs
     join_type: JoinType
     filter: L.Expr | None = None
+    reduction: bool = False
 
     def schema(self) -> Schema:
         if self.join_type in (JoinType.SEMI, JoinType.ANTI):
@@ -250,12 +255,13 @@ class Join(LogicalPlan):
         return [self.left, self.right]
 
     def with_children(self, children: list[LogicalPlan]) -> "Join":
-        return Join(children[0], children[1], self.on, self.join_type, self.filter)
+        return dataclasses.replace(self, left=children[0], right=children[1])
 
     def describe(self) -> str:
         on = ", ".join(f"{a.name()} = {b.name()}" for a, b in self.on)
         f = f" filter={self.filter.name()}" if self.filter is not None else ""
-        return f"Join({self.join_type.value}): on=[{on}]{f}"
+        mark = ", reduction" if self.reduction else ""
+        return f"Join({self.join_type.value}{mark}): on=[{on}]{f}"
 
 
 @dataclasses.dataclass(frozen=True, eq=False)

@@ -11,7 +11,9 @@ rules the TPC-H plans actually need:
    a greedy left-deep equi-join tree (every TPC-H query is written with
    comma joins)
 3. predicate pushdown through projections/aliases/joins into scans
-4. projection pushdown (column pruning) into scans
+4. semi-join reduction of a decorrelated subquery's aggregate by its outer
+   query's key domain
+5. projection pushdown (column pruning) into scans
 """
 
 from __future__ import annotations
@@ -21,7 +23,7 @@ import dataclasses
 import datetime
 
 from ballista_tpu.datatypes import DataType, Schema
-from ballista_tpu.errors import PlanError
+from ballista_tpu.errors import PlanError, SchemaError
 from ballista_tpu.expr import logical as L
 from ballista_tpu.plan.logical import (
     Aggregate,
@@ -55,6 +57,8 @@ def optimize(plan: LogicalPlan) -> LogicalPlan:
     plan = push_down_filters(plan)
     plan = eliminate_cross_joins(plan)
     plan = push_down_filters(plan)
+    # the outer query's filters have landed: its key domains are known
+    plan = reduce_subquery_aggregates(plan)
     plan = split_percentiles(plan)
     plan = prune_columns(plan)
     return plan
@@ -242,12 +246,10 @@ def map_plan_expressions(plan: LogicalPlan, fn) -> LogicalPlan:
             ),
         )
     if isinstance(plan, Join):
-        return Join(
-            plan.left,
-            plan.right,
-            tuple((_rw(a, fn), _rw(b, fn)) for a, b in plan.on),
-            plan.join_type,
-            _rw(plan.filter, fn) if plan.filter is not None else None,
+        return dataclasses.replace(
+            plan,
+            on=tuple((_rw(a, fn), _rw(b, fn)) for a, b in plan.on),
+            filter=_rw(plan.filter, fn) if plan.filter is not None else None,
         )
     if isinstance(plan, TableScan) and plan.filters:
         return TableScan(
@@ -561,8 +563,9 @@ def _push_on_conjuncts(plan: Join) -> LogicalPlan:
             if not_pushed:
                 side = Filter(side, _conjoin(not_pushed))
         sides.append(side)
-    return Join(
-        sides[0], sides[1], plan.on, jt, _conjoin(kept) if kept else None
+    return dataclasses.replace(
+        plan, left=sides[0], right=sides[1],
+        filter=_conjoin(kept) if kept else None,
     )
 
 
@@ -654,10 +657,7 @@ def _push_conjuncts(
             if np_:
                 right = Filter(right, _conjoin(np_))
         if isinstance(plan, Join):
-            return (
-                Join(left, right, plan.on, plan.join_type, plan.filter),
-                kept,
-            )
+            return plan.with_children([left, right]), kept
         return CrossJoin(left, right), kept
     if isinstance(plan, TableScan):
         return (
@@ -705,7 +705,153 @@ def _rewrite_through(
     return e.with_children(new_kids)
 
 
-# -- rule 4: column pruning ---------------------------------------------------
+# -- rule 4: semi-join reduction of decorrelated subquery aggregates ---------
+
+
+def reduce_subquery_aggregates(plan: LogicalPlan) -> LogicalPlan:
+    """The aggregate that decorrelates a scalar subquery
+    (``Aggregate.subquery``: grouped by the correlation keys, inner-joined
+    back to the outer query on them) groups only the keys the outer query
+    can join: a semi join of its input to the outer query's domain of one
+    correlation key goes below it (the "magic" rewrite, Seshadri et al.,
+    SIGMOD 1996). The domain is the smallest subtree of the outer side with
+    a column equal to that key, directly or through the outer's inner and
+    semi equi-joins, that applies a predicate (a filter or a semi join):
+    q17's brand- and container-filtered ``part``. The reduction's key is a
+    group key, so a group keeps all of its rows or none, and every group
+    the join back can match is kept: the answer is the same. Where no such
+    subtree exists the domain would be a whole table, and the plan stays as
+    it is."""
+    kids = [reduce_subquery_aggregates(c) for c in plan.children()]
+    plan = plan.with_children(kids) if kids else plan
+    # the SQL planner joins the subquery back as the right side
+    if not (isinstance(plan, Join) and plan.join_type == JoinType.INNER):
+        return plan
+    found = None
+    for outer_key, sub_key in plan.on:
+        marked = _subquery_group_key(plan.right, sub_key)
+        i = _field_index(plan.left.schema(), outer_key)
+        if marked is None or i is None:
+            continue
+        agg, key = marked
+        key_type = agg.input.schema().fields[key].dtype
+        for domain, j in _key_domains(plan.left, i):
+            if (
+                domain.schema().fields[j].dtype == key_type
+                and _applies_predicate(domain)
+                and (found is None or _size(domain) < found[0])
+            ):
+                found = (_size(domain), agg, key, domain, j)
+    if found is None:
+        return plan
+    _, agg, key, domain, j = found
+    reduced = dataclasses.replace(agg, input=Join(
+        agg.input, domain,
+        ((L.Column(agg.input.schema().fields[key].name),
+          L.Column(domain.schema().fields[j].name)),),
+        JoinType.SEMI, reduction=True,
+    ))
+    return plan.with_children(
+        [plan.left, _replace_in_chain(plan.right, agg, reduced)]
+    )
+
+
+def _field_index(schema: Schema, e: L.Expr) -> int | None:
+    if not isinstance(e, L.Column):
+        return None
+    try:
+        return L.resolve_field_index(schema, e.cname)
+    except SchemaError:
+        return None
+
+
+def _subquery_group_key(
+    plan: LogicalPlan, e: L.Expr
+) -> tuple[Aggregate, int] | None:
+    """The marked aggregate under ``plan`` (through aliases, filters and
+    projections of columns) and the index, in its input's schema, of the
+    group key that ``plan``'s column ``e`` carries; None if ``e`` is no
+    such key."""
+    i = _field_index(plan.schema(), e)
+    while i is not None:
+        if isinstance(plan, (SubqueryAlias, Filter)):
+            plan = plan.input
+        elif isinstance(plan, Projection):
+            p = plan.exprs[i]
+            plan = plan.input
+            i = _field_index(
+                plan.schema(), p.expr if isinstance(p, L.Alias) else p
+            )
+        elif isinstance(plan, Aggregate) and plan.subquery:
+            if i >= len(plan.group_exprs):
+                return None
+            key = _field_index(plan.input.schema(), plan.group_exprs[i])
+            return None if key is None else (plan, key)
+        else:
+            return None
+    return None
+
+
+def _key_domains(plan: LogicalPlan, i: int):
+    """``(subtree, field index)``: ``plan`` itself, then each subtree below
+    it whose column holds, for every row of ``plan``, the value of
+    ``plan``'s field ``i``: through filters, aliases, sorts, projections of
+    the column, and the equi-keys of inner and semi joins."""
+    yield plan, i
+    if isinstance(plan, (Filter, SubqueryAlias, Sort, Limit, Distinct)):
+        yield from _key_domains(plan.children()[0], i)
+    elif isinstance(plan, Projection):
+        p = plan.exprs[i]
+        j = _field_index(
+            plan.input.schema(), p.expr if isinstance(p, L.Alias) else p
+        )
+        if j is not None:
+            yield from _key_domains(plan.input, j)
+    elif isinstance(plan, Join) and plan.join_type in (
+        JoinType.INNER, JoinType.SEMI,
+    ):
+        ls, rs = plan.left.schema(), plan.right.schema()
+        # equal columns of the two sides, grown through the equi-keys
+        equal = ({i}, set()) if i < len(ls) else (set(), {i - len(ls)})
+        pairs = [
+            (_field_index(ls, a), _field_index(rs, b)) for a, b in plan.on
+        ]
+        grown = True
+        while grown:
+            grown = False
+            for a, b in pairs:
+                if a is None or b is None:
+                    continue
+                if (a in equal[0]) != (b in equal[1]):
+                    equal[0].add(a)
+                    equal[1].add(b)
+                    grown = True
+        for side, at in zip((plan.left, plan.right), equal):
+            for j in sorted(at):
+                yield from _key_domains(side, j)
+
+
+def _applies_predicate(plan: LogicalPlan) -> bool:
+    if isinstance(plan, Filter) or (
+        isinstance(plan, TableScan) and plan.filters
+    ) or (isinstance(plan, Join) and plan.join_type == JoinType.SEMI):
+        return True
+    return any(_applies_predicate(c) for c in plan.children())
+
+
+def _size(plan: LogicalPlan) -> int:
+    return 1 + sum(_size(c) for c in plan.children())
+
+
+def _replace_in_chain(
+    plan: LogicalPlan, old: LogicalPlan, new: LogicalPlan
+) -> LogicalPlan:
+    if plan is old:
+        return new
+    return plan.with_children([_replace_in_chain(plan.children()[0], old, new)])
+
+
+# -- rule 5: column pruning ---------------------------------------------------
 
 
 def prune_columns(plan: LogicalPlan) -> LogicalPlan:

@@ -370,6 +370,7 @@ def logical_to_proto(plan: P.LogicalPlan) -> pb.LogicalPlanNode:
                 for a, b in plan.on
             ],
             join_type=getattr(pb, f"JOIN_{plan.join_type.name}"),
+            reduction=plan.reduction,
         )
         if plan.filter is not None:
             node.filter.CopyFrom(expr_to_proto(plan.filter))
@@ -534,6 +535,7 @@ def logical_from_proto(p: pb.LogicalPlanNode) -> P.LogicalPlan:
             ),
             P.JoinType[pb.JoinTypeP.Name(n.join_type)[5:]],
             expr_from_proto(n.filter) if n.HasField("filter") else None,
+            n.reduction,
         )
     if kind == "cross_join":
         return P.CrossJoin(
@@ -688,6 +690,7 @@ class BallistaCodec:
                 ],
                 join_type=getattr(pb, f"JOIN_{plan.join_type.name}"),
                 partition_mode=plan.partition_mode,
+                reduction=plan.reduction,
             )
             if plan.filter is not None:
                 node.filter.CopyFrom(expr_to_proto(plan.filter))
@@ -980,6 +983,7 @@ class BallistaCodec:
                 P.JoinType[pb.JoinTypeP.Name(n.join_type)[5:]],
                 expr_from_proto(n.filter) if n.HasField("filter") else None,
                 partition_mode=n.partition_mode or "collect",
+                reduction=n.reduction,
             )
         if kind == "repartition":
             n = p.repartition

@@ -40,6 +40,8 @@ TEMPLATES = ["q17", "q20"]
 IN_THE_CELL = ["q20", "q17"]
 BROUGHT = ["subquery_agg_rows_per_query", "subquery_agg_groups_per_query",
            "subquery_agg_self_ms_per_query"]
+# and the one its aggregates' reduction brought after them
+REDUCED = "subquery_agg_reduced_per_query"
 SEED = 4_000_000_017  # at SF 0.02 every draw of the pool keeps lines
 
 
@@ -70,14 +72,17 @@ def test_the_cell_is_tpch_sf1_mem_cut_in_queries_only():
     assert cfg["queries"] == len(mix["templates"]) <= cfg["queries_published"]
     assert (mix["clients"], mix["pool"], mix["param_seed"], mix["order"]) == (
         1, 2, 40, "shuffled")
-    # the three per-layer metrics this cell brought: every cell's, each with
-    # a reader, appended together after what was there
-    for name in BROUGHT:
+    # the three per-layer metrics this cell brought and the reduction's
+    # count: every cell's, each with a reader, appended together after what
+    # was there
+    for name in BROUGHT + [REDUCED]:
         m = next(m for m in bench["per_layer"] if m["name"] == name)
         assert (PERF / "layers" / f"{name}.py").is_file()
         assert m["moves"] == "queries_per_s"
         assert m["source"] == "program_counter" and "workloads" not in m
-    assert [m["name"] for m in bench["per_layer"]][-3:] == BROUGHT
+    assert [m["name"] for m in bench["per_layer"]][-4:] == BROUGHT + [REDUCED]
+    reduced = next(m for m in bench["per_layer"] if m["name"] == REDUCED)
+    assert (reduced["layer"], reduced["better"]) == ("executor", "higher")
     assert [c["name"] for c in bench["configs"]][-1] == CONFIG
     assert [w["name"] for w in bench["workloads"]][-1] == CELL
 
@@ -150,7 +155,7 @@ def test_a_traced_run_of_the_cell_reports_every_metric_it_is_held_to(tmp_path):
     held_to = {m["name"] for m in bench["per_layer"]
                if CELL in m.get("workloads", [CELL])
                and m["source"] != "device_trace" and m["layer"] != "device"}
-    assert set(BROUGHT) <= held_to
+    assert set(BROUGHT) | {REDUCED} <= held_to
     assert {"noninner_join_tasks_per_query", "agg_groups_per_query",
             "agg_self_ms_per_query", "agg_capacity_retries_in_window",
             "task_unnamed_ms_per_query"} <= held_to
@@ -165,10 +170,12 @@ def test_a_traced_run_of_the_cell_reports_every_metric_it_is_held_to(tmp_path):
     line = json.loads(done.stdout.strip().splitlines()[-1])
     assert held_to - set(line["metrics"]) == set()
     metrics = {k: v["value"] for k, v in line["metrics"].items()}
-    # q17 groups all of lineitem (60,000 lines at SF 0.01) by part (2,000),
-    # q20 a year of it by part and supplier: a query's mean lies between
-    assert 1_000 < metrics["subquery_agg_rows_per_query"] < 60_000
-    assert 1_000 < metrics["subquery_agg_groups_per_query"] < 10_000
+    # reduced to the outer query's parts: q17 groups the lines of the one
+    # or two of 2,000 parts its brand and container keep (of 60,000 lines
+    # at SF 0.01), q20 the year's lines of the some 21 its colour keeps
+    assert 0 < metrics["subquery_agg_rows_per_query"] < 1_000
+    assert 0 < metrics["subquery_agg_groups_per_query"] < 1_000
+    assert metrics[REDUCED] > 0
     assert metrics["subquery_agg_self_ms_per_query"] > 0
     assert metrics["subquery_agg_self_ms_per_query"] <= metrics[
         "agg_self_ms_per_query"]
@@ -176,6 +183,30 @@ def test_a_traced_run_of_the_cell_reports_every_metric_it_is_held_to(tmp_path):
     assert metrics["noninner_join_tasks_per_query"] > 0
     assert metrics["agg_capacity_retries_in_window"] == 0
     assert metrics["holistic_tasks_per_query"] == 0
+
+
+def test_the_reduced_aggregates_reader():
+    """A number per completed query where the program counts reduced tasks,
+    0 where it declares the counter and nothing was reduced (every other
+    cell), ``None`` where the program has no such counter (a parent
+    commit), so that the metric is left out of the line."""
+    from layers import subquery_agg_reduced_per_query
+
+    key = "subquery.agg_reduced"
+    done = {"error": None, "template": "q17", "t0": 10.0, "t1": 11.0}
+    failed = {"error": "Boom", "template": "q20", "t0": 11.0, "t1": 11.5}
+
+    def read(before, after, queries=(done, done, failed)):
+        return subquery_agg_reduced_per_query.read(
+            {"queries": list(queries), "counters_before": before,
+             "counters_after": after})
+
+    assert read({key: 3}, {key: 7}) == pytest.approx(2.0)
+    assert read({key: 0}, {key: 0}) == 0.0
+    old = {"subquery.agg_rows": 0, "agg.sort_passes": 4}
+    assert read(old, old) is None
+    assert read(None, None) is None
+    assert read({key: 0}, {key: 2}, [failed]) is None
 
 
 # -- planted faults -----------------------------------------------------------------

@@ -9,9 +9,11 @@ aggregate's partial and final halves are cut across a shuffle. Then two
 crafted tables: q17 with lines at an exact decimal tie (``5 * qty * count =
 sum``), which SQL's ``<`` drops, and q20 with pairs that have no line in the
 year (their subquery is NULL: the pair is dropped) or exactly twice the
-available quantity shipped. The counters ``subquery.*`` read the rows and
-groups of the decorrelating aggregates, and nothing for a plan without
-one."""
+available quantity shipped, and a correlated subquery whose outer keys
+repeat, miss the subquery's rows or are NULL. The decorrelating aggregate
+groups only the keys the outer query can join (the semi-join reduction by
+the outer's key domain): the counters ``subquery.*`` read the rows and
+groups of the reduced aggregates, and nothing for a plan without one."""
 
 import pathlib
 import sys
@@ -97,19 +99,29 @@ def served(ctx, sql):
 
 
 def expected_work(tables, name, p):
-    """The decorrelating aggregate's input rows and groups, by numpy: q17's
-    all of lineitem by part, q20's lines of the year by part and supplier."""
-    li = tables["lineitem"]
+    """The decorrelating aggregate's input rows and groups once reduced to
+    the outer query's parts, by numpy: q17's lines of the brand's parts in
+    the container, by part; q20's lines of the year of the parts whose name
+    starts with the colour, by part and supplier."""
+    li, parts = tables["lineitem"], tables["part"]
     part = li["l_partkey"].to_numpy()
+    keys = parts["p_partkey"].to_numpy()
     if name == "q17":
-        return len(part), len(np.unique(part))
+        kept = keys[(parts["p_brand"].to_numpy(zero_copy_only=False)
+                     == p["brand"])
+                    & (parts["p_container"].to_numpy(zero_copy_only=False)
+                       == p["container"])]
+        lines = np.isin(part, kept)
+        return int(lines.sum()), len(np.unique(part[lines]))
+    names = parts["p_name"].to_numpy(zero_copy_only=False)
+    kept = keys[np.char.startswith(names.astype(str), p["color"])]
     ship = li["l_shipdate"].cast(pa.int32()).to_numpy()
     lo = datagen.days(int(p["date"][:4]), 1, 1)
     hi = datagen.days(int(p["date"][:4]) + 1, 1, 1)
-    year = (ship >= lo) & (ship < hi)
-    pairs = np.unique(np.stack([part[year],
-                                li["l_suppkey"].to_numpy()[year]]), axis=1)
-    return int(year.sum()), pairs.shape[1]
+    lines = (ship >= lo) & (ship < hi) & np.isin(part, kept)
+    pairs = np.unique(np.stack([part[lines],
+                                li["l_suppkey"].to_numpy()[lines]]), axis=1)
+    return int(lines.sum()), pairs.shape[1]
 
 
 CASES = ([(t, which, "one_executor") for t in TEMPLATES
@@ -133,12 +145,15 @@ def test_the_served_path_gives_the_reference_answer(
         assert verdict["numbers"]["relerr_q17"]["value"] < 1e-12
     else:
         assert len(reference) > 3 and mod.LIMITS == {}
-    # the decorrelating aggregate saw every row under it once, and handed
-    # the join one row a group, however many tasks it ran in
+    # the decorrelating aggregate saw every row of the outer query's parts
+    # under it once, and handed the join one row a group, however many
+    # tasks it ran in: every task of its partial ran over a reduced input
     rows, groups = expected_work(data[0], name, p)
+    assert 0 < rows < data[0]["lineitem"].num_rows / 20
     assert moved["subquery.agg_rows"] == rows
     assert moved["subquery.agg_groups"] == groups
     assert moved["subquery.agg_self_seconds"] > 0
+    assert moved["subquery.agg_reduced"] > 0
 
 
 def test_no_correlated_scalar_subquery_counts_nothing(one_executor, data):
@@ -150,7 +165,7 @@ def test_no_correlated_scalar_subquery_counts_nothing(one_executor, data):
              group by l_partkey"""
     answer, moved = served(one_executor, sql)
     assert answer.num_rows > 100
-    assert moved == dict.fromkeys(moved, 0) and len(moved) == 3
+    assert moved == dict.fromkeys(moved, 0) and len(moved) == 4
 
 
 # -- crafted: a decimal tie, and a pair without lines in the year -----------
@@ -205,8 +220,11 @@ def test_q17_drops_the_lines_at_an_exact_decimal_tie(templates):
     verdict = verify.judge([("q17", 0, answer)], {"q17": mod},
                            {("q17", 0): reference}, 0)
     assert verdict["correct"], (verdict["numbers"], answer.to_pylist())
-    assert moved["subquery.agg_rows"] == sum(map(len, Q17_LINES.values()))
-    assert moved["subquery.agg_groups"] == len(Q17_LINES)
+    # reduced to the six Brand#23 parts: part 7's lines are not grouped
+    assert moved["subquery.agg_rows"] == sum(
+        len(lines) for k, lines in Q17_LINES.items() if k != 7)
+    assert moved["subquery.agg_groups"] == 6
+    assert moved["subquery.agg_reduced"] > 0
     # the case bites: the comparison refuses the lenient answer
     assert lenient.to_pylist()[0]["avg_yearly"] == pytest.approx(
         (100.0 + 10.5 + TIES) / 7.0)
@@ -288,6 +306,59 @@ def test_q20_drops_a_pair_without_lines_in_the_year(templates):
     verdict = verify.judge([("q20", 0, answer)], {"q20": mod},
                            {("q20", 0): reference}, 0)
     assert verdict["correct"], (verdict["first_mismatch"], answer.to_pylist())
-    # the year's lines (seven of nine) into six (part, supplier) groups
-    assert moved["subquery.agg_rows"] == 7
-    assert moved["subquery.agg_groups"] == 6
+    # the year's lines of the forest parts (six of nine: part 3's is not
+    # one) into five (part, supplier) groups
+    assert moved["subquery.agg_rows"] == 6
+    assert moved["subquery.agg_groups"] == 5
+
+
+# -- crafted: outer keys that repeat, that the subquery lacks, that are NULL -
+
+# outer rows (id, k, tag, v) and the subquery's rows (k, q): keys 1 and 2
+# repeat in the outer query, 3 has no subquery row (NULL: dropped), one
+# outer key and one subquery key are NULL (never equal), 4 is kept by no
+# outer row (its group is cut) and 0 is a key like any other
+OUTER = [(1, 1, "keep", 1.0), (2, 1, "keep", 9.0), (3, 2, "keep", 2.0),
+         (4, 2, "keep", 3.5), (5, 2, "drop", 0.0), (6, 3, "keep", 0.0),
+         (7, None, "keep", 0.0), (8, 0, "keep", 1.0), (9, 4, "drop", 0.0),
+         (10, 1, "keep", 3.0)]
+INNER = [(1, 6.0), (1, 10.0), (2, 8.0), (2, 6.0), (2, 7.0), (4, 100.0),
+         (4, 50.0), (None, 40.0), (0, 4.0), (5, 1.0)]
+REPEATS = """select id, ok, v from o where tag = 'keep'
+             and v < (select 0.5 * avg(q) from t where tk = ok)
+             order by id"""
+
+
+def test_outer_keys_that_repeat_miss_or_are_null_give_the_reference():
+    """The reduction keeps each group some outer row can join, once however
+    often its key repeats, and the answer is SQL's: a key the subquery has
+    no row for, or a NULL key, drops its outer rows."""
+    tables = {
+        "o": pa.table({
+            "id": pa.array([r[0] for r in OUTER], pa.int64()),
+            "ok": pa.array([r[1] for r in OUTER], pa.int64()),
+            "tag": [r[2] for r in OUTER],
+            "v": pa.array([r[3] for r in OUTER]),
+        }),
+        "t": pa.table({
+            "tk": pa.array([r[0] for r in INNER], pa.int64()),
+            "q": pa.array([r[1] for r in INNER]),
+        }),
+    }
+    reference = []
+    for i, k, tag, v in OUTER:
+        qs = [q for tk, q in INNER if k is not None and tk == k]
+        if tag == "keep" and qs and v < 0.5 * sum(qs) / len(qs):
+            reference.append({"id": i, "ok": k, "v": v})
+    assert [r["id"] for r in reference] == [1, 3, 8, 10]
+    ctx = standalone(tables, concurrent_tasks=4)
+    try:
+        answer, moved = served(ctx, REPEATS)
+    finally:
+        ctx.close()
+    assert answer.to_pylist() == reference
+    # the kept keys 0, 1, 2 and 3 hold six of the subquery's ten rows, in
+    # three groups: 4's, 5's and the NULL key's are cut
+    assert moved["subquery.agg_rows"] == 6
+    assert moved["subquery.agg_groups"] == 3
+    assert moved["subquery.agg_reduced"] > 0
