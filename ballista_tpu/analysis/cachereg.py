@@ -299,7 +299,7 @@ CACHES: tuple[CacheEntry, ...] = (
             "ballista_tpu/exec/joins.py::_jit_probe",
             "ballista_tpu/exec/joins.py::_jit_counts",
             "ballista_tpu/exec/joins.py::_jit_expand_total",
-            "ballista_tpu/exec/joins.py::_jit_noninner_counts",
+            "ballista_tpu/exec/joins.py::_jit_unmatched",
             "ballista_tpu/exec/percentile.py::_pct_program",
             "ballista_tpu/exec/repartition.py::_jit_mask_partition",
             "ballista_tpu/exec/repartition.py::jit_partition_ids",

@@ -44,6 +44,14 @@ telemetry uses) and keeps process-global counters:
   (``exec/joins.py``): tasks that ran one, the live rows of their preserved
   side, and those an outer or anti join emitted without a match. Declared
   at 0 likewise.
+- ``join.builds`` / ``join.build_rows`` / ``join.probe_rows`` /
+  ``join.key_remaps`` — every ``HashJoinExec`` (``exec/joins.py``): probe
+  tables built (``build_side``, rebuilds after a dictionary remap
+  included), their live rows, the live probe rows of every join kind (of
+  which ``join.noninner.probe_rows`` is the part that is not inner), and
+  probe batches whose dictionary unification changed the build side.
+  Summed from the operators' metrics as a task ends; declared at 0
+  likewise.
 - ``poll.rpcs`` / ``poll.wakes_by_status`` (executor) and ``poll.holds`` /
   ``poll.holds_granted`` / ``poll.holds_timed_out`` (scheduler) — the pull
   loop's hand-off (docs/observability.md): ``PollWork`` calls sent, waits
@@ -106,6 +114,12 @@ NONINNER_JOIN_COUNTERS = (
     "join.noninner.tasks", "join.noninner.probe_rows",
     "join.noninner.unmatched_rows",
 )
+# every hash join (exec/joins.py HashJoinExec), summed from its metrics as a
+# task ends: the operator's metric of each, by counter
+JOIN_COUNTERS = {
+    "join.builds": "builds", "join.build_rows": "build_rows",
+    "join.probe_rows": "probe_rows", "join.key_remaps": "key_remaps",
+}
 # the pull loop's hand-off (docs/serving.md): polls sent and waits ended by
 # a finished task (executor); polls held, and how a hold ended (scheduler)
 POLL_COUNTERS = (
@@ -163,8 +177,9 @@ def op_counter(operator: str) -> str:
 
 _COUNTERS: dict[str, float] = dict.fromkeys(
     AGG_COUNTERS + HOLISTIC_COUNTERS + DICT_PREDICATE_COUNTERS
-    + NONINNER_JOIN_COUNTERS + POLL_COUNTERS + STATUS_COUNTERS
-    + HINT_COUNTERS + SHUFFLE_COUNTERS + OP_COUNTERS + SUBQUERY_COUNTERS, 0
+    + NONINNER_JOIN_COUNTERS + tuple(JOIN_COUNTERS) + POLL_COUNTERS
+    + STATUS_COUNTERS + HINT_COUNTERS + SHUFFLE_COUNTERS + OP_COUNTERS
+    + SUBQUERY_COUNTERS, 0
 )
 _INSTALLED = False
 

@@ -181,8 +181,8 @@ VOCABULARY: dict[str, KernelSpec] = {
     "exec.joins.join_semi_mask": KernelSpec(
         None, "semi/anti mask from match counts (keys, kind)"
     ),
-    "exec.joins.join_noninner_counts": KernelSpec(
-        None, "probe and unmatched rows of a LEFT/SEMI/ANTI probe batch"
+    "exec.joins.join_unmatched_rows": KernelSpec(
+        None, "running unmatched rows of a LEFT/ANTI output batch"
     ),
     "exec.joins.join_expand": KernelSpec(
         None, "expansion-join body (filter expr, kind, output capacity)"
@@ -237,7 +237,7 @@ _AGG = (
 _JOIN = (
     "exec.joins.join_probe", "exec.joins.join_probe_counts",
     "exec.joins.join_expand_total", "exec.joins.join_semi_mask",
-    "exec.joins.join_noninner_counts",
+    "exec.joins.join_unmatched_rows",
     "exec.joins.join_expand", "exec.joins.join_probe_filter",
     "ops.join._build_finish", "ops.join.join_build_prep",
     "ops.join.join_exact2_range", "ops.join.join_lut",

@@ -458,7 +458,10 @@ class Metrics:
         self.timers: dict[str, float] = {}
 
     def add(self, name: str, v: int = 1) -> None:
-        self.counters[name] = self.counters.get(name, 0) + v
+        # a counter's first value is kept as it comes: ``0 + v`` of a device
+        # scalar would be one more program dispatched
+        had = self.counters.get(name)
+        self.counters[name] = v if had is None else had + v
 
     def reset(self) -> None:
         self.counters.clear()

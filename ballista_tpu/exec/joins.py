@@ -19,6 +19,7 @@ from typing import Iterator
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from ballista_tpu.columnar.batch import DeviceBatch
 from ballista_tpu.columnar.dict_util import merge_dictionaries, remap_codes
@@ -61,16 +62,24 @@ def _collect_partition(
     return concat_batches(batches)
 
 
+def _probe_rows(rows, pb: DeviceBatch):
+    """The operator's running count of live probe rows after ``pb``
+    (``join.probe_rows``, docs/observability.md). Every program that probes
+    a batch carries it in and out, so counting dispatches nothing."""
+    return rows + jnp.sum(pb.valid, dtype=jnp.int64)
+
+
 # build_side host-composes cached sort passes (wrapping it in another jit
 # would re-inline the sorts into one slow-compiling program — don't); the
 # probe is a single fast-compiling program per shape.
 @functools.lru_cache(maxsize=None)
 def _jit_probe(probe_keys: tuple, kind: JoinSide, contiguous: bool = False):
 
-    def join_probe(bt, pb):
-        return probe_side(
+    def join_probe(bt, pb, rows):
+        out = probe_side(
             bt, pb, list(probe_keys), kind, contiguous=contiguous
         )
+        return out, _probe_rows(rows, pb)
 
     return jax.jit(join_probe)
 
@@ -78,8 +87,8 @@ def _jit_probe(probe_keys: tuple, kind: JoinSide, contiguous: bool = False):
 @functools.lru_cache(maxsize=None)
 def _jit_counts(probe_keys: tuple):
 
-    def join_probe_counts(bt, pb):
-        return probe_counts(bt, pb, list(probe_keys))
+    def join_probe_counts(bt, pb, rows):
+        return probe_counts(bt, pb, list(probe_keys)), _probe_rows(rows, pb)
 
     return jax.jit(join_probe_counts)
 
@@ -99,26 +108,26 @@ def _jit_expand_total(preserve_probe: bool):
 
 
 @functools.lru_cache(maxsize=None)
-def _jit_noninner_counts(kind: JoinSide, build_col: int | None):
-    """(live probe rows, preserved rows emitted without a match) of one
-    probe batch of a LEFT, SEMI or ANTI join: two device scalars for the
-    operator's metrics (``join.noninner.*``, docs/observability.md).
-    ``build_col`` is a build-side key column of a LEFT join's output, which
-    is NULL exactly in the rows that found no match."""
+def _jit_unmatched(build_col: int | None):
+    """The operator's running count of preserved rows a LEFT or ANTI join
+    emitted without a match, after one more output batch: a device scalar
+    for ``join.noninner.unmatched_rows`` (docs/observability.md), carried
+    from batch to batch. ``build_col`` is a build-side key column of a LEFT
+    join's output, which is NULL exactly in the rows that found no match;
+    None for ANTI, every row of whose output is unmatched."""
 
-    def join_noninner_counts(pb, out):
-        probe_rows = jnp.sum(pb.valid, dtype=jnp.int64)
-        if kind == JoinSide.ANTI:
-            unmatched = jnp.sum(out.valid, dtype=jnp.int64)
-        elif kind == JoinSide.LEFT and out.nulls[build_col] is not None:
-            unmatched = jnp.sum(
-                out.valid & out.nulls[build_col], dtype=jnp.int64
-            )
-        else:
-            unmatched = jnp.zeros((), jnp.int64)
-        return probe_rows, unmatched
+    def join_unmatched_rows(unmatched, out):
+        miss = out.valid
+        if build_col is not None:
+            miss = miss & out.nulls[build_col]
+        return unmatched + jnp.sum(miss, dtype=jnp.int64)
 
-    return jax.jit(join_noninner_counts)
+    return jax.jit(join_unmatched_rows)
+
+
+# the running counts' start: a host scalar, so that the first batch of a
+# task dispatches the same program as the rest and nothing else
+_NO_ROWS = np.int64(0)
 
 
 class HashJoinExec(ExecutionPlan):
@@ -155,6 +164,10 @@ class HashJoinExec(ExecutionPlan):
         # are partition-invariant: compute once, reuse across partitions
         self._decide_flags: tuple[bool, bool] | None = None
         self._decide_from_cache = False
+        # the last dictionary merge of a string key, (build dictionary,
+        # probe dictionary, merged and both remaps): a probe input's batches
+        # share one dictionary, so it is made once a task
+        self._last_merge: tuple | None = None
         ls, rs = left.schema(), right.schema()
         for a, b in self.on:
             if not (isinstance(a, L.Column) and isinstance(b, L.Column)):
@@ -193,7 +206,14 @@ class HashJoinExec(ExecutionPlan):
         self, build: DeviceBatch, probe: DeviceBatch,
         build_keys: list[int], probe_keys: list[int],
     ) -> tuple[DeviceBatch, DeviceBatch]:
-        """String join keys must share a dictionary; remap both sides."""
+        """String join keys must share a dictionary: both sides' codes are
+        remapped onto the sorted union of the two. A side whose dictionary
+        is the union already keeps its batch and its codes, so a build whose
+        dictionary holds every string of a probe batch comes back as it
+        went in and is not built again; a remap of the build side counts
+        ``key_remaps`` (its caller rebuilds the probe table). The last
+        merge is kept: the probe batches of one input share a dictionary."""
+        remapped = False
         for bi, pi in zip(build_keys, probe_keys):
             bf = build.schema.fields[bi]
             pf = probe.schema.fields[pi]
@@ -205,26 +225,36 @@ class HashJoinExec(ExecutionPlan):
                 raise ExecutionError(
                     f"string join key {bf.name!r} missing dictionary"
                 )
-            if bd.values == pd_.values:
+            if bd == pd_:
                 continue
-            merged, rb, rp = merge_dictionaries(bd, pd_)
-            bcols = list(build.columns)
-            bcols[bi] = remap_codes(build.columns[bi], rb)
-            bdicts = dict(build.dictionaries)
-            bdicts[bf.name] = merged
-            build = DeviceBatch(
-                schema=build.schema, columns=tuple(bcols), valid=build.valid,
-                nulls=build.nulls, dictionaries=bdicts,
-            )
-            pcols = list(probe.columns)
-            pcols[pi] = remap_codes(probe.columns[pi], rp)
-            pdicts = dict(probe.dictionaries)
-            pdicts[pf.name] = merged
-            probe = DeviceBatch(
-                schema=probe.schema, columns=tuple(pcols), valid=probe.valid,
-                nulls=probe.nulls, dictionaries=pdicts,
-            )
+            last = self._last_merge
+            if last is not None and last[0] is bd and last[1] is pd_:
+                merged, rb, rp = last[2]
+            else:
+                merged, rb, rp = merge_dictionaries(bd, pd_)
+                merged = next((d for d in (bd, pd_) if d == merged), merged)
+                self._last_merge = (bd, pd_, (merged, rb, rp))
+            if merged is not bd:
+                remapped = True
+                build = self._with_codes(build, bi, rb, merged)
+            if merged is not pd_:
+                probe = self._with_codes(probe, pi, rp, merged)
+        if remapped:
+            self.metrics.add("key_remaps")
         return build, probe
+
+    @staticmethod
+    def _with_codes(batch: DeviceBatch, i: int, table, merged) -> DeviceBatch:
+        """``batch`` with column ``i``'s codes remapped through ``table``
+        onto the dictionary ``merged``."""
+        cols = list(batch.columns)
+        cols[i] = remap_codes(batch.columns[i], table)
+        dicts = dict(batch.dictionaries)
+        dicts[batch.schema.fields[i].name] = merged
+        return DeviceBatch(
+            schema=batch.schema, columns=tuple(cols), valid=batch.valid,
+            nulls=batch.nulls, dictionaries=dicts,
+        )
 
     # -- cross-run build-table cache ------------------------------------------
     # A warm suite re-collects and re-sorts every build side each run
@@ -510,14 +540,19 @@ class HashJoinExec(ExecutionPlan):
                 # nulled build side
                 if kind in (JoinSide.INNER, JoinSide.SEMI):
                     continue
+                # every probe row is live and goes out unmatched: the host
+                # holds their count
+                n = sum(t.num_rows for t in ptabs)
+                self.metrics.add("probe_rows", n)
+                self.metrics.add("noninner_unmatched_rows", n)
+                c = self.metrics.counters
+                c["noninner_probe_rows"] = c["probe_rows"]
                 for pb in probe_batches():
-                    out = (
+                    yield (
                         pb
                         if kind == JoinSide.ANTI
                         else self._null_extend(pb)
                     )
-                    self._count_noninner(kind, pb, out, right_keys)
-                    yield out
                 continue
             with self.metrics.time("build_time"):
                 bb_parts: list[DeviceBatch] = []
@@ -530,42 +565,55 @@ class HashJoinExec(ExecutionPlan):
                     if len(bb_parts) > 1
                     else bb_parts[0]
                 )
-                bt = build_side(bb, right_keys)
+                bt = self._build(bb, right_keys)
             for pb in probe_batches():
                 bb2, pb2 = self._unify_key_dicts(
                     bb, pb, right_keys, left_keys
                 )
                 if bb2 is not bb:
                     with self.metrics.time("build_time"):
-                        bt = build_side(bb2, right_keys)
+                        bt = self._build(bb2, right_keys)
                     bb = bb2
                 out = self._probe_or_expand(
                     bt, pb2, left_keys, kind, ctx, None, partition
                 )
                 if kind in (JoinSide.INNER, JoinSide.LEFT):
                     out = self._restore_column_order(out, pb2, bt.batch, True)
-                self._count_noninner(kind, pb2, out, right_keys)
+                self._count_noninner(kind, out, right_keys)
                 self.metrics.add("output_batches")
                 yield maybe_shrink(out, ctx, site, partition)
         pset.close()
 
     def _count_noninner(
-        self, kind: JoinSide, pb: DeviceBatch, out: DeviceBatch,
-        right_keys: list[int],
+        self, kind: JoinSide, out: DeviceBatch, right_keys: list[int],
     ) -> None:
         """One probe batch of a join that preserves its left side, into the
-        operator's metrics: the executor sums them into the
-        ``join.noninner.*`` counters as the task ends. The rows stay device
-        scalars until the task's metrics are read."""
+        operator's metrics: its probe rows, which the probe program has
+        added to ``probe_rows``, are ``noninner_probe_rows`` too, and a
+        LEFT or ANTI join adds the preserved rows it emitted without a match
+        to ``noninner_unmatched_rows``. The executor sums them into
+        ``join.noninner.*`` as the task ends. The counts stay device scalars
+        until the task's metrics are read."""
         if kind == JoinSide.INNER:
             return
-        build_col = (
-            len(self.left.schema()) + right_keys[0]
-            if kind == JoinSide.LEFT else None
-        )
-        probe_rows, unmatched = _jit_noninner_counts(kind, build_col)(pb, out)
-        self.metrics.add("noninner_probe_rows", probe_rows)
-        self.metrics.add("noninner_unmatched_rows", unmatched)
+        c = self.metrics.counters
+        c["noninner_probe_rows"] = c["probe_rows"]
+        unmatched = c.get("noninner_unmatched_rows", _NO_ROWS)
+        build_col = len(self.left.schema()) + right_keys[0]
+        if kind == JoinSide.ANTI:
+            unmatched = _jit_unmatched(None)(unmatched, out)
+        elif kind == JoinSide.LEFT and out.nulls[build_col] is not None:
+            unmatched = _jit_unmatched(build_col)(unmatched, out)
+        c["noninner_unmatched_rows"] = unmatched
+
+    def _build(self, batch: DeviceBatch, key_idxs: list[int]):
+        """``build_side``, counted: +1 ``builds`` and its live rows (a
+        device scalar the build has already) into ``build_rows``, summed
+        into ``join.builds`` and ``join.build_rows`` as the task ends."""
+        bt = build_side(batch, key_idxs)
+        self.metrics.add("builds")
+        self.metrics.add("build_rows", bt.n)
+        return bt
 
     def _null_extend(self, pb: DeviceBatch) -> DeviceBatch:
         """LEFT-join rows for an empty build range: probe columns pass
@@ -613,7 +661,7 @@ class HashJoinExec(ExecutionPlan):
             bb, pb = self._unify_key_dicts(build_batch, b, right_keys, left_keys)
             if bt is None or bb is not build_batch:
                 with self.metrics.time("build_time"):
-                    bt = build_side(bb, right_keys)
+                    bt = self._build(bb, right_keys)
                 build_batch = bb
                 self._build_cache_put(ctx, slot, build_batch, bt, right_keys)
             out = self._probe_or_expand(
@@ -622,7 +670,7 @@ class HashJoinExec(ExecutionPlan):
             if kind in (JoinSide.INNER, JoinSide.LEFT):
                 # probe++build == left++right; relabel to the plan schema
                 out = self._restore_column_order(out, pb, bt.batch, True)
-            self._count_noninner(kind, pb, out, right_keys)
+            self._count_noninner(kind, out, right_keys)
             self.metrics.add("output_batches")
             # selective joins (q18's SEMI against a tiny HAVING set) leave
             # a near-empty batch at full probe capacity — re-bucket so the
@@ -689,7 +737,7 @@ class HashJoinExec(ExecutionPlan):
             else:
                 with self.metrics.time("build_time"):
                     left_batch = _collect(self.left, ctx)
-                    lbt = build_side(left_batch, left_keys)
+                    lbt = self._build(left_batch, left_keys)
                 self._build_cache_put(
                     ctx, ("bt_flip",), left_batch, lbt, left_keys
                 )
@@ -762,7 +810,7 @@ class HashJoinExec(ExecutionPlan):
             flags, from_cache = self._decide_flags, self._decide_from_cache
         if flags is None:
             with self.metrics.time("build_time"):
-                decide = build_side(right_batch, right_keys)
+                decide = self._build(right_batch, right_keys)
             flags = decide.flags()
             if cache is not None:
                 cache[fp] = flags
@@ -782,7 +830,7 @@ class HashJoinExec(ExecutionPlan):
                 left_batch, right_batch, left_keys, right_keys
             )
             with self.metrics.time("build_time"):
-                lbt = build_side(lb, left_keys)
+                lbt = self._build(lb, left_keys)
             lfp = self._strategy_key(self.left, left_keys, ctx)
             lflags = cache.get(lfp) if cache is not None else None
             l_from_cache = lflags is not None
@@ -871,7 +919,7 @@ class HashJoinExec(ExecutionPlan):
                 )
             else:
                 with self.metrics.time("build_time"):
-                    rbt = build_side(rb, right_keys)
+                    rbt = self._build(rb, right_keys)
                 # expansion cannot count collision-overflowed runs. If the
                 # branch came from cached flags, treat a firing as a stale
                 # speculation (fresh flags may pick the other build side);
@@ -932,7 +980,7 @@ class HashJoinExec(ExecutionPlan):
             )
         else:
             with self.metrics.time("build_time"):
-                bt = build_side(bb, right_keys)
+                bt = self._build(bb, right_keys)
             _validate(bt)
             if bb is right_batch:
                 self._build_cache_put(
@@ -958,7 +1006,7 @@ class HashJoinExec(ExecutionPlan):
             bb2, pb = self._unify_key_dicts(base, b, right_keys, left_keys)
             if bb2 is not base:
                 with self.metrics.time("build_time"):
-                    bt = build_side(bb2, right_keys)
+                    bt = self._build(bb2, right_keys)
                 _validate(bt)
                 contig = False
                 base = bb2
@@ -1134,7 +1182,10 @@ class HashJoinExec(ExecutionPlan):
         (the match bit is enough). The output capacity sync is skipped on
         warm runs via the plan cache (deferred-validated)."""
         with self.metrics.time("probe_time"):
-            first, count, live = _jit_counts(tuple(probe_keys))(bt, probe)
+            (first, count, _), rows = _jit_counts(tuple(probe_keys))(
+                bt, probe, self._rows_so_far()
+            )
+        self.metrics.counters["probe_rows"] = rows
 
         if kind in (JoinSide.SEMI, JoinSide.ANTI) and self.filter is None:
             from ballista_tpu.compilecache import shared_callable
@@ -1286,11 +1337,13 @@ class HashJoinExec(ExecutionPlan):
     ) -> DeviceBatch:
         """Probe (jitted); apply the residual join filter to match
         semantics."""
+        c = self.metrics.counters
         if self.filter is None:
             with self.metrics.time("probe_time"):
-                return _jit_probe(tuple(probe_keys), kind, contiguous)(
-                    bt, probe
-                )
+                out, c["probe_rows"] = _jit_probe(
+                    tuple(probe_keys), kind, contiguous
+                )(bt, probe, self._rows_so_far())
+            return out
         from ballista_tpu.compilecache import expr_key, shared_callable
 
         key = (
@@ -1302,7 +1355,10 @@ class HashJoinExec(ExecutionPlan):
             filt = self.filter
             pk = list(probe_keys)
 
-            def join_probe_filter(bt, probe):
+            def join_probe_filter(bt, probe, rows):
+                return filtered(bt, probe), _probe_rows(rows, probe)
+
+            def filtered(bt, probe):
                 # Residual filters see probe ++ build columns: join LEFT-like
                 # first, evaluate, then adjust validity per join kind.
                 joined = probe_side(
@@ -1342,7 +1398,12 @@ class HashJoinExec(ExecutionPlan):
 
         fn = shared_callable(key, build)
         with self.metrics.time("probe_time"):
-            return fn(bt, probe)
+            out, c["probe_rows"] = fn(bt, probe, self._rows_so_far())
+        return out
+
+    def _rows_so_far(self):
+        """The running ``probe_rows`` a probe program carries on."""
+        return self.metrics.counters.get("probe_rows", _NO_ROWS)
 
     def _restore_column_order(
         self,

@@ -487,6 +487,17 @@ class Executor:
                     for name in ("probe_rows", "unmatched_rows")
                 ]
             )
+        # and every hash join's builds, their rows, its probe rows and the
+        # remaps of its build's dictionary
+        joined = {
+            counter: sum(
+                r["counters"].get(name, 0) for r in op_metrics or ()
+                if r["operator"] == "HashJoinExec"
+            )
+            for counter, name in compile_metrics.JOIN_COUNTERS.items()
+        }
+        if any(joined.values()):
+            compile_metrics.add_many(joined.items())
         # and each operator's own time on the task's threads, by family; the
         # aggregates that decorrelate a scalar subquery also apart: rows into
         # their partials, groups out of their finals, their own time, and

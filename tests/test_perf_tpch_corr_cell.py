@@ -80,11 +80,17 @@ def test_the_cell_is_tpch_sf1_mem_cut_in_queries_only():
         assert (PERF / "layers" / f"{name}.py").is_file()
         assert m["moves"] == "queries_per_s"
         assert m["source"] == "program_counter" and "workloads" not in m
-    assert [m["name"] for m in bench["per_layer"]][-4:] == BROUGHT + [REDUCED]
+    # appended together, after what was there (later PRs append after them)
+    names = [m["name"] for m in bench["per_layer"]]
+    at = names.index(BROUGHT[0])
+    assert names[at:at + 4] == BROUGHT + [REDUCED]
     reduced = next(m for m in bench["per_layer"] if m["name"] == REDUCED)
     assert (reduced["layer"], reduced["better"]) == ("executor", "higher")
-    assert [c["name"] for c in bench["configs"]][-1] == CONFIG
-    assert [w["name"] for w in bench["workloads"]][-1] == CELL
+    # the configuration and the cell after every one that was there before
+    configs = [c["name"] for c in bench["configs"]]
+    assert configs.index(CONFIG) == configs.index("tpch-sf1-subq-mem") + 1
+    cells = [w["name"] for w in bench["workloads"]]
+    assert cells.index(CELL) == cells.index("tpch-sf1-subq-mem.subquery") + 1
 
 
 @pytest.mark.parametrize("name", TEMPLATES)

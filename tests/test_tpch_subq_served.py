@@ -103,7 +103,7 @@ def test_the_served_path_gives_the_reference_answer(
     before = metrics.snapshot()
     answer = ctx.sql(mod.SQL.format(**p)).collect()
     moved = {k: v - before[k] for k, v in metrics.snapshot().items()
-             if k.startswith("join.noninner.")}
+             if k.startswith("join.")}
     verdict = verify.judge([(name, 0, answer)], {name: mod},
                            {(name, 0): reference}, 0)
     assert verdict["correct"], (verdict["numbers"], verdict["first_mismatch"])
@@ -130,6 +130,10 @@ def test_the_served_path_gives_the_reference_answer(
     assert moved["join.noninner.tasks"] >= 1, (kind, moved)
     assert moved["join.noninner.probe_rows"] > 0
     assert (moved["join.noninner.unmatched_rows"] > 0) == emits_unmatched
+    # every join kind's probe rows, of which those are a part; each join
+    # built at least one probe table
+    assert moved["join.probe_rows"] >= moved["join.noninner.probe_rows"]
+    assert moved["join.builds"] >= 1 and moved["join.build_rows"] > 0
     if name == "q13":
         assert moved["join.noninner.probe_rows"] == 3_000
         # the two without an order, and whoever's orders are all special
