@@ -317,6 +317,7 @@ CACHES: tuple[CacheEntry, ...] = (
             "ballista_tpu/ops/join.py::_build_prep_program",
             "ballista_tpu/ops/join.py::_exact2_range_program",
             "ballista_tpu/ops/join.py::_lut_program",
+            "ballista_tpu/ops/join.py::_sorted_rows_program",
             "ballista_tpu/ops/pallas_agg.py::_program",
             "ballista_tpu/ops/perm.py::_argsort_program",
             "ballista_tpu/ops/perm.py::_take_program",

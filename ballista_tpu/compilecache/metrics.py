@@ -50,8 +50,14 @@ telemetry uses) and keeps process-global counters:
   included), their live rows, the live probe rows of every join kind (of
   which ``join.noninner.probe_rows`` is the part that is not inner), and
   probe batches whose dictionary unification changed the build side.
-  Summed from the operators' metrics as a task ends; declared at 0
-  likewise.
+  ``join.build_gather_bytes`` / ``join.builds_in_place`` — the bytes each
+  build's finisher gathered through its sort's permutation (the key columns:
+  static capacity x itemsize, no device read; and a payload gathered into
+  sorted order for a probe batch or an expansion output larger than the
+  build), and the builds
+  whose payload stayed in arrival order (``ops/join.py BuildTable``,
+  ``exec/joins.py HashJoinExec._rows_for``). Summed from the
+  operators' metrics as a task ends; declared at 0 likewise.
 - ``poll.rpcs`` / ``poll.wakes_by_status`` (executor) and ``poll.holds`` /
   ``poll.holds_granted`` / ``poll.holds_timed_out`` (scheduler) — the pull
   loop's hand-off (docs/observability.md): ``PollWork`` calls sent, waits
@@ -119,6 +125,8 @@ NONINNER_JOIN_COUNTERS = (
 JOIN_COUNTERS = {
     "join.builds": "builds", "join.build_rows": "build_rows",
     "join.probe_rows": "probe_rows", "join.key_remaps": "key_remaps",
+    "join.build_gather_bytes": "build_gather_bytes",
+    "join.builds_in_place": "builds_in_place",
 }
 # the pull loop's hand-off (docs/serving.md): polls sent and waits ended by
 # a finished task (executor); polls held, and how a hold ended (scheduler)

@@ -104,6 +104,10 @@ VOCABULARY: dict[str, KernelSpec] = {
     "ops.join.join_lut": KernelSpec(
         None, "direct-address probe table (key range, build capacity)"
     ),
+    "ops.join.join_sorted_rows": KernelSpec(
+        None, "a build's payload gathered into sorted order (dtypes + null "
+        "layout)"
+    ),
     "ops.aggregate._seg_part1": KernelSpec(
         None, "static op/layout tuples from the aggregate spec"
     ),
@@ -241,6 +245,7 @@ _JOIN = (
     "exec.joins.join_expand", "exec.joins.join_probe_filter",
     "ops.join._build_finish", "ops.join.join_build_prep",
     "ops.join.join_exact2_range", "ops.join.join_lut",
+    "ops.join.join_sorted_rows",
 ) + _PERM + _CONCAT + _FETCH
 _REPARTITION = (
     "exec.repartition.repartition_hash", "exec.repartition.repartition_mask",

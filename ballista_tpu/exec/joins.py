@@ -15,6 +15,7 @@ detection at runtime, since there are no table statistics yet).
 from __future__ import annotations
 
 import functools
+import threading
 from typing import Iterator
 
 import jax
@@ -160,6 +161,9 @@ class HashJoinExec(ExecutionPlan):
         self.partition_mode = partition_mode
         self.reduction = reduction
         self._build_cache: dict = {}
+        # task slots share this instance's cached tables: a table's sorted
+        # payload is made, and a cache slot written, under this lock
+        self._build_lock = threading.Lock()
         # build-strategy flags (dups/overflow of the collected right side)
         # are partition-invariant: compute once, reuse across partitions
         self._decide_flags: tuple[bool, bool] | None = None
@@ -281,11 +285,15 @@ class HashJoinExec(ExecutionPlan):
         budget = ctx.config.build_cache_mb() << 20
         if budget <= 0:
             return
-        size = sum(c.nbytes for c in build_batch.columns)
-        size += sum(c.nbytes for c in bt.batch.columns)
-        size += bt.keys.nbytes + sum(c.nbytes for c in bt.key_cols)
-        if bt.lut2 is not None:
-            size += bt.lut2.nbytes
+        # each array once: the table holds the build batch's own columns
+        # (its rows stay where they lie), and an exact key column is ``keys``
+        held = {
+            id(a): a.nbytes
+            for a in (*build_batch.columns, *bt.batch.columns, bt.perm,
+                      bt.keys, *bt.key_cols, bt.lut2)
+            if a is not None
+        }
+        size = sum(held.values())
 
         def commit():
             # COMMIT ONLY AT A CLEAN TASK BOUNDARY: a run that fails its
@@ -293,15 +301,23 @@ class HashJoinExec(ExecutionPlan):
             # SEMI build, a stale speculation) computed this table from
             # truncated intermediates — caching it would poison every
             # retry and every later query sharing the slot.
-            if slot in self._build_cache:
-                return
-            used = cache.get("__build_cache_bytes__", 0)
-            if used + size > budget:
-                self.metrics.add("build_cache_skip")
-                return
-            cache["__build_cache_bytes__"] = used + size
-            self._build_cache[slot] = (build_batch, bt)
-            self.metrics.add("build_cache_store")
+            with self._build_lock:
+                if slot in self._build_cache:
+                    return
+                # a payload the task's probes gathered into sorted order
+                # takes the place of the arrival batch and ``perm``, in no
+                # more bytes than ``size`` counted for them (``_rows_for``)
+                table = (
+                    bt.in_sorted_order()[0] if bt.sorted_batch is not None
+                    else bt
+                )
+                used = cache.get("__build_cache_bytes__", 0)
+                if used + size > budget:
+                    self.metrics.add("build_cache_skip")
+                    return
+                cache["__build_cache_bytes__"] = used + size
+                self._build_cache[slot] = (table.batch, table)
+                self.metrics.add("build_cache_store")
 
         ctx.defer_commit(commit)
 
@@ -437,8 +453,9 @@ class HashJoinExec(ExecutionPlan):
                 for b in self.right.execute(p, ctx):
                     nbytes += device_nbytes(b)
                     if sset is None and nbytes * 2 > budget:
-                        # crossed the budget (build tables cost ~2x the
-                        # raw side: sorted copy + key arrays): switch to
+                        # crossed the budget (a build costs the raw side,
+                        # its sorted keys and permutation, and the probe's
+                        # gathers beside it; ~2x the side): switch to
                         # spilling, draining what is already resident
                         sset = ctx.spill_manager().new_set(
                             f"join-build-{id(self):x}", self._GRACE_BUCKETS
@@ -608,11 +625,16 @@ class HashJoinExec(ExecutionPlan):
 
     def _build(self, batch: DeviceBatch, key_idxs: list[int]):
         """``build_side``, counted: +1 ``builds`` and its live rows (a
-        device scalar the build has already) into ``build_rows``, summed
-        into ``join.builds`` and ``join.build_rows`` as the task ends."""
+        device scalar the build has already) into ``build_rows``, the bytes
+        its finisher gathered through the sort's permutation (from static
+        shapes) into ``build_gather_bytes``, and +1 ``builds_in_place`` (its
+        payload stays in arrival order, unless ``_rows_for`` gathers it);
+        summed into ``join.*`` as the task ends."""
         bt = build_side(batch, key_idxs)
         self.metrics.add("builds")
         self.metrics.add("build_rows", bt.n)
+        self.metrics.add("build_gather_bytes", bt.gather_bytes())
+        self.metrics.add("builds_in_place")
         return bt
 
     def _null_extend(self, pb: DeviceBatch) -> DeviceBatch:
@@ -882,8 +904,8 @@ class HashJoinExec(ExecutionPlan):
                 # the strategy-decision input and is dropped here.
                 from ballista_tpu.exec.shrink import maybe_shrink
 
-                # free the collected right AND the decide build's sorted
-                # copy of it before streaming
+                # free the collected right AND the decide build, which
+                # holds it beside its keys, before streaming
                 right_batch = rb = lb = decide = None
                 site = self.display()
                 rpart = self.right.output_partitioning()
@@ -1304,6 +1326,7 @@ class HashJoinExec(ExecutionPlan):
             return jax.jit(join_expand)
 
         fn = shared_callable(key, build)
+        bt = self._rows_for(bt, out_cap)  # the expansion reads out_cap rows
         with self.metrics.time("probe_time"):
             return fn(bt, probe, first, count)
 
@@ -1338,6 +1361,8 @@ class HashJoinExec(ExecutionPlan):
         """Probe (jitted); apply the residual join filter to match
         semantics."""
         c = self.metrics.counters
+        if kind in (JoinSide.INNER, JoinSide.LEFT) or self.filter is not None:
+            bt = self._rows_for(bt, probe.capacity)  # the payload is read
         if self.filter is None:
             with self.metrics.time("probe_time"):
                 out, c["probe_rows"] = _jit_probe(
@@ -1400,6 +1425,30 @@ class HashJoinExec(ExecutionPlan):
         with self.metrics.time("probe_time"):
             out, c["probe_rows"] = fn(bt, probe, self._rows_so_far())
         return out
+
+    def _rows_for(self, bt, rows: int):
+        """The build table a program that reads ``rows`` build rows (a probe
+        batch's capacity, an expansion's output capacity) reads the payload
+        of. The build's rows stay where they lie, and each row read pays one
+        more gather (``perm``) for that; where the program reads more rows
+        than the build has slots, it reads every build row many times over,
+        so there the payload is gathered into sorted order once a build
+        (``BuildTable.in_sorted_order``), which then counts as not in place
+        and its payload's bytes as gathered. A cached table's sorted copy
+        takes the place of its arrival batch in the cache, in no more bytes
+        than the budget counted for the batch and ``perm``. Decided on
+        static shapes."""
+        if bt.perm is None or rows <= bt.keys.shape[0]:
+            return bt
+        with self._build_lock:
+            sorted_bt, gathered = bt.in_sorted_order()
+            if gathered:
+                self.metrics.add("builds_in_place", -1)
+                self.metrics.add("build_gather_bytes", gathered)
+                for slot, (_, table) in list(self._build_cache.items()):
+                    if table is bt:
+                        self._build_cache[slot] = (sorted_bt.batch, sorted_bt)
+        return sorted_bt
 
     def _rows_so_far(self):
         """The running ``probe_rows`` a probe program carries on."""

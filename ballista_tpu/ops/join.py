@@ -4,9 +4,11 @@ Replaces DataFusion's HashJoinExec (serialized by the reference at
 ballista/rust/core/src/serde/physical_plan/mod.rs:438-523, modes
 COLLECT_LEFT / PARTITIONED in ballista.proto:474-487). TPU-native design:
 
-- **build**: one ``lax.sort`` by (dead-flag, packed 64-bit key) — dead and
-  null-key rows sink to the end, live rows come out compacted AND key-sorted
-  in a single fused sort; all columns ride a permutation gather;
+- **build**: one sort by (dead-flag, packed 64-bit key) — dead and null-key
+  rows sink to the end, live rows come out compacted AND key-sorted. Only
+  the keys ride the sort's permutation: the payload stays in arrival order,
+  and whoever reads a build row (the probe, the expansion) gathers it at
+  ``perm[sorted position]``, composing the index it already gathers at;
 - **probe**: ``searchsorted`` (vectorized binary search — log2(n) gathers,
   no data-dependent loops) finds the start of the packed-key run, then a
   fixed-width window scan verifies the *actual* key columns, so hash
@@ -38,7 +40,7 @@ from ballista_tpu.columnar.batch import DeviceBatch
 from ballista_tpu.datatypes import Schema
 from ballista_tpu.errors import ExecutionError
 from ballista_tpu.ops.hashing import hash_columns
-from ballista_tpu.ops.perm import take_many_split
+from ballista_tpu.ops.perm import take_many, take_many_split
 from ballista_tpu.ops.search import searchsorted
 
 # Max packed-key collision run the probe window resolves. Distinct keys
@@ -110,10 +112,18 @@ def _pack_key(cols: list[jnp.ndarray], mode: str = None) -> jnp.ndarray:
 @jax.tree_util.register_pytree_node_class
 @dataclasses.dataclass
 class BuildTable:
-    """Build side, compacted and sorted by packed key (one fused sort).
-    Registered as a pytree so build/probe run under jit."""
+    """Build side: its keys sorted by packed key, its rows where they lie.
+    Registered as a pytree so build/probe run under jit.
 
-    batch: DeviceBatch  # columns in key-sorted order, live rows first
+    Everything the search reads (``keys``, ``key_cols``, ``lut2``, ``n``,
+    ``lo``/``hi``) is by SORTED position; ``batch`` is the build batch as it
+    arrived, and sorted position ``p`` is its row ``perm[p]``
+    (``in_sorted_order`` gives the table whose rows are in sorted order)."""
+
+    batch: DeviceBatch | None  # the build batch in arrival order (not
+    # copied; None only inside ``_build_finish``, whose caller attaches it)
+    perm: jnp.ndarray | None  # int32[cap]: sorted position -> arrival row;
+    # None where ``batch`` is in sorted order already
     keys: jnp.ndarray  # int64[cap], dead slots forced to INT64_MAX
     key_cols: list[jnp.ndarray]  # actual key columns, sorted order
     key_idxs: list[int]  # key column indices into batch.schema
@@ -132,6 +142,9 @@ class BuildTable:
     # Replaces the per-probe-batch sorted searchsorted (~220ms at 6M
     # probes on a v5e) with one stacked gather (~70ms).
     lut2: jnp.ndarray | None = None  # int32[(domain, 2)]
+    # ``batch`` gathered into sorted order, made once by ``in_sorted_order``
+    # (not a leaf: a jitted program reads the table it is handed)
+    sorted_batch: DeviceBatch | None = None
 
     @property
     def exact(self) -> bool:
@@ -140,7 +153,7 @@ class BuildTable:
 
     def tree_flatten(self):
         leaves = (
-            self.batch, self.keys, self.key_cols, self.n,
+            self.batch, self.perm, self.keys, self.key_cols, self.n,
             self.has_dups, self.run_overflow, self.lo, self.contiguous,
             self.hi, self.lut2,
         )
@@ -148,15 +161,50 @@ class BuildTable:
 
     @classmethod
     def tree_unflatten(cls, aux, leaves):
-        (batch, keys, key_cols, n, has_dups, run_overflow, lo,
+        (batch, perm, keys, key_cols, n, has_dups, run_overflow, lo,
          contiguous, hi, lut2) = leaves
         key_idxs, mode = aux
         return cls(
-            batch=batch, keys=keys, key_cols=list(key_cols),
+            batch=batch, perm=perm, keys=keys, key_cols=list(key_cols),
             key_idxs=list(key_idxs), n=n, mode=mode,
             has_dups=has_dups, run_overflow=run_overflow,
             lo=lo, contiguous=contiguous, hi=hi, lut2=lut2,
         )
+
+    def rows(self, sorted_pos: jnp.ndarray) -> jnp.ndarray:
+        """The ``batch`` rows at sorted positions ``sorted_pos``."""
+        return sorted_pos if self.perm is None else self.perm[sorted_pos]
+
+    def in_sorted_order(self) -> tuple["BuildTable", int]:
+        """This table with its payload in sorted order (``perm`` None), and
+        the bytes this call gathered for it. The payload is gathered once
+        (``join_sorted_rows``) and kept as ``sorted_batch``: a later call
+        gathers nothing and gives 0. Callers that share a table serialize
+        their calls (``exec/joins.py HashJoinExec._rows_for``)."""
+        if self.perm is None:
+            return self, 0
+        gathered = 0
+        if self.sorted_batch is None:
+            b = self.batch
+            cols, nulls, valid = join_sorted_rows(
+                list(b.columns), list(b.nulls), b.valid, self.perm
+            )
+            self.sorted_batch = DeviceBatch(
+                schema=b.schema, columns=tuple(cols), valid=valid,
+                nulls=tuple(nulls), dictionaries=dict(b.dictionaries),
+            )
+            gathered = sum(
+                a.nbytes for a in (*cols, *nulls, valid) if a is not None
+            )
+        return dataclasses.replace(
+            self, batch=self.sorted_batch, perm=None, sorted_batch=None
+        ), gathered
+
+    def gather_bytes(self) -> int:
+        """Bytes the finisher gathered through ``perm``: the key columns
+        (in exact mode the one key column, which the sorted packed key
+        widens); static capacity x itemsize, no device read."""
+        return sum(a.nbytes for a in self.key_cols)
 
     def spec_flag(self):
         """Device bool: this build cannot serve as a unique-key probe table
@@ -244,28 +292,56 @@ def _exact2_range_program(cap: int):
     return jax.jit(join_exact2_range)
 
 
-def _build_finish(perm, dead, packed, batch: DeviceBatch, key_idxs: tuple,
+@functools.lru_cache(maxsize=None)
+def _sorted_rows_program(sig: tuple, nulls_sig: tuple):
+    def join_sorted_rows(cols, nulls, valid, perm):
+        gathered, out_nulls = take_many_split(
+            [valid, *cols], list(nulls), perm
+        )
+        return gathered[1:], out_nulls, gathered[0]
+
+    return jax.jit(join_sorted_rows)
+
+
+def join_sorted_rows(cols: list, nulls: list, valid, perm):
+    """A build's columns, null masks and validity gathered into sorted order
+    (``perm``) in one dispatch, stacked by dtype: ``ops/perm.take_batch``
+    under the join's own name, which the join's metrics read."""
+    prog = _sorted_rows_program(
+        tuple(str(c.dtype) for c in cols),
+        tuple(m is not None for m in nulls),
+    )
+    return prog(tuple(cols), tuple(nulls), valid, perm)
+
+
+def _build_finish(perm, dead, batch: DeviceBatch, key_idxs: tuple,
                   mode: str) -> BuildTable:
-    """Jitted finisher after the sort passes (no sort in here)."""
+    """Jitted finisher after the sort passes (no sort in here). It gathers
+    the keys through ``perm`` and nothing else: the payload stays where it
+    lies, in ``batch``, and its readers compose ``perm``. The table comes
+    back without its rows (``batch`` None): the caller attaches the batch it
+    holds, because a jitted program that returned the batch would copy
+    every column of it."""
     cap = batch.capacity
     iota = jnp.arange(cap, dtype=jnp.int32)
     n = jnp.sum((~dead).astype(jnp.int32))
     valid_sorted = iota < n
     # Dead tail forced to INT64_MAX keeps `keys` sorted (all live packed
     # values are <= MAX) and inert to searchsorted.
-    keys_sorted = jnp.where(
-        valid_sorted, packed[perm], jnp.iinfo(jnp.int64).max
-    )
-    cols = tuple(col[perm] for col in batch.columns)
-    nulls = tuple(None if m is None else m[perm] for m in batch.nulls)
-    sorted_batch = DeviceBatch(
-        schema=batch.schema,
-        columns=cols,
-        valid=valid_sorted,
-        nulls=nulls,
-        dictionaries=dict(batch.dictionaries),
-    )
-    sorted_key_cols = [cols[i] for i in key_idxs]
+    dead_key = jnp.iinfo(jnp.int64).max
+    if mode == "exact":
+        # the packed key IS the key column, widened: one gather serves both,
+        # at the column's own width, and an int64 column is ``keys`` itself
+        kc = batch.columns[key_idxs[0]][perm]
+        keys_sorted = jnp.where(valid_sorted, kc.astype(jnp.int64), dead_key)
+        sorted_key_cols = [keys_sorted if kc.dtype == jnp.int64 else kc]
+    else:
+        # packing is row-wise, so the sorted packed key is the packing of
+        # the sorted key columns: gather those, not ``packed`` besides
+        sorted_key_cols = take_many([batch.columns[i] for i in key_idxs], perm)
+        keys_sorted = jnp.where(
+            valid_sorted, _pack_key(sorted_key_cols, mode), dead_key
+        )
 
     # Equal actual keys are always adjacent after the sort (exact packing is
     # injective; hash mode tie-breaks on the actual key columns), so one
@@ -323,7 +399,8 @@ def _build_finish(perm, dead, packed, batch: DeviceBatch, key_idxs: tuple,
         run_overflow = jnp.max(lengths) > COLLISION_WINDOW
 
     return BuildTable(
-        batch=sorted_batch,
+        batch=None,
+        perm=perm,
         keys=keys_sorted,
         key_cols=sorted_key_cols,
         key_idxs=list(key_idxs),
@@ -383,9 +460,8 @@ def build_side(batch: DeviceBatch, key_idxs: list[int]) -> BuildTable:
     if mode == "hash":
         passes.extend((batch.columns[i], False) for i in key_idxs)
     perm = multi_key_perm(passes)
-    return _build_finish_jit(
-        perm, dead, packed, batch, tuple(key_idxs), mode
-    )
+    bt = _build_finish_jit(perm, dead, batch, tuple(key_idxs), mode)
+    return dataclasses.replace(bt, batch=batch)
 
 
 # Direct-address probe tables stay below this domain span (i32 pairs:
@@ -510,11 +586,11 @@ def probe_side(
         return probe.with_valid(probe.valid & ~match)
 
     # INNER / LEFT: probe columns ++ build columns gathered at the
-    # candidate — one stacked random-access pass per dtype, not one gather
-    # per column (ops/perm.take_many).
+    # candidate's arrival row — one stacked random-access pass per dtype,
+    # not one gather per column (ops/perm.take_many).
     b = build.batch
     gath_cols, gath_m = take_many_split(
-        list(b.columns), list(b.nulls), cand
+        list(b.columns), list(b.nulls), build.rows(cand)
     )
     if verify_after:
         # the key columns came along in the main gather — the verify is a
@@ -637,7 +713,8 @@ def expand_join(
     k = j - start
     valid_out = j < total
     real = valid_out & (k < count[i])
-    bidx = jnp.clip(first[i] + k, 0, cap_b - 1)
+    # the match's sorted position, then its row in the build batch
+    bidx = build.rows(jnp.clip(first[i] + k, 0, cap_b - 1))
 
     b = build.batch
     # probe-side and build-side gathers each stacked by dtype

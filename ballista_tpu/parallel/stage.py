@@ -26,6 +26,7 @@ on the mesh.
 from __future__ import annotations
 
 
+import dataclasses
 import threading
 
 import jax
@@ -778,8 +779,9 @@ class MeshStageRunner:
                 nulls=rn,
                 dictionaries=r_dicts,
             )
-            bt = _build_finish(
-                perm, dead, packed, rbatch, right_keys, mode
+            bt = dataclasses.replace(
+                _build_finish(perm, dead, rbatch, right_keys, mode),
+                batch=rbatch,
             )
             lbatch = DeviceBatch(
                 schema=l_schema,

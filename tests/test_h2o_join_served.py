@@ -128,13 +128,24 @@ def test_the_join_counters_read_what_numpy_counts(tables, served, name):
     for _, moved in runs[name]:
         assert {k: moved.get(k, 0) for k in want} == want, moved
         assert moved.get("join.noninner.probe_rows", 0) == 0
+        # each build gathered its key column: 8 bytes a slot of big's int64
+        # key, 4 of medium's string codes. big's buckets, probed by batches
+        # smaller than themselves, keep their payload in place; medium's
+        # 30 rows, probed by 4,096-row batches, are gathered into sorted
+        # order once a probed build (the decision builds are never probed)
+        assert moved["join.builds_in_place"] == partitions
+        width = {"j1q5": 8, "j1q4": 4}[name]
+        assert moved["join.build_gather_bytes"] >= width * moved[
+            "join.build_rows"]
 
 
 def test_the_counters_are_declared_at_zero():
     from ballista_tpu.compilecache.metrics import JOIN_COUNTERS
 
     assert set(JOIN_COUNTERS) == {"join.builds", "join.build_rows",
-                                  "join.probe_rows", "join.key_remaps"}
+                                  "join.probe_rows", "join.key_remaps",
+                                  "join.build_gather_bytes",
+                                  "join.builds_in_place"}
     assert set(JOIN_COUNTERS) <= set(metrics.snapshot())
 
 

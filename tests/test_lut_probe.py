@@ -60,7 +60,8 @@ def test_lut_matches_searchsorted_on_sparse_domain():
     # matched probes point at the right build row (keys agree)
     f = np.asarray(first)
     cnt = np.asarray(c_lut)
-    skeys = np.asarray(bt.batch.columns[0])
+    # the build's rows stay in arrival order: sorted position p is row perm[p]
+    skeys = np.asarray(bt.batch.columns[0])[np.asarray(bt.perm)]
     m = cnt > 0
     assert np.array_equal(
         skeys[f[m]], np.asarray(probe.columns[0])[m]
@@ -88,7 +89,8 @@ def test_lut_duplicate_build_run_counts():
     assert np.array_equal(count, want)
     # first indices point at the start of each run in the sorted build
     f = np.asarray(first)[: len(pkeys)]
-    skeys = np.asarray(bt.batch.columns[0])
+    # the build's rows stay in arrival order: sorted position p is row perm[p]
+    skeys = np.asarray(bt.batch.columns[0])[np.asarray(bt.perm)]
     for i, k in enumerate(pkeys):
         if want[i]:
             assert skeys[f[i]] == k

@@ -169,6 +169,8 @@ def test_a_traced_run_of_the_cell_reports_every_metric_it_is_held_to(tmp_path):
     assert 1 <= line["metrics"]["status_polls_per_query"]["value"] < 10
     # int64 keys: no dense pass, none on the factorized one-hot (PR 37)
     assert line["metrics"]["agg_dense_factored_passes_per_query"]["value"] == 0
+    # the two-key join's builds gathered their keys through the permutation
+    assert line["metrics"]["join_build_gather_mb_per_query"]["value"] > 0
 
 
 # -- planted faults -----------------------------------------------------------------

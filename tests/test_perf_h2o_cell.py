@@ -183,6 +183,8 @@ def test_a_traced_run_of_the_cell_reports_every_metric_it_is_held_to(tmp_path):
     assert 1 <= line["metrics"]["status_polls_per_query"]["value"] < 10
     # g1q2's 10,201 slots (PR 37): its dense passes reduce on the MXU
     assert line["metrics"]["agg_dense_factored_passes_per_query"]["value"] > 0
+    # no join: the builds' gathers read 0, not nothing
+    assert line["metrics"]["join_build_gather_mb_per_query"]["value"] == 0
 
 
 # -- planted faults -----------------------------------------------------------------

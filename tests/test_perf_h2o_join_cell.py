@@ -231,6 +231,10 @@ def test_a_traced_run_of_the_cell_reports_every_metric_it_is_held_to(tmp_path):
     assert metrics["join_key_remaps_per_query"] >= 1
     assert metrics["noninner_join_probe_rows_per_query"] == 0
     assert metrics["join_self_ms_per_query"] > 0
+    # every build is exact and gathers its key column: j1q5's int64 key,
+    # 8 bytes a slot of capacities past its rows, holds the most
+    gathered = metrics["join_build_gather_mb_per_query"] * 1e6
+    assert gathered >= 8 * metrics["join_build_rows_per_query"]
 
 
 @pytest.mark.parametrize("name,counter", [
@@ -259,6 +263,29 @@ def test_a_join_reader(name, counter):
     old = {"join.noninner.probe_rows": 0, "agg.sort_passes": 4}
     assert read(old, old) is None
     assert read(None, None) is None
+    assert read({counter: 0}, {counter: 2}, [failed]) is None
+
+
+def test_the_build_gather_reader():
+    """Megabytes per completed query where the program counts, 0 where it
+    declares the counter and no join ran, ``None`` where the program has no
+    such counter (a parent commit), so that the metric is left out."""
+    from layers import join_build_gather_mb_per_query as reader
+
+    counter = "join.build_gather_bytes"
+    done = {"error": None, "template": "j1q5", "t0": 10.0, "t1": 11.0}
+    failed = {"error": "Boom", "template": "j1q4", "t0": 11.0, "t1": 11.5}
+
+    def read(before, after, queries=(done, done, failed)):
+        return reader.read({"queries": list(queries),
+                            "counters_before": before,
+                            "counters_after": after})
+
+    assert read({counter: 0}, {counter: 134_217_728}) == pytest.approx(
+        67.108864)
+    assert read({counter: 0}, {counter: 0}) == 0.0
+    old = {"join.builds": 0, "agg.sort_passes": 4}
+    assert read(old, old) is None
     assert read({counter: 0}, {counter: 2}, [failed]) is None
 
 

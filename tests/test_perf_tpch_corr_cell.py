@@ -189,6 +189,8 @@ def test_a_traced_run_of_the_cell_reports_every_metric_it_is_held_to(tmp_path):
     assert metrics["noninner_join_tasks_per_query"] > 0
     assert metrics["agg_capacity_retries_in_window"] == 0
     assert metrics["holistic_tasks_per_query"] == 0
+    # the reductions' and the joins' builds gathered their keys
+    assert metrics["join_build_gather_mb_per_query"] > 0
 
 
 def test_the_reduced_aggregates_reader():

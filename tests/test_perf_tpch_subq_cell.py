@@ -181,6 +181,9 @@ def test_a_traced_run_of_the_cell_reports_every_metric_it_is_held_to(tmp_path):
     assert metrics["holistic_tasks_per_query"] == 0
     # q4's dense aggregate has 6 slots, within the one-hot kernels (PR 37)
     assert metrics["agg_dense_factored_passes_per_query"] == 0
+    # q13's and q4's builds gathered their keys, 4 bytes a row at least
+    assert metrics["join_build_gather_mb_per_query"] >= 4e-6 * metrics[
+        "join_build_rows_per_query"] > 0
 
 
 # -- planted faults -----------------------------------------------------------------
